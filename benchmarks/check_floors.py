@@ -67,9 +67,7 @@ def check_prefill() -> None:
     """Chunked prefill must beat whole-prompt buckets >= 1.5x on cold TTFT
     (mean or worst-request; 1 compiled chunk trace vs one per bucket) or
     warm mixed prefill/decode throughput, compiled einsum path wall-clock
-    — and must compile exactly one prefill trace (-1 = the private jax
-    trace-count API is unavailable; the metric degrades instead of
-    failing CI)."""
+    — and must compile exactly one prefill trace."""
     run = last_with("BENCH_serving.json", "accept_speedup_x")
     x = run["accept_speedup_x"]
     traces = run["chunked_prefill_traces_off"]
@@ -81,11 +79,11 @@ def check_prefill() -> None:
     print(f"accept metric: {run['accept_metric']}")
     print(f"prefill traces: chunked={traces} "
           f"whole={run['whole_prefill_traces_off']}")
-    if traces not in (1, -1):
+    if traces != 1:
         raise SystemExit(
             f"FLOOR FAILED: chunked_prefill_traces_off = {traces}, "
-            "required exactly 1 compiled trace (-1 = API unavailable)")
-    print(f"floor ok: chunked_prefill_traces_off = {traces} (1 or -1)")
+            "required exactly 1 compiled trace")
+    print(f"floor ok: chunked_prefill_traces_off = {traces}")
     _floor("accept_speedup_x", x, ">=", 1.5)
 
 

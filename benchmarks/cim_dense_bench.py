@@ -67,7 +67,7 @@ def bench_decode_tiles() -> dict:
     # the auto skinny tile (bit-identical under threefry; the model carries
     # the compiled-TPU 32-sublane floor so the ratio is a real launch)
     pad = modeled_cost(M, K, N, bm=256, bn=256)
-    skinny = modeled_cost(M, K, N)           # auto: bm = 32 (TPU floor)
+    skinny = modeled_cost(M, K, N)           # auto: bm = next multiple of 8
     combined_pad = pad["flops"] + pad["hbm_bytes"]
     combined_skinny = skinny["flops"] + skinny["hbm_bytes"]
 

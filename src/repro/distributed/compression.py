@@ -26,7 +26,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.distributed.sharding import dp_axes, pvary, shard_map
+from repro.distributed.sharding import dp_axes
 
 
 def _stochastic_round(x: jnp.ndarray, key: jax.Array) -> jnp.ndarray:
@@ -77,10 +77,11 @@ def compressed_dp_grads(
     n = mesh.shape[dp_axis]
 
     def local(params, local_batch):
-        # pvary: mark params as device-varying so jax.grad does NOT insert
-        # its automatic psum for replicated inputs (shard_map check_vma
+        # mark params as device-varying so jax.grad does NOT insert its
+        # automatic psum for replicated inputs (shard_map check_vma
         # semantics) — the int8 psum below must be the only reduction.
-        params = jax.tree.map(lambda t: pvary(t, (dp_axis,)), params)
+        params = jax.tree.map(
+            lambda t: jax.lax.pcast(t, (dp_axis,), to="varying"), params)
         g = grad_fn(params, local_batch)
         idx = jax.lax.axis_index(dp_axis)
 
@@ -100,7 +101,7 @@ def compressed_dp_grads(
 
     batch_specs = jax.tree.map(lambda x: P(dp_axis), batch)
     param_specs = jax.tree.map(lambda x: P(), params)
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(param_specs, batch_specs),
         out_specs=param_specs,

@@ -24,8 +24,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.distributed.sharding import (pp_axis, pvary as _pvary,
-                                        shard_map as _shard_map)
+from repro.distributed.sharding import pp_axis
 
 
 def pipeline_apply(
@@ -90,10 +89,11 @@ def pipeline_apply(
                 outs)
             return (nxt, outs), None
 
-        # pvary: the carries become device-varying after the first ppermute;
-        # mark the initial values accordingly (shard_map vma semantics).
-        buf0 = _pvary(jnp.zeros((mb,) + x_l.shape[1:], x_l.dtype), (axis,))
-        outs0 = _pvary(jnp.zeros_like(micros), (axis,))
+        # the carries become device-varying after the first ppermute; mark
+        # the initial values accordingly (shard_map vma semantics)
+        buf0 = jax.lax.pcast(jnp.zeros((mb,) + x_l.shape[1:], x_l.dtype),
+                             (axis,), to="varying")
+        outs0 = jax.lax.pcast(jnp.zeros_like(micros), (axis,), to="varying")
         (_, outs), _ = jax.lax.scan(tick, (buf0, outs0), jnp.arange(n_ticks))
         # only the last stage holds real outputs; zero elsewhere -> psum
         outs = jnp.where(stage == n_stage - 1, outs, jnp.zeros_like(outs))
@@ -101,7 +101,7 @@ def pipeline_apply(
         return outs.reshape((b,) + x_l.shape[1:])
 
     spec_params = jax.tree.map(lambda _: P(axis), stage_params)
-    return _shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(spec_params, P()),
         out_specs=P(),

@@ -98,13 +98,6 @@ class VirtualMesh:
             n *= s
         return _np.empty((n,), object)
 
-# jax >= 0.6 promotes shard_map/pvary to the top level; jax 0.4.x keeps
-# shard_map experimental and has no vma tracking (pvary == identity there).
-# Import these from here instead of `jax.` directly.
-shard_map = getattr(jax, "shard_map", None)
-if shard_map is None:
-    from jax.experimental.shard_map import shard_map  # noqa: F401
-pvary = getattr(jax.lax, "pvary", lambda x, axes: x)
 
 AxisVal = Union[None, str, Tuple[str, ...]]
 

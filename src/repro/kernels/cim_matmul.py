@@ -15,16 +15,18 @@ HBM stream of the old design (for a 4096^3 int8 matmul: 256 MiB of noise vs
 
 Two noise constructions (``prng_impl``):
 
-  * ``"threefry"`` (default off-TPU / interpret): counter-based Threefry-2x32
-    keyed on (seed, k-tile) with the *global* (row, col) as counter, bits ->
-    Box-Muller Gaussian (``repro.core.prng``). Bit-reproducible against the
-    pure-jnp oracle ``ref.cim_matmul_prng_ref`` and invariant to bm/bn.
-  * ``"hw"`` (default on compiled TPU): the TPU on-core PRNG
-    (``pltpu.prng_seed`` seeded with (seed, i, j, k) / ``prng_random_bits``),
-    same bits -> Gaussian pipeline. Cheapest on hardware, deterministic given
-    (seed, grid), but the stream differs from the oracle and depends on the
-    block shape. jax 0.4.x has no CPU lowering for these primitives, so this
-    path never runs in interpret mode.
+  * ``"threefry"`` (the default on every backend): counter-based
+    Threefry-2x32 keyed on (seed, k-tile) with the *global* (row, col) as
+    counter, bits -> Box-Muller Gaussian (``repro.core.prng``).
+    Bit-reproducible against the pure-jnp oracle ``ref.cim_matmul_prng_ref``
+    and invariant to bm/bn, and the same stream the dense megakernel draws.
+  * ``"hw"`` (opt-in, compiled TPU only): the TPU on-core PRNG
+    (``pltpu.prng_seed`` with the key word and the folded grid position,
+    ``prng_random_bits``), same bits -> Gaussian pipeline. Deterministic
+    given (seed, grid), but the stream differs from the oracle and depends
+    on the block shape, so it is checked by its moments, not bit for bit.
+    There is no CPU lowering for these primitives. It stays opt-in until its
+    device time is measured against Threefry (DESIGN.md §3).
 
 The dequant epilogue multiplies the f32 accumulator by a scalar ``scale``
 (= x_scale * w_scale) held in SMEM, so ``ops.cim_matmul`` no longer runs a
@@ -34,11 +36,11 @@ TPU mapping (DESIGN.md §2): bk == macro_rows == 1024 keeps one macro tile per
 grid step and is MXU-aligned; bm/bn auto-select (``bm=None``) — 256 for
 training/prefill shapes (working set x 256KiB + w 256KiB + acc 256KiB inside
 VMEM), but a *decode-shaped* call (M = a handful of serving slots) gets a
-skinny tile instead of a 256-row pad (next multiple of 8; floored at 32
-sublanes on compiled TPU, Mosaic's native int8 tile): 8-64x less row work
-and activation traffic. Under the threefry PRNG the result is bit-identical
-across tile shapes (global (row, col) counter, §3); the "hw" stream seeds
-on block indices, so on compiled TPU re-tiling keeps only statistical
+skinny tile instead of a 256-row pad (next multiple of 8; Mosaic compiles
+8-row int8 blocks for v5e): 8-64x less row work and activation traffic.
+Under the threefry PRNG the result is bit-identical across tile shapes
+(global (row, col) counter, §3); the "hw" stream seeds on the grid
+position, so on compiled TPU re-tiling keeps only statistical
 equivalence. Grid iteration order is (m, n, k) with k innermost
 ("arbitrary" semantics) so the f32 accumulator lives in a VMEM scratch
 across the K sweep.
@@ -61,21 +63,20 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.prng import tile_gaussian
-from repro.kernels._compat import CompilerParams as _CompilerParams
 
 MACRO_ROWS = 1024
+_GOLDEN = -1640531527  # 0x9E3779B9 as int32
 
 
 def _auto_bm(m: int) -> int:
     """Decode-shaped tile pick: next multiple of 8 >= m, capped at 256.
 
     A fused decode step runs M = active-slot count (4-8 rows); padding that
-    to the training-shaped bm=256 does 8-64x the row work (compiled TPU
-    floors the tile at 32 sublanes, see ``_resolve_blocks``) and streams a
+    to the training-shaped bm=256 does 8-64x the row work and streams a
     256-row activation block per grid step. Under the threefry PRNG the
     noise counter is the *global* (row, col) (DESIGN.md §3), so shrinking bm
-    is bit-invariant; the TPU "hw" stream seeds on block indices and is only
-    *statistically* equivalent across tile shapes.
+    is bit-invariant; the TPU "hw" stream seeds on the grid position and is
+    only *statistically* equivalent across tile shapes.
     """
     return max(8, min(256, -(-m // 8) * 8))
 
@@ -95,11 +96,10 @@ def modeled_cost(m: int, k: int, n: int, bm: int | None = None,
     the w block once per M-block row, the output writes once. This is the
     cost the benchmarks compare across tile shapes (interpret-mode wall
     clock is emulation — the model is the perf witness, as in
-    benchmarks/attention_bench.py). Auto-picked bm carries the same 32-row
-    Mosaic int8 floor as ``_resolve_blocks`` on compiled TPU, so the model
-    describes a launch configuration the hardware actually runs.
+    benchmarks/attention_bench.py). Auto-picked blocks are the ones the
+    kernel launches.
     """
-    bm = max(_auto_bm(m), 32) if bm is None else bm
+    bm = _auto_bm(m) if bm is None else bm
     bn = _auto_bn(n) if bn is None else bn
     gm, gn, gk = -(-m // bm), -(-n // bn), -(-k // bk)
     mp, np_, kp = gm * bm, gn * bn, gk * bk
@@ -110,10 +110,16 @@ def modeled_cost(m: int, k: int, n: int, bm: int | None = None,
 
 
 def _hw_tile_gaussian(seed_ref, i, j, kk, bm, bn):
-    """(bm, bn) standard normals from the TPU on-core PRNG."""
+    """(bm, bn) standard normals from the TPU on-core PRNG.
+
+    Mosaic seeds the core PRNG with at most two words, so the grid position
+    is folded into the second one: the linear tile index times an odd
+    (golden-ratio) constant, XOR-ed onto the key word.
+    """
     from repro.core.prng import gaussian_from_bits
 
-    pltpu.prng_seed(seed_ref[0], seed_ref[1], i, j, kk)
+    tile = (i * pl.num_programs(1) + j) * pl.num_programs(2) + kk
+    pltpu.prng_seed(seed_ref[0], seed_ref[1] ^ (tile * _GOLDEN))
     bits = pltpu.bitcast(pltpu.prng_random_bits((2 * bm, bn)), jnp.uint32)
     return gaussian_from_bits(bits[:bm], bits[bm:])
 
@@ -180,22 +186,9 @@ def _fused_kernel(seed_ref, x_ref, w_ref, qp_ref, o_ref, acc_ref, *,
         o_ref[...] = acc_ref[...] * qp_ref[1]
 
 
-def _resolve_blocks(m, n, bm, bn, interpret):
-    bm = _auto_bm(m) if bm is None else bm
-    bn = _auto_bn(n) if bn is None else bn
-    if jax.default_backend() == "tpu" and not interpret:
-        # Mosaic's native int8 tile is (32, 128): sub-32-sublane int8 blocks
-        # risk failing to lower on compiled TPU. Flooring bm is free —
-        # results are bit-invariant to the block shape (§3).
-        bm = max(bm, 32)
-    return bm, bn
-
-
-def _resolve_prng(prng_impl, interpret):
-    if prng_impl == "auto":
-        return ("hw" if (jax.default_backend() == "tpu" and not interpret)
-                else "threefry")
-    return prng_impl
+def _resolve_blocks(m, n, bm, bn):
+    return (_auto_bm(m) if bm is None else bm,
+            _auto_bn(n) if bn is None else bn)
 
 
 def _resolve_seed(seed, sigma):
@@ -236,7 +229,7 @@ def cim_matmul_pallas(
     bn: int | None = None,
     bk: int = MACRO_ROWS,
     interpret: bool = False,
-    prng_impl: str = "auto",
+    prng_impl: str = "threefry",
 ) -> jnp.ndarray:
     """CIM behavioural matmul with in-kernel noise. See module docstring.
 
@@ -251,17 +244,16 @@ def cim_matmul_pallas(
       bm/bn: block shape; None auto-selects — decode-shaped (skinny) M gets
              the next multiple of 8 instead of a 256-row pad, bit-identically
              (the threefry counter is the global coordinate, DESIGN.md §3).
-      prng_impl: "auto" | "threefry" | "hw" (see module docstring).
+      prng_impl: "threefry" | "hw" (see module docstring).
 
     Returns: (M, N) float32 of (sum_k tiles + noise) * scale.
     """
     m, k = xq.shape
     k2, n = wq.shape
     assert k == k2, (xq.shape, wq.shape)
-    bm, bn = _resolve_blocks(m, n, bm, bn, interpret)
+    bm, bn = _resolve_blocks(m, n, bm, bn)
     n_k = -(-k // bk)
     mp, np_, kp = -(-m // bm) * bm, -(-n // bn) * bn, n_k * bk
-    prng_impl = _resolve_prng(prng_impl, interpret)
 
     xq = jnp.pad(xq, ((0, mp - m), (0, kp - k)))
     wq = jnp.pad(wq, ((0, kp - k), (0, np_ - n)))
@@ -279,7 +271,7 @@ def cim_matmul_pallas(
         ),
         grid_spec=_macro_grid_spec(mp, np_, bm, bn, bk, n_k),
         out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.float32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -304,7 +296,7 @@ def cim_matmul_fused_pallas(
     bn: int | None = None,
     bk: int = MACRO_ROWS,
     interpret: bool = False,
-    prng_impl: str = "auto",
+    prng_impl: str = "threefry",
 ) -> jnp.ndarray:
     """Fused activation quant + CIM matmul on a resident int8 weight plane.
 
@@ -325,10 +317,9 @@ def cim_matmul_fused_pallas(
     assert k == k2, (x.shape, wq.shape)
     # the prologue casts the quantized block to int8 for the MXU dot
     assert in_bits <= 8, f"fused act quant is int8-bound, got in_bits={in_bits}"
-    bm, bn = _resolve_blocks(m, n, bm, bn, interpret)
+    bm, bn = _resolve_blocks(m, n, bm, bn)
     n_k = -(-k // bk)
     mp, np_, kp = -(-m // bm) * bm, -(-n // bn) * bn, n_k * bk
-    prng_impl = _resolve_prng(prng_impl, interpret)
 
     x = jnp.pad(x.astype(jnp.float32), ((0, mp - m), (0, kp - k)))
     wq = jnp.pad(wq, ((0, kp - k), (0, np_ - n)))
@@ -344,7 +335,7 @@ def cim_matmul_fused_pallas(
         ),
         grid_spec=_macro_grid_spec(mp, np_, bm, bn, bk, n_k),
         out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.float32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

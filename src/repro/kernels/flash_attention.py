@@ -55,7 +55,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
 from repro.kernels.decode_attention import _pick_block_k
 
 NEG_INF = -1e30
@@ -208,7 +207,7 @@ def flash_attention(
                           t_valid=t, s_valid=s),
         grid_spec=grid_spec,
         out_shape=out_shapes,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -236,7 +235,7 @@ def _gqa_blocks(s: int, t: int, block_q: int, block_k: int):
 
 def _gqa_kernel(start_ref, *refs, scale: float, int8: bool, count: bool,
                 block_q: int, block_k: int, n_k: int, group: int,
-                s_valid: int):
+                kv_heads: int, s_valid: int):
     if int8:
         q_ref, k_ref, v_ref, ks_ref, vs_ref = refs[:5]
         rest = refs[5:]
@@ -250,8 +249,9 @@ def _gqa_kernel(start_ref, *refs, scale: float, int8: bool, count: bool,
         o_ref, m_ref, l_ref, acc_ref, cnt_ref = rest
         counts_ref = None
     b = pl.program_id(0)
-    qb = pl.program_id(2)
-    kb = pl.program_id(3)
+    qb = pl.program_id(1)
+    kb = pl.program_id(2)
+    rows = block_q * group
 
     @pl.when(kb == 0)
     def _init():
@@ -263,48 +263,47 @@ def _gqa_kernel(start_ref, *refs, scale: float, int8: bool, count: bool,
     start_b = start_ref[b]
     # causal frontier of this q block (last absolute query position it can
     # hold); k blocks strictly beyond it are pruned — same contract as the
-    # MHA kernel, now shared across the G grouped heads of one KV head
+    # MHA kernel, shared by every head of the row
     q_abs_max = start_b + jnp.minimum((qb + 1) * block_q, s_valid) - 1
 
     @pl.when(kb * block_k <= q_abs_max)
     def _compute():
         cnt_ref[0] += 1
-        # (block_q, G, D) query block -> (block_q*G, D): row r holds query
-        # position r // G, grouped head r % G — one dense MXU operand per
-        # KV head, no cache head-replication
-        q = q_ref[0].reshape(block_q * group, -1)
-        k = k_ref[0, :, 0, :]                          # (bk, D)
-        v = v_ref[0, :, 0, :]
-        if int8:
-            k = k.astype(jnp.float32) * ks_ref[0, :, 0, :]
-            v = v.astype(jnp.float32) * vs_ref[0, :, 0, :]
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-
+        # row r of a head's (block_q*G, D) query block holds query position
+        # r // G, grouped head r % G — one dense MXU operand per KV head, no
+        # cache head-replication
         qi = qb * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q * group, block_k), 0) // group
+            jnp.int32, (rows, block_k), 0) // group
         kj = kb * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q * group, block_k), 1)
+            jnp.int32, (rows, block_k), 1)
         # _cached_mask semantics: causal at start[b]+i, keys beyond the
         # freshly written prefix (recycled-slot junk) never exposed
         mask = (kj <= qi + start_b) & (kj < start_b + s_valid)
-        s = jnp.where(mask, s, NEG_INF)
-
-        m_prev = m_ref[...]                            # (bq*G,)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new[:, None])                # (bq*G, bk)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1)
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + jnp.dot(
-            p.astype(v.dtype), v, preferred_element_type=jnp.float32)
-        m_ref[...] = m_new
+        for h in range(kv_heads):
+            q = q_ref[0, h]                            # (bq*G, D)
+            k = k_ref[0, :, h, :]                      # (bk, D)
+            v = v_ref[0, :, h, :]
+            if int8:
+                k = k.astype(jnp.float32) * ks_ref[0, :, h, :]
+                v = v.astype(jnp.float32) * vs_ref[0, :, h, :]
+            s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
+            s = jnp.where(mask, s, NEG_INF)
+            m_prev = m_ref[h]                          # (bq*G,)
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new[:, None])            # (bq*G, bk)
+            l_ref[h] = l_ref[h] * alpha + jnp.sum(p, axis=-1)
+            acc_ref[h] = acc_ref[h] * alpha[:, None] + jnp.dot(
+                p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+            m_ref[h] = m_new
 
     @pl.when(kb == n_k - 1)
     def _done():
-        denom = jnp.maximum(l_ref[...], 1e-30)[:, None]
-        o = acc_ref[...] / denom                       # (bq*G, D)
-        o_ref[0] = o.reshape(block_q, group, -1).astype(o_ref.dtype)
+        denom = jnp.maximum(l_ref[...], 1e-30)[..., None]
+        o_ref[0] = (acc_ref[...] / denom).astype(o_ref.dtype)
         if count:
-            counts_ref[0, 0, 0] = cnt_ref[0]
+            for h in range(kv_heads):
+                counts_ref[0, h, 0] = cnt_ref[0]
 
 
 @functools.partial(
@@ -359,66 +358,72 @@ def flash_gqa_attention(
     scale = 1.0 / (d ** 0.5)
     bq, bk = _gqa_blocks(s, t, block_q, block_k)
     sq = -(-s // bq) * bq
-    qp = jnp.pad(q, ((0, 0), (0, sq - s), (0, 0), (0, 0)))
-    start_arr = (jnp.zeros((b,), jnp.int32) if start is None
-                 else start.astype(jnp.int32))
     n_q = sq // bq
     n_k = t // bk
+    # (B, S, H, D) -> (B, KV, S*G, D): a KV head's G query heads become
+    # rows of one operand, so the block's minor dims are (bq*G, D) — Mosaic
+    # tiles the last two dims, and a (G, D) head-slice block is not tileable
+    qp = jnp.pad(q, ((0, 0), (0, sq - s), (0, 0), (0, 0)))
+    qp = qp.reshape(b, sq, kv_heads, group, d).transpose(0, 2, 1, 3, 4)
+    qp = qp.reshape(b, kv_heads, sq * group, d)
+    start_arr = (jnp.zeros((b,), jnp.int32) if start is None
+                 else start.astype(jnp.int32))
 
-    def q_map(bi, hi, qi, kb, st):
-        return (bi, qi, hi, 0)
+    def q_map(bi, qi, kb, st):
+        return (bi, 0, qi, 0)
 
-    def kv_map(bi, hi, qi, kb, st):
+    def kv_map(bi, qi, kb, st):
         # clamp pruned blocks onto the causal-frontier block: the repeated
         # block index elides the DMA (same trick as the MHA kernel)
         last = (st[bi] + jnp.minimum((qi + 1) * bq, s) - 1) // bk
-        return (bi, jnp.minimum(kb, last), hi, 0)
+        return (bi, jnp.minimum(kb, last), 0, 0)
 
     in_specs = [
-        pl.BlockSpec((1, bq, group, d), q_map),
-        pl.BlockSpec((1, bk, 1, d), kv_map),
-        pl.BlockSpec((1, bk, 1, d), kv_map),
+        pl.BlockSpec((1, kv_heads, bq * group, d), q_map),
+        pl.BlockSpec((1, bk, kv_heads, d), kv_map),
+        pl.BlockSpec((1, bk, kv_heads, d), kv_map),
     ]
     operands = [qp, k, v]
     if int8:
         in_specs += [
-            pl.BlockSpec((1, bk, 1, 1), kv_map),
-            pl.BlockSpec((1, bk, 1, 1), kv_map),
+            pl.BlockSpec((1, bk, kv_heads, 1), kv_map),
+            pl.BlockSpec((1, bk, kv_heads, 1), kv_map),
         ]
         operands += [ks, vs]
 
-    out_shapes = [jax.ShapeDtypeStruct((b, sq, h, d), q.dtype)]
-    out_specs = [pl.BlockSpec((1, bq, group, d), q_map)]
+    out_shapes = [jax.ShapeDtypeStruct((b, kv_heads, sq * group, d), q.dtype)]
+    out_specs = [pl.BlockSpec((1, kv_heads, bq * group, d), q_map)]
     if return_block_counts:
         out_shapes.append(jax.ShapeDtypeStruct((b, kv_heads, n_q), jnp.int32))
-        out_specs.append(
-            pl.BlockSpec((1, 1, 1), lambda bi, hi, qi, kb, st: (bi, hi, qi)))
+        out_specs.append(pl.BlockSpec((1, kv_heads, 1),
+                                      lambda bi, qi, kb, st: (bi, 0, qi)))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(b, kv_heads, n_q, n_k),
+        grid=(b, n_q, n_k),
         in_specs=in_specs,
         out_specs=out_specs,
         scratch_shapes=[
-            pltpu.VMEM((bq * group,), jnp.float32),      # running max
-            pltpu.VMEM((bq * group,), jnp.float32),      # denominator
-            pltpu.VMEM((bq * group, d), jnp.float32),    # accumulator
+            pltpu.VMEM((kv_heads, bq * group), jnp.float32),     # running max
+            pltpu.VMEM((kv_heads, bq * group), jnp.float32),     # denominator
+            pltpu.VMEM((kv_heads, bq * group, d), jnp.float32),  # accumulator
             pltpu.SMEM((1,), jnp.int32),
         ],
     )
     outs = pl.pallas_call(
         functools.partial(_gqa_kernel, scale=scale, int8=int8,
                           count=return_block_counts, block_q=bq, block_k=bk,
-                          n_k=n_k, group=group, s_valid=s),
+                          n_k=n_k, group=group, kv_heads=kv_heads,
+                          s_valid=s),
         grid_spec=grid_spec,
         out_shape=out_shapes,
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary"),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
     )(start_arr, *operands)
-    out = outs[0][:, :s]
+    out = outs[0].reshape(b, kv_heads, sq, group, d).transpose(0, 2, 1, 3, 4)
+    out = out.reshape(b, sq, h, d)[:, :s]
     if return_block_counts:
         return out, outs[1]
     return out

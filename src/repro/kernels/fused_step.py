@@ -59,12 +59,58 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core import quant
 from repro.core.cim import output_noise_std_int_per_tile
 from repro.core.prng import seed_from_key, tile_gaussian
-from repro.kernels._compat import CompilerParams as _CompilerParams
 from repro.kernels.decode_attention import NEG_INF, _pick_block_k
 
 # projection order == the unfused layer's dense-call (and next_key) order
 _ROLES = ("attn_qkv", "attn_qkv", "attn_qkv", "attn_out",
           "mlp_in", "mlp_in", "mlp_out")
+
+
+# scoped VMEM a pallas_call may use on TPU v5e unless it raises the limit
+# itself (this kernel does not)
+VMEM_LIMIT_BYTES = 16 * 2**20
+
+
+def vmem_bytes(cfg, max_slots: int, max_len: int, sim: bool) -> int:
+    """VMEM the megakernel needs for one layer, from the config's widths.
+
+    Counts what ``fused_dense_layer`` keeps resident: the seven projection
+    planes as whole-array blocks (int8 in sim, the weight dtype in off),
+    double-buffered by the Pallas pipeline, plus the f32/int32 copy of the
+    widest plane each projection widens in-kernel, plus the double-buffered
+    K/V cache blocks of the attention sweep.
+    """
+    d, f, h, kv, hd = cfg.d_model, cfg.d_ff, cfg.n_heads, cfg.n_kv_heads, cfg.hd
+    planes = [d * h * hd, d * kv * hd, d * kv * hd, h * hd * d,
+              d * f, d * f, f * d]
+    w_bytes = 1 if sim else jnp.dtype(cfg.dtype).itemsize
+    kv_bytes = 1 if cfg.kv_cache_int8 else jnp.dtype(cfg.dtype).itemsize
+    bk = _pick_block_k(max_len, 128)
+    return (2 * sum(planes) * w_bytes + 4 * max(planes)
+            + 2 * 2 * max_slots * bk * kv * hd * kv_bytes)
+
+
+def check_fused_layer(cfg, max_slots: int, max_len: int, sim: bool) -> None:
+    """Raise ValueError where the megakernel cannot serve this config.
+
+    The kernel runs on float32 activations only (``_use_fused_layer``
+    routes any other dtype to the per-layer path, which would turn
+    ``fuse_layer`` into a silent no-op), and its resident working set must
+    fit the scoped VMEM limit; the seven whole-array weight blocks do not
+    stream over a grid axis.
+    """
+    if jnp.dtype(cfg.dtype) != jnp.float32:
+        raise ValueError(
+            f"fuse_layer needs float32 activations; {cfg.name} runs "
+            f"{cfg.dtype}, which the dense megakernel does not take")
+    need = vmem_bytes(cfg, max_slots, max_len, sim)
+    if need > VMEM_LIMIT_BYTES:
+        raise ValueError(
+            f"fuse_layer does not fit {cfg.name}: the dense megakernel "
+            f"keeps {need / 2**20:.1f} MiB of one layer resident in VMEM "
+            f"(whole-array projection weights, double-buffered), over the "
+            f"{VMEM_LIMIT_BYTES / 2**20:.0f} MiB scoped limit; serve with "
+            f"fuse_layer=False")
 
 
 def _rms(xf: jnp.ndarray, g: jnp.ndarray, eps: float) -> jnp.ndarray:
@@ -138,10 +184,15 @@ def _kernel(lens_ref, lmax_ref, *refs, b: int, d: int, h: int, kv: int, hd: int,
         q = _proj(h1, 0, xs).reshape(b, h, hd)
         k = _proj(h1, 1, xs).reshape(b, kv, hd)
         v = _proj(h1, 2, xs).reshape(b, kv, hd)
-        # rope at the query position lens[b]-1 (== cache len before write)
-        pos = (lens_ref[...] - 1).astype(jnp.float32)           # (B,)
-        expnt = (jax.lax.broadcasted_iota(jnp.float32, (hd // 2,), 0)
-                 * 2.0) / hd
+        # rope at the query position lens[b]-1 (== cache len before write);
+        # SMEM holds scalars only, so the (B,) vector is assembled per row
+        rows = jax.lax.broadcasted_iota(jnp.int32, (b,), 0)
+        pos = jnp.zeros((b,), jnp.int32)
+        for bi in range(b):
+            pos = jnp.where(rows == bi, lens_ref[bi] - 1, pos)
+        pos = pos.astype(jnp.float32)                           # (B,)
+        expnt = (jax.lax.broadcasted_iota(jnp.int32, (hd // 2,), 0)
+                 .astype(jnp.float32) * 2.0) / hd
         freqs = 1.0 / (theta ** expnt)
         ang = pos[:, None] * freqs[None, :]                     # (B, hd/2)
         cos = jnp.cos(ang)[:, None, :]
@@ -333,7 +384,7 @@ def fused_dense_layer(ctx, p, x, cache):
             macro_rows=macro_rows),
         grid_spec=grid_spec,
         out_shape=out_shape,
-        compiler_params=_CompilerParams(dimension_semantics=("arbitrary",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(lens, jnp.max(lens).reshape(1), *operands)
 

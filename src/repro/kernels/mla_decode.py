@@ -36,7 +36,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
 from repro.kernels.decode_attention import NEG_INF, _pick_block_k
 
 
@@ -145,7 +144,7 @@ def mla_decode_attention(
         functools.partial(_kernel, scale=scale, block_k=bk, n_kb=n_kb),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, lat), q_lat.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -83,9 +83,8 @@ def cim_matmul_int(
     scale: scalar dequant factor applied in the epilogue (None -> 1.0).
     force: None (auto), "pallas", "pallas_interpret", "ref".
     bm/bn: kernel block shape; None auto-selects (decode-shaped M gets a
-      skinny tile — 8 rows in interpret mode, 32 on compiled TPU — instead
-      of a 256-row pad; bit-identical under threefry, statistically
-      equivalent under the TPU hw PRNG whose stream depends on the grid).
+      skinny tile — the next multiple of 8 rows — instead of a 256-row pad;
+      bit-identical under the threefry PRNG).
     """
     mode = force or ("pallas" if _use_pallas() else "ref")
     if mode in ("pallas", "pallas_interpret"):

@@ -36,7 +36,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
 
 
 def _kernel(conv_ref, xbc_ref, w_ref, b_ref, dt_ref, a_ref, dsk_ref,
@@ -147,7 +146,7 @@ def ssm_decode_step(
             jax.ShapeDtypeStruct((b, win, conv_dim), conv_cache.dtype),
             jax.ShapeDtypeStruct((b, nheads, headdim, d_state), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
         ),
         interpret=interpret,

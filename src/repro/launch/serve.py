@@ -68,6 +68,7 @@ import jax
 import numpy as np
 
 from repro.configs.registry import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model import build
 from repro.serving.engine import Engine, LoopEngine, Request, RequestError
 
@@ -430,6 +431,7 @@ async def _run_frontend(args, engine, cfg):
 
 def main():
     args = _build_argparser().parse_args()
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()

@@ -23,7 +23,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.models.layers import Ctx, Params, _init_dense, dense, init_swiglu, swiglu
-from repro.distributed.sharding import shard, shard_map
+from repro.distributed.sharding import shard
 
 
 def init_moe(key, cfg: ModelConfig, dtype=jnp.float32):
@@ -210,7 +210,7 @@ def _smap_dispatch(mesh, dp_ax, dtype, xg, e_idx, pos_idx, keep,
 
         return jax.vmap(one)(xg_l, e_rel, pos_l, ok)
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(dp_ax, None, None), P(dp_ax, None), P(dp_ax, None),
                   P(dp_ax, None)),
@@ -236,7 +236,7 @@ def _smap_combine(mesh, dp_ax, dtype, out, e_idx, pos_idx, keep, gates,
         y = jax.vmap(one)(out_l, e_rel, pos_l, ok, gat_l)
         return jax.lax.psum(y, "model")
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(dp_ax, "model", None, None), P(dp_ax, None),
                   P(dp_ax, None), P(dp_ax, None), P(dp_ax, None)),

@@ -159,6 +159,18 @@ def _validate_requests(requests: List[Request], max_len: int) -> None:
                 f"shorten the request")
 
 
+def _host_snapshot(a: np.ndarray) -> jnp.ndarray:
+    """Device copy of a host array the scheduler mutates in place.
+
+    ``jnp.asarray`` may alias a numpy buffer and read it only when the
+    asynchronously dispatched program runs; a slot recycled in between
+    (``_rk_slot[s] = 0``) would then change the inputs of a step already
+    issued. A private copy keeps every step's inputs what they were at
+    dispatch.
+    """
+    return jnp.asarray(np.array(a))
+
+
 def _request_uid(r: Request, fallback: int) -> int:
     """Stable 31-bit uid behind the per-request sampling key."""
     if r.rid:
@@ -174,17 +186,8 @@ def _pow2_bucket(n: int, lo: int = 8) -> int:
 
 
 def _jit_cache_size(jitted) -> int:
-    """Compiled-trace count behind a ``jax.jit`` callable, or -1.
-
-    ``_cache_size`` is a private jax API (present on 0.4.37, the pinned
-    toolchain). The trace count is a bench/CI *metric*, not a correctness
-    input — a jax upgrade that renames the API must degrade the metric to
-    -1, not crash the engine.
-    """
-    try:
-        return int(jitted._cache_size())
-    except Exception:
-        return -1
+    """Compiled-trace count behind a ``jax.jit`` callable."""
+    return int(jitted._cache_size())
 
 
 def _apply_attn_impl(cfg: ModelConfig, attn_impl: Optional[str]) -> ModelConfig:
@@ -281,7 +284,8 @@ class Engine:
                  ladder: Any = None,
                  drift: Any = None,
                  calib: Any = None,
-                 replica: Optional[str] = None):
+                 replica: Optional[str] = None,
+                 device: Any = None):
         # replica label (PR 10 scale-out): stamped onto every RequestError
         # this engine produces so the router/serve.py can attribute failover
         # causes; None for a standalone engine.
@@ -339,6 +343,12 @@ class Engine:
         self._alloc_len = (-(-max_len // self.chunk_size) * self.chunk_size
                            if self.chunk_size else max_len)
         mode = cim_mode if cim_mode is not None else cfg.cim.mode
+        if cfg.fuse_layer:
+            # refused here, not skipped per call: a megakernel that cannot
+            # run would otherwise serve silently on the per-layer path
+            from repro.kernels.fused_step import check_fused_layer
+            check_fused_layer(cfg, max_slots, self._alloc_len,
+                              sim=mode == "sim")
         # deploy=None auto-deploys pre-quantized weight planes for sim-mode
         # serving (core.deploy, DESIGN.md §12): weights are programmed once
         # per engine like the macro's weight-stationary array, instead of
@@ -429,6 +439,14 @@ class Engine:
         # layout (segments) matches what guarded_dense will check against
         self.params = _maybe_deploy(cfg, params, self.deployed, fault=fault,
                                     guard=self.guard)
+        # device=None serves on the default device. A replica of a pool is
+        # given its own device: params, cache, token and key state are
+        # committed there, so every program of this engine runs on it (host
+        # inputs follow the committed arguments)
+        self.device = device
+        place = ((lambda t: t) if device is None
+                 else (lambda t: jax.device_put(t, device)))
+        self.params = place(self.params)
 
         # drift clock + background calibration controller. The step counter
         # is monotonic for the engine's lifetime (macro age — begin() does
@@ -455,8 +473,9 @@ class Engine:
                 use_kernel=cfg.cim.use_kernel)
 
         # allocated once; recycled for the lifetime of the engine
-        self.caches = tf.init_caches(cfg, max_slots, self._alloc_len)
-        self.last_tok = jnp.zeros((max_slots,), jnp.int32)
+        self.caches = place(tf.init_caches(cfg, max_slots, self._alloc_len))
+        self.last_tok = place(jnp.zeros((max_slots,), jnp.int32))
+        self.key = place(self.key)
         deployed = self.deployed
         guard_on = self.guard is not None
         gspec, fspec = self.guard, self.fault
@@ -686,16 +705,24 @@ class Engine:
 
         # donate only the cache: last_tok/toks arrays stay referenced by the
         # pending-drain token log until device_get, so they must not alias
-        self._prefill = jax.jit(prefill_fn, donate_argnums=(1,))
-        self._prefill_chunk = jax.jit(prefill_chunk_fn, donate_argnums=(1,))
-        self._decode = jax.jit(decode_fn, donate_argnums=(1,))
-        self._step = jax.jit(step_fn, donate_argnums=(1,))
+        self._programs = {
+            "prefill": jax.jit(prefill_fn, donate_argnums=(1,)),
+            "prefill_chunk": jax.jit(prefill_chunk_fn, donate_argnums=(1,)),
+            "decode": jax.jit(decode_fn, donate_argnums=(1,)),
+            "step": jax.jit(step_fn, donate_argnums=(1,)),
+        }
+        self._built: set = set()
+        self._prefill = self._programs["prefill"]
+        self._prefill_chunk = self._programs["prefill_chunk"]
+        self._decode = self._programs["decode"]
+        self._step = self._programs["step"]
         self._draw_keys = jax.jit(draw_keys_fn)
         # fused_step=None -> auto: collapse each scheduler iteration into
         # the single _step launch whenever prefill is chunked and the guard
         # is off (guard escalation needs per-slot host-side blame, which the
-        # all-or-nothing fused launch cannot assign). An engine that ever
-        # sees _step raise falls back to the per-call path for its lifetime.
+        # all-or-nothing fused launch cannot assign). An engine whose _step
+        # raises at run time falls back to the per-call path for its
+        # lifetime; one that cannot trace or compile it raises (_build).
         if fused_step is None:
             fused_step = self.guard is None and self.chunk_size > 0
         elif fused_step and (self.guard is not None or self.chunk_size == 0):
@@ -717,14 +744,9 @@ class Engine:
     @property
     def prefill_traces(self) -> int:
         """Distinct prefill programs traced: 1 for chunked prefill, one per
-        power-of-two bucket for the whole-prompt path (-1 if the private
-        trace-count API is unavailable on this jax)."""
-        sizes = (_jit_cache_size(self._prefill),
-                 _jit_cache_size(self._prefill_chunk),
-                 _jit_cache_size(self._step))
-        if any(s < 0 for s in sizes):
-            return -1
-        return sum(sizes)
+        power-of-two bucket for the whole-prompt path."""
+        return sum(_jit_cache_size(self._programs[n])
+                   for n in ("prefill", "prefill_chunk", "step"))
 
     # -------------------------------------------- incremental session API
     def begin(self) -> None:
@@ -1112,13 +1134,13 @@ class Engine:
         """(pin, frow) closure extras: batch-1 row ``s`` views."""
         if self.guard is None:
             return ()
-        return (jnp.asarray(self._pinned[s:s + 1]),
+        return (_host_snapshot(self._pinned[s:s + 1]),
                 jnp.asarray(self._frow_host[s:s + 1]))
 
     def _guard_batch_args(self):
         if self.guard is None:
             return ()
-        return (jnp.asarray(self._pinned), jnp.asarray(self._frow_host))
+        return (_host_snapshot(self._pinned), jnp.asarray(self._frow_host))
 
     def _admit(self, s: int, r: Request) -> None:
         ri = self._req_index[id(r)]
@@ -1153,15 +1175,16 @@ class Engine:
                 # oversized bucket) fails *this* request, not the batch;
                 # the next occupant's zero-reset re-initialises the slot
                 self._slots[s] = r
-                try:
-                    self.launch_count += 1
-                    out = self._prefill(
-                        self.params, self.caches, self.last_tok,
+                args = (self.params, self.caches, self.last_tok,
                         jnp.asarray(padded), true_len, s,
                         float(r.temperature), self._next_key(),
-                        jnp.asarray(self._rk_slot[s]),
+                        _host_snapshot(self._rk_slot[s]),
                         np.int32(self._lvl_slot[s]), self._dstate(),
                         *self._guard_args(s))
+                self._build("prefill", args, variant=bucket)
+                try:
+                    self.launch_count += 1
+                    out = self._prefill(*args)
                 except Exception as e:     # noqa: BLE001
                     self._fail_request(s, RequestError(
                         reason=f"prefill failed: {e!r}", phase="prefill",
@@ -1200,16 +1223,17 @@ class Engine:
             chunk = np.zeros((1, self.chunk_size), np.int32)
             chunk[0, :valid] = prompt[off:off + valid]
             is_final = off + valid >= prompt.shape[0]
-            try:
-                self.launch_count += 1
-                out = self._prefill_chunk(
-                    self.params, self.caches, self.last_tok,
+            args = (self.params, self.caches, self.last_tok,
                     jnp.asarray(chunk), jnp.asarray(off == 0),
                     jnp.asarray(valid, jnp.int32), jnp.asarray(is_final),
                     s, float(r.temperature), self._next_key(),
-                    jnp.asarray(self._rk_slot[s]),
+                    _host_snapshot(self._rk_slot[s]),
                     np.int32(self._lvl_slot[s]), self._dstate(),
                     *self._guard_args(s))
+            self._build("prefill_chunk", args)
+            try:
+                self.launch_count += 1
+                out = self._prefill_chunk(*args)
             except Exception as e:         # noqa: BLE001
                 self._fail_request(s, RequestError(
                     reason=f"prefill chunk failed: {e!r}", phase="prefill",
@@ -1272,8 +1296,8 @@ class Engine:
                 self.launch_count += 1
                 out = self._decode(
                     self.params, self.caches, toks, jnp.asarray(solo),
-                    temps, step_key, jnp.asarray(self._rk_slot),
-                    jnp.asarray(tok_idx), jnp.asarray(self._lvl_slot),
+                    temps, step_key, _host_snapshot(self._rk_slot),
+                    jnp.asarray(tok_idx), _host_snapshot(self._lvl_slot),
                     self._dstate(), *self._guard_batch_args())
                 self.caches, toks = out[:2]
                 if guard_on:
@@ -1304,12 +1328,13 @@ class Engine:
         dead_errs: Dict[int, RequestError] = {}
         gdead: List[int] = []
         self.launch_count += 1
-        try:
-            out = self._decode(
-                self.params, self.caches, self.last_tok, active, temps,
-                step_key, jnp.asarray(self._rk_slot), jnp.asarray(tok_idx),
-                jnp.asarray(self._lvl_slot), self._dstate(),
+        args = (self.params, self.caches, self.last_tok, active, temps,
+                step_key, _host_snapshot(self._rk_slot), jnp.asarray(tok_idx),
+                _host_snapshot(self._lvl_slot), self._dstate(),
                 *self._guard_batch_args())
+        self._build("decode", args)
+        try:
+            out = self._decode(*args)
             self.caches, toks = out[:2]
             if guard_on:
                 gdead = self._note_guard(
@@ -1413,13 +1438,14 @@ class Engine:
                   for s in range(n_slots)]
         meta_d = [self._req_index[id(self._slots[s])] if act_after[s]
                   else None for s in range(n_slots)]
-        try:
-            self.launch_count += 1
-            caches, toks, ptoks = self._step(
-                self.params, self.caches, self.last_tok,
+        args = (self.params, self.caches, self.last_tok,
                 jnp.asarray(chunk_toks), jnp.asarray(flags),
                 jnp.asarray(temps_now), key_rows,
-                jnp.asarray(self._rk_slot), self._dstate())
+                _host_snapshot(self._rk_slot), self._dstate())
+        self._build("step", args)
+        try:
+            self.launch_count += 1
+            caches, toks, ptoks = self._step(*args)
         except Exception:                  # noqa: BLE001
             self._fused_ok = False
             return False
@@ -1456,6 +1482,21 @@ class Engine:
     def _next_key(self):
         self.key, k = jax.random.split(self.key)
         return k
+
+    def _build(self, name: str, args: tuple, variant: Any = None) -> None:
+        """Trace and compile program ``name`` for ``args`` once per engine.
+
+        Runs ahead of the call sites' failure isolation, so an error from
+        tracing, lowering or compiling propagates to the caller instead of
+        becoming a ``RequestError`` or the per-call fallback: a program the
+        compiler refuses is a broken engine, not a bad request. The jit
+        call that follows reuses the compiled executable. ``variant`` keys
+        programs compiled per shape (the whole-prompt prefill buckets).
+        """
+        if (name, variant) in self._built:
+            return
+        self._programs[name].lower(*args).compile()
+        self._built.add((name, variant))
 
 
 class LoopEngine:
@@ -1587,6 +1628,21 @@ class LoopEngine:
     def _next_key(self):
         self.key, k = jax.random.split(self.key)
         return k
+
+    def _build(self, name: str, args: tuple, variant: Any = None) -> None:
+        """Trace and compile program ``name`` for ``args`` once per engine.
+
+        Runs ahead of the call sites' failure isolation, so an error from
+        tracing, lowering or compiling propagates to the caller instead of
+        becoming a ``RequestError`` or the per-call fallback: a program the
+        compiler refuses is a broken engine, not a bad request. The jit
+        call that follows reuses the compiled executable. ``variant`` keys
+        programs compiled per shape (the whole-prompt prefill buckets).
+        """
+        if (name, variant) in self._built:
+            return
+        self._programs[name].lower(*args).compile()
+        self._built.add((name, variant))
 
     def _sample(self, logits: jnp.ndarray, temperature: float) -> int:
         if temperature <= 0:

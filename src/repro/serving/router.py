@@ -40,7 +40,6 @@ exactly.
 
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import time
 from typing import Any, Dict, List, Optional
@@ -587,9 +586,9 @@ def build_pool(cfg, params, n_replicas: int,
 
     The shared ``seed`` is what makes migration deterministic: per-request
     sampling keys depend only on (seed, rid), so any replica replays any
-    rid bit-for-bit in off mode. ``devices`` places replica i's caches and
-    compute on ``devices[i % len]`` (the forced-host-device mesh of the
-    scaleout bench). A ``ReplicaFaultSpec(mode="storm")`` victim is built
+    rid bit-for-bit in off mode. ``devices`` commits replica i's params,
+    caches and compute to ``devices[i % len]`` (one chip per replica, or
+    the forced-host-device mesh of the scaleout bench). A ``ReplicaFaultSpec(mode="storm")`` victim is built
     with the spec's aggressive FaultSpec on every slot — its health decays
     through guard telemetry rather than a router-injected event (pass
     ``guard=`` in engine_kwargs; the storm disturbance acts through the
@@ -608,9 +607,7 @@ def build_pool(cfg, params, n_replicas: int,
         if i == storm_victim:
             kw["fault"] = replica_fault.storm_fault()
             kw["fault_slots"] = range(kw.get("max_slots", 4))
-        ctx = (jax.default_device(devices[i % len(devices)])
-               if devices else contextlib.nullcontext())
-        with ctx:
-            engines.append(Engine(cfg, params, seed=seed,
-                                  replica=f"r{i}", **kw))
+        engines.append(Engine(
+            cfg, params, seed=seed, replica=f"r{i}",
+            device=devices[i % len(devices)] if devices else None, **kw))
     return engines

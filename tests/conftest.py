@@ -7,19 +7,6 @@ os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
-# The target container ships without `hypothesis` (and without network to
-# install it); fall back to the deterministic stub so the property tests
-# still run. The real package always wins when present.
-try:
-    import hypothesis  # noqa: F401
-except ImportError:
-    sys.path.insert(0, os.path.dirname(__file__))
-    import _hypothesis_stub
-
-    sys.modules["hypothesis"] = _hypothesis_stub
-    sys.modules["hypothesis.strategies"] = _hypothesis_stub.strategies
-
-
 # Per-test wall-clock guard (CI sets REPRO_TEST_TIMEOUT, seconds): a wedged
 # scheduler loop (the failure class the §16 front-end suite exists to
 # catch) must fail ONE test with a traceback, not eat the whole job

@@ -23,6 +23,7 @@ from repro.core import quant
 from repro.core.cim import CIMSpec, cim_dense
 from repro.core.deploy import deploy, plane_summary, quantize_plane
 from repro.core.sac import get_policy
+from repro.launch.mesh import make_mesh
 from repro.models import transformer as tf
 from repro.models.layers import Ctx, dense
 from repro.models.model import build
@@ -273,7 +274,7 @@ def test_sharded_deploy_bit_identical_single_device():
 
     cfg = _tiny_dense_cfg()
     params, _ = build(cfg).init(jax.random.PRNGKey(0))
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     plain = deploy(cfg, params, guard=True)
     sharded = deploy(cfg, params, guard=True, rules=default_rules(mesh))
 
@@ -329,7 +330,7 @@ def test_plan_matches_live_rules_resolution():
     cfg = _tiny_dense_cfg()
     vm_plan = plan_deploy_sharding(cfg, default_rules(VirtualMesh.make(
         data=1, model=1)))
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     live_plan = plan_deploy_sharding(cfg, default_rules(mesh))
     assert vm_plan["ok"] and live_plan["ok"]
     a = {e["path"] + "/" + e["plane"]: e["spec"] for e in vm_plan["entries"]}
@@ -346,7 +347,7 @@ def test_deploy_sharded_guard_segments_compose():
 
     cfg = _tiny_dense_cfg()
     params, _ = build(cfg).init(jax.random.PRNGKey(0))
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     plain = deploy(cfg, params, guard=GuardSpec(segments=4))
     shard = deploy(cfg, params, guard=GuardSpec(segments=4),
                    rules=default_rules(mesh))

@@ -11,11 +11,12 @@ PROG = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     import json
     import jax
+    from repro.launch.mesh import make_mesh
     import jax.numpy as jnp
     import numpy as np
     from repro.distributed.pipeline import pipeline_apply
 
-    mesh = jax.make_mesh((4,), ("pod",))
+    mesh = make_mesh((4,), ("pod",))
     n_stage, b, d = 4, 8, 16
     key = jax.random.PRNGKey(0)
     ws = jax.random.normal(key, (n_stage, d, d)) / jnp.sqrt(d)

@@ -11,6 +11,11 @@ another router run.
 
 import asyncio
 import dataclasses
+import json
+import os
+import subprocess
+import sys
+import textwrap
 
 import jax
 import numpy as np
@@ -264,3 +269,61 @@ def test_failed_request_carries_replica_tag(setup):
     (serve.py prints it); router-level route errors stringify with it."""
     err = RequestError(reason="boom", phase="decode", replica="r2")
     assert "r2:" in str(err)
+
+
+PLACEMENT_PROG = textwrap.dedent("""
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
+    import dataclasses, json
+    import jax
+    import numpy as np
+    from repro.configs.registry import get_config
+    from repro.models.model import build
+    from repro.serving.engine import Engine, Request
+    from repro.serving.router import ReplicaRouter, build_pool
+
+    cfg = get_config("qwen2-0.5b").reduced()
+    cfg = dataclasses.replace(cfg, n_layers=2, d_model=128, d_ff=256,
+                              vocab_size=128, n_heads=4, n_kv_heads=2,
+                              head_dim=32)
+    params, _ = build(cfg).init(jax.random.PRNGKey(0))
+    rng = np.random.default_rng(0)
+    prompts = [rng.integers(0, 128, 5 + i, dtype=np.int32) for i in range(4)]
+
+    def reqs():
+        return [Request(prompt=p, max_new_tokens=6, temperature=0.7 * (i % 2),
+                        rid=f"q{i}") for i, p in enumerate(prompts)]
+
+    kw = dict(max_slots=2, max_len=32, cim_mode="off")
+    devs = jax.devices()[:2]
+    pool = build_pool(cfg, params, 2, devices=devs, **kw)
+
+    def where(e):
+        leaves = jax.tree.leaves((e.params, e.caches, e.last_tok))
+        return sorted({str(d) for x in leaves for d in x.devices()})
+
+    before = [where(e) for e in pool]
+    out = ReplicaRouter(pool).generate(reqs())
+    ref = Engine(cfg, params, **kw).generate(reqs())
+    print(json.dumps({"devices": [str(d) for d in devs], "before": before,
+                      "after": [where(e) for e in pool],
+                      "same": out == ref}))
+""")
+
+
+def test_pool_replicas_committed_to_their_devices():
+    """build_pool(devices=) commits each replica's params, cache and token
+    state to its own device, where they stay while it serves; the pool's
+    streams equal a single engine's. (Construction under a default-device
+    context alone left every program on the first device.)"""
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    env["JAX_PLATFORMS"] = "cpu"
+    out = subprocess.run([sys.executable, "-c", PLACEMENT_PROG], env=env,
+                         capture_output=True, text=True, timeout=600)
+    assert out.returncode == 0, out.stderr[-3000:]
+    res = json.loads(out.stdout.strip().splitlines()[-1])
+    want = [[d] for d in res["devices"]]
+    assert res["before"] == want
+    assert res["after"] == want
+    assert res["same"] is True

@@ -195,19 +195,6 @@ def test_record_ttft(dense_setup):
     assert all(t is not None and t > 0 for t in eng.ttft_s)
 
 
-def test_prefill_traces_degrades_without_private_api(dense_setup):
-    """prefill_traces rides jax's private ``_cache_size``; on a jax that
-    drops it the metric must degrade to -1, not crash (bench/CI guard)."""
-    cfg, params = dense_setup
-    eng = Engine(cfg, params, max_slots=1, max_len=16)
-
-    class _NoCacheSize:
-        pass
-
-    eng._prefill = _NoCacheSize()
-    assert eng.prefill_traces == -1
-
-
 # --------------------------------------------------------- prefill buckets
 
 
@@ -351,6 +338,57 @@ def test_kernel_attn_impl_accepted_everywhere_bogus_rejected():
     with pytest.raises(ValueError, match="attn_impl"):
         LoopEngine(get_config("qwen2-0.5b").reduced(), params=None,
                    max_slots=1, max_len=8, attn_impl="flash")
+
+
+# ------------------------------------------- build errors are not requests
+
+
+def _trace_failure(*args):
+    raise TypeError("refused while tracing")
+
+
+@pytest.mark.parametrize("program,kw", [
+    ("step", {}),
+    ("decode", {"fused_step": False}),
+    ("prefill_chunk", {"fused_step": False}),
+])
+def test_build_error_propagates(dense_setup, program, kw):
+    """A program that fails to trace or compile raises out of generate():
+    it never becomes a per-request RequestError and never flips the fused
+    engine onto the per-call path."""
+    cfg, params = dense_setup
+    eng = Engine(cfg, params, max_slots=2, max_len=64, chunk_size=8, **kw)
+    broken = jax.jit(_trace_failure)
+    eng._programs[program] = broken
+    setattr(eng, "_" + program, broken)
+    with pytest.raises(TypeError, match="refused while tracing"):
+        eng.generate(_ragged_requests(cfg, [5, 9], np.random.default_rng(0)))
+    assert eng._fused_ok
+    assert all(e is None for e in eng.request_errors)
+
+
+def test_sampled_streams_repeat_across_sessions(dense_setup):
+    """Sampled (temperature > 0) streams depend only on (seed, rid): the
+    same engine replays them across sessions, and the per-call path agrees
+    with the fused step. Guards the host-state snapshot: a request key
+    mutated in place after dispatch (slot recycling) must not reach a step
+    that was already issued."""
+    cfg, params = dense_setup
+    rng = np.random.default_rng(3)
+    prompts = [rng.integers(0, cfg.vocab_size, 24, dtype=np.int32)
+               for _ in range(2)]
+
+    def reqs():
+        return [Request(prompt=p, max_new_tokens=6, temperature=t,
+                        rid=f"r{i}")
+                for i, (p, t) in enumerate(zip(prompts, (0.0, 0.7)))]
+
+    fused = Engine(cfg, params, max_slots=2, max_len=48, chunk_size=4)
+    percall = Engine(cfg, params, max_slots=2, max_len=48, chunk_size=4,
+                     fused_step=False)
+    runs = [fused.generate(reqs()) for _ in range(3)]
+    runs += [percall.generate(reqs()) for _ in range(3)]
+    assert all(r == runs[0] for r in runs), runs
 
 
 # ----------------------------------------- per-request failure isolation

@@ -51,6 +51,7 @@ SUBPROCESS_PROG = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import json
     import jax
+    from repro.launch.mesh import make_mesh
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.configs.registry import get_config
@@ -58,7 +59,7 @@ SUBPROCESS_PROG = textwrap.dedent("""
     from repro.models.model import build, param_specs
     import dataclasses
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     cfg = get_config("internlm2-1.8b").reduced()
     api = build(cfg)
     rules = default_rules(mesh)
@@ -111,11 +112,12 @@ COMPRESSION_PROG = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import json
     import jax
+    from repro.launch.mesh import make_mesh
     import jax.numpy as jnp
     import numpy as np
     from repro.distributed.compression import compressed_dp_grads
 
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = make_mesh((8,), ("data",))
     params = {"w": jnp.linspace(-1, 1, 64).reshape(8, 8)}
     batch = {"x": jnp.arange(32.0).reshape(8, 4) / 32.0}
 
@@ -149,20 +151,21 @@ ELASTIC_PROG = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import json
     import jax
+    from repro.launch.mesh import make_mesh
     import jax.numpy as jnp
     import numpy as np
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.training.checkpoint import CheckpointManager
 
     # save from a 4-way DP layout, restore onto 8-way (elastic rescale)
-    mesh4 = jax.make_mesh((4,), ("data",))
+    mesh4 = make_mesh((4,), ("data",))
     state = {"w": jnp.arange(64.0).reshape(8, 8)}
     sharded4 = jax.device_put(state, jax.tree.map(
         lambda _: NamedSharding(mesh4, P("data")), state))
     ckpt = CheckpointManager("/tmp/elastic_ckpt_test", keep=1)
     ckpt.save(1, sharded4)
 
-    mesh8 = jax.make_mesh((8,), ("data",))
+    mesh8 = make_mesh((8,), ("data",))
     restored, meta = ckpt.restore(1, state, shardings=jax.tree.map(
         lambda _: NamedSharding(mesh8, P("data")), state))
     ok = bool(jnp.all(restored["w"] == state["w"]))
@@ -216,6 +219,7 @@ SHARDED_DEPLOY_PROG = textwrap.dedent("""
     import json
     import dataclasses
     import jax
+    from repro.launch.mesh import make_mesh
     import jax.numpy as jnp
     import numpy as np
     from jax.sharding import NamedSharding
@@ -229,7 +233,7 @@ SHARDED_DEPLOY_PROG = textwrap.dedent("""
                               vocab_size=128, n_heads=4, n_kv_heads=2,
                               head_dim=32)
     params, _ = build(cfg).init(jax.random.PRNGKey(0))
-    mesh = jax.make_mesh((1, 2), ("data", "model"))
+    mesh = make_mesh((1, 2), ("data", "model"))
     plain = deploy(cfg, params, guard=True)
     shard = deploy(cfg, params, guard=True, rules=default_rules(mesh))
 
