@@ -160,12 +160,14 @@ def _launch_witness(cfg, params) -> dict:
     b = percall.generate(reqs())
     assert a == b, "fused-step scheduler diverged from the per-call path"
     assert fused._fused_ok, "fused engine silently fell back to per-call"
+
+    def per_iter(eng):
+        return eng.counters.launches / max(eng.counters.iterations, 1)
+
     return {
-        "launches_per_iter_fused": fused.launch_count / max(fused.iter_count, 1),
-        "launches_per_iter_percall": (percall.launch_count
-                                      / max(percall.iter_count, 1)),
-        "launch_drop_x": (percall.launch_count / max(percall.iter_count, 1))
-                         / (fused.launch_count / max(fused.iter_count, 1)),
+        "launches_per_iter_fused": per_iter(fused),
+        "launches_per_iter_percall": per_iter(percall),
+        "launch_drop_x": per_iter(percall) / per_iter(fused),
     }
 
 

@@ -50,22 +50,37 @@ each active slot solo against the same compiled program), or guard
 hard-fail — yield a structured ``RequestError`` (reason, phase, slot,
 retryable) at their position in the results list (never an exception), the
 slot is recycled token-clean, and the rest of the batch is unaffected.
+
+Observability: each phase of a scheduler iteration runs inside a
+``jax.profiler.TraceAnnotation`` span — ``engine.step`` around the
+iteration, holding ``engine.fill`` (slot admission), ``engine.stage``
+(host arrays, transfers, key draws) and one ``engine.launch.<program>``
+per jitted call, which carries the rows it computes (``rows``,
+``pad_rows``, ``decode_rows``, ``prefill_rows``); ``engine.drain`` around
+the blocking device->host token copy. With no profiler running a span
+costs about a microsecond and touches nothing on the device.
+``Engine.counters`` (``serving.metrics.ServingCounters``) keeps the same
+counts, cumulative over the engine's lifetime.
 """
 
 from __future__ import annotations
 
 import dataclasses
 import time
+import traceback
 import zlib
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import monitoring
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ModelConfig
 from repro.models import transformer as tf
 from repro.models.layers import Ctx
+from repro.serving.metrics import ServingCounters, note_compile_event
 
 # default prefill chunk: small enough to bound the decode stall a chunk
 # inserts, large enough that the per-chunk dispatch/attention overhead
@@ -183,6 +198,18 @@ def _pow2_bucket(n: int, lo: int = 8) -> int:
     while b < n:
         b *= 2
     return b
+
+
+_COMPILES_WATCHED = False
+
+
+def _watch_compiles() -> None:
+    """Count JAX traces and compiles for ``ServingCounters``: one
+    listener per process, however many engines it builds."""
+    global _COMPILES_WATCHED
+    if not _COMPILES_WATCHED:
+        monitoring.register_event_duration_secs_listener(note_compile_event)
+        _COMPILES_WATCHED = True
 
 
 def _jit_cache_size(jitted) -> int:
@@ -732,10 +759,12 @@ class Engine:
                 "failure isolation and no whole-prompt admission path")
         self._fused_step = bool(fused_step)
         self._fused_ok = True
-        # dispatch witness (serving_bench): jitted program launches and
-        # scheduler iterations since the last generate() call
-        self.launch_count = 0
-        self.iter_count = 0
+        # the first error that made the fused iteration fall back
+        self.fused_step_error: Optional[str] = None
+        # cumulative work counts over the engine's lifetime; launches and
+        # iterations are the dispatch witness of serving_bench
+        self.counters = ServingCounters()
+        _watch_compiles()
         self._frow_host = np.array([s in self.fault_slots
                                     for s in range(self.max_slots)])
         self.begin()
@@ -784,8 +813,6 @@ class Engine:
         self.status: List[str] = []                   # per-request lifecycle
         self.request_errors = []
         self.ttft_s = []
-        self.launch_count = 0
-        self.iter_count = 0
         self._t0 = time.perf_counter()
         self._turnover = False
 
@@ -892,25 +919,28 @@ class Engine:
             # a hung launch: the call "succeeds" but nothing advances —
             # only the router's no-progress watchdog can tell
             return True
-        if now is not None:
-            self.expire_deadlines(now)
-        self._fill_slots()
-        if not any(r is not None for r in self._slots):
-            return False
-        self.iter_count += 1
-        self._turnover = False
-        if self._fused_step and self._fused_ok and self._fused_iteration():
-            if self._turnover:
-                self._fill_slots()
-        else:
-            self._percall_iteration()
-        if self.drift is not None:
-            # background calibration/watchdog (at most ONE bounded probe
-            # launch — no decode stall), then advance the macro's clock
-            self._drift_tick()
-        if len(self._pend) >= self.drain_every:
-            self.drain_pending()
-        return True
+        with TraceAnnotation("engine.step"):
+            if now is not None:
+                self.expire_deadlines(now)
+            self._fill_slots()
+            if not any(r is not None for r in self._slots):
+                return False
+            self.counters.iterations += 1
+            self._turnover = False
+            if (self._fused_step and self._fused_ok
+                    and self._fused_iteration()):
+                if self._turnover:
+                    self._fill_slots()
+            else:
+                self._percall_iteration()
+            if self.drift is not None:
+                # background calibration/watchdog (at most ONE bounded
+                # probe launch — no decode stall), then advance the
+                # macro's clock
+                self._drift_tick()
+            if len(self._pend) >= self.drain_every:
+                self.drain_pending()
+            return True
 
     def kill(self, reason: str = "device lost") -> None:
         """Simulate whole-replica device loss (DESIGN.md §18).
@@ -938,15 +968,21 @@ class Engine:
                 f"replica {self.replica or '?'} dead: {self.dead}")
         if not self._pend:
             return
-        vals = jax.device_get([e[1] for e in self._pend])
-        for (kind, _, meta), v in zip(self._pend, vals):
-            if kind == "p":
-                self._reqs[meta].out_tokens.append(int(v))
-            else:
-                for s, ri in enumerate(meta):
-                    if ri is not None:
-                        self._reqs[ri].out_tokens.append(int(v[s]))
-        self._pend.clear()
+        n = 0
+        with TraceAnnotation("engine.drain"):
+            vals = jax.device_get([e[1] for e in self._pend])
+            for (kind, _, meta), v in zip(self._pend, vals):
+                if kind == "p":
+                    self._reqs[meta].out_tokens.append(int(v))
+                    n += 1
+                else:
+                    for s, ri in enumerate(meta):
+                        if ri is not None:
+                            self._reqs[ri].out_tokens.append(int(v[s]))
+                            n += 1
+            self._pend.clear()
+        self.counters.drains += 1
+        self.counters.tokens_drained += n
 
     def generate(self, requests: List[Request]) -> List[Any]:
         """Run all requests to completion; returns generated token lists.
@@ -1149,65 +1185,87 @@ class Engine:
         self._lvl_slot[s] = self._levels[ri]
         self._reset_slot_guard(s)
 
+    def _launch(self, program: str, decode: int = 0, idle: int = 0,
+                prefill: int = 0, pad: int = 0) -> TraceAnnotation:
+        """Count one launch of ``program`` and return its span. The rows
+        come from the host arrays that staged the launch: slots decoded,
+        decode rows of slots not decoding, prompt tokens carried and
+        their padding."""
+        c = self.counters
+        c.launches += 1
+        c.decode_rows += decode
+        c.decode_idle_rows += idle
+        c.prefill_rows += prefill
+        c.prefill_pad_rows += pad
+        return TraceAnnotation(f"engine.launch.{program}",
+                               rows=decode + prefill, pad_rows=idle + pad,
+                               decode_rows=decode, prefill_rows=prefill)
+
     def _fill_slots(self) -> None:
-        guard_on = self.guard is not None
-        for s in range(self.max_slots):
-            while self._slots[s] is None and self._queue:
-                r = self._queue.pop(0)
-                self._admit(s, r)
-                if self.chunk_size > 0:
-                    # chunked admit costs nothing here: the prompt streams
-                    # through the main loop one chunk per step, interleaved
-                    # with the other slots' decode steps
-                    self._slots[s] = r
-                    self._offsets[s] = 0
-                    self._counts[s] = 0
-                    self._decoding[s] = False
-                    continue
-                prompt = np.asarray(r.prompt, np.int32)
-                true_len = prompt.shape[0]
-                bucket = (min(_pow2_bucket(true_len), self.max_len)
-                          if self._bucketed else true_len)
-                padded = np.zeros((1, bucket), np.int32)
-                padded[0, :true_len] = prompt
-                # per-slot isolation: a prefill failure (bad request
-                # reaching the forward, guard plumbing, OOM on an
-                # oversized bucket) fails *this* request, not the batch;
-                # the next occupant's zero-reset re-initialises the slot
-                self._slots[s] = r
-                args = (self.params, self.caches, self.last_tok,
-                        jnp.asarray(padded), true_len, s,
-                        float(r.temperature), self._next_key(),
-                        _host_snapshot(self._rk_slot[s]),
-                        np.int32(self._lvl_slot[s]), self._dstate(),
-                        *self._guard_args(s))
-                self._build("prefill", args, variant=bucket)
-                try:
-                    self.launch_count += 1
-                    out = self._prefill(*args)
-                except Exception as e:     # noqa: BLE001
-                    self._fail_request(s, RequestError(
-                        reason=f"prefill failed: {e!r}", phase="prefill",
-                        slot=s))
-                    continue
-                self.caches, self.last_tok, tok = out[:3]
-                self._slots[s] = None
-                if guard_on:
-                    dead = self._note_guard(out[3], out[4], [(s, 0)])
-                    if dead:
+        with TraceAnnotation("engine.fill"):
+            for s in range(self.max_slots):
+                while self._slots[s] is None and self._queue:
+                    r = self._queue.pop(0)
+                    self._admit(s, r)
+                    if self.chunk_size > 0:
+                        # chunked admit costs nothing here: the prompt
+                        # streams through the main loop one chunk per step,
+                        # interleaved with the other slots' decode steps
                         self._slots[s] = r
-                        self._fail_request(s, self._guard_err(s, "prefill"))
+                        self._offsets[s] = 0
+                        self._counts[s] = 0
+                        self._decoding[s] = False
                         continue
-                ri = self._req_index[id(r)]
-                self._pend.append(("p", tok, ri))
-                self._note_first_token(r, tok)
-                if r.max_new_tokens > 1:
-                    self._slots[s] = r
-                    self._counts[s] = 1
-                    self._decoding[s] = True
-                else:
-                    self._slots[s] = r
-                    self._finish_request(s)
+                    self._prefill_whole(s, r)
+
+    def _prefill_whole(self, s: int, r: Request) -> None:
+        """Prefill ``r``'s whole prompt into free slot ``s`` (the
+        ``chunk_size=0`` path), padded to its power-of-two bucket."""
+        with TraceAnnotation("engine.stage"):
+            prompt = np.asarray(r.prompt, np.int32)
+            true_len = prompt.shape[0]
+            bucket = (min(_pow2_bucket(true_len), self.max_len)
+                      if self._bucketed else true_len)
+            padded = np.zeros((1, bucket), np.int32)
+            padded[0, :true_len] = prompt
+            # per-slot isolation: a prefill failure (bad request reaching
+            # the forward, guard plumbing, OOM on an oversized bucket)
+            # fails *this* request, not the batch; the next occupant's
+            # zero-reset re-initialises the slot
+            self._slots[s] = r
+            args = (self.params, self.caches, self.last_tok,
+                    jnp.asarray(padded), true_len, s,
+                    float(r.temperature), self._next_key(),
+                    _host_snapshot(self._rk_slot[s]),
+                    np.int32(self._lvl_slot[s]), self._dstate(),
+                    *self._guard_args(s))
+        with self._launch("prefill", prefill=true_len,
+                          pad=bucket - true_len):
+            self._build("prefill", args, variant=bucket)
+            try:
+                out = self._prefill(*args)
+            except Exception as e:     # noqa: BLE001
+                self._fail_request(s, RequestError(
+                    reason=f"prefill failed: {e!r}", phase="prefill",
+                    slot=s))
+                return
+        self.caches, self.last_tok, tok = out[:3]
+        self._slots[s] = None
+        if self.guard is not None:
+            dead = self._note_guard(out[3], out[4], [(s, 0)])
+            if dead:
+                self._slots[s] = r
+                self._fail_request(s, self._guard_err(s, "prefill"))
+                return
+        ri = self._req_index[id(r)]
+        self._pend.append(("p", tok, ri))
+        self._note_first_token(r, tok)
+        self._slots[s] = r
+        if r.max_new_tokens > 1:
+            self._counts[s] = 1
+            self._decoding[s] = True
+        else:
+            self._finish_request(s)
 
     def _prefill_chunks(self) -> bool:
         """One chunk of progress for every still-prefilling slot;
@@ -1217,29 +1275,31 @@ class Engine:
         for s, r in enumerate(self._slots):
             if r is None or self._decoding[s]:
                 continue
-            prompt = np.asarray(r.prompt, np.int32)
-            off = self._offsets[s]
-            valid = min(self.chunk_size, prompt.shape[0] - off)
-            chunk = np.zeros((1, self.chunk_size), np.int32)
-            chunk[0, :valid] = prompt[off:off + valid]
-            is_final = off + valid >= prompt.shape[0]
-            args = (self.params, self.caches, self.last_tok,
-                    jnp.asarray(chunk), jnp.asarray(off == 0),
-                    jnp.asarray(valid, jnp.int32), jnp.asarray(is_final),
-                    s, float(r.temperature), self._next_key(),
-                    _host_snapshot(self._rk_slot[s]),
-                    np.int32(self._lvl_slot[s]), self._dstate(),
-                    *self._guard_args(s))
-            self._build("prefill_chunk", args)
-            try:
-                self.launch_count += 1
-                out = self._prefill_chunk(*args)
-            except Exception as e:         # noqa: BLE001
-                self._fail_request(s, RequestError(
-                    reason=f"prefill chunk failed: {e!r}", phase="prefill",
-                    slot=s))
-                finished = True            # slot freed -> refill
-                continue
+            with TraceAnnotation("engine.stage"):
+                prompt = np.asarray(r.prompt, np.int32)
+                off = self._offsets[s]
+                valid = min(self.chunk_size, prompt.shape[0] - off)
+                chunk = np.zeros((1, self.chunk_size), np.int32)
+                chunk[0, :valid] = prompt[off:off + valid]
+                is_final = off + valid >= prompt.shape[0]
+                args = (self.params, self.caches, self.last_tok,
+                        jnp.asarray(chunk), jnp.asarray(off == 0),
+                        jnp.asarray(valid, jnp.int32),
+                        jnp.asarray(is_final), s, float(r.temperature),
+                        self._next_key(), _host_snapshot(self._rk_slot[s]),
+                        np.int32(self._lvl_slot[s]), self._dstate(),
+                        *self._guard_args(s))
+            with self._launch("prefill_chunk", prefill=int(valid),
+                              pad=self.chunk_size - int(valid)):
+                self._build("prefill_chunk", args)
+                try:
+                    out = self._prefill_chunk(*args)
+                except Exception as e:     # noqa: BLE001
+                    self._fail_request(s, RequestError(
+                        reason=f"prefill chunk failed: {e!r}",
+                        phase="prefill", slot=s))
+                    finished = True        # slot freed -> refill
+                    continue
             self.caches, self.last_tok, tok = out[:3]
             if guard_on:
                 dead = self._note_guard(out[3], out[4], [(s, 0)])
@@ -1293,12 +1353,14 @@ class Engine:
             solo = np.zeros(self.max_slots, bool)
             solo[s] = True
             try:
-                self.launch_count += 1
-                out = self._decode(
-                    self.params, self.caches, toks, jnp.asarray(solo),
-                    temps, step_key, _host_snapshot(self._rk_slot),
-                    jnp.asarray(tok_idx), _host_snapshot(self._lvl_slot),
-                    self._dstate(), *self._guard_batch_args())
+                with self._launch("decode", decode=1,
+                                  idle=self.max_slots - 1):
+                    out = self._decode(
+                        self.params, self.caches, toks, jnp.asarray(solo),
+                        temps, step_key, _host_snapshot(self._rk_slot),
+                        jnp.asarray(tok_idx),
+                        _host_snapshot(self._lvl_slot), self._dstate(),
+                        *self._guard_batch_args())
                 self.caches, toks = out[:2]
                 if guard_on:
                     self._note_guard(out[2], out[3], [(s, s)])
@@ -1312,36 +1374,43 @@ class Engine:
         then one batch decode — now with per-slot decode failure isolation
         (the fused path recovers it by falling back here)."""
         guard_on = self.guard is not None
-        act_host, active, temps = self._slot_state()
         if self._prefill_chunks():
-            # a slot finished prefilling (or freed at max_new==1): refresh
-            # membership so it joins this iteration's decode step — or
-            # admit the next request into the free slot
+            # a slot finished prefilling (or freed at max_new==1): admit
+            # the next request into the free slot; the membership read
+            # below puts a finished slot in this iteration's decode step
             self._fill_slots()
+        with TraceAnnotation("engine.stage"):
             act_host, active, temps = self._slot_state()
-        if not act_host.any():
+            n_dec = int(act_host.sum())
+            if n_dec:
+                tok_idx = np.array(self._counts, np.int32)
+                step_key = self._next_key()
+                args = (self.params, self.caches, self.last_tok, active,
+                        temps, step_key, _host_snapshot(self._rk_slot),
+                        jnp.asarray(tok_idx), _host_snapshot(self._lvl_slot),
+                        self._dstate(), *self._guard_batch_args())
+        if not n_dec:
             if self._turnover:
                 self._fill_slots()
             return
-        tok_idx = np.array(self._counts, np.int32)
-        step_key = self._next_key()
         dead_errs: Dict[int, RequestError] = {}
         gdead: List[int] = []
-        self.launch_count += 1
-        args = (self.params, self.caches, self.last_tok, active, temps,
-                step_key, _host_snapshot(self._rk_slot), jnp.asarray(tok_idx),
-                _host_snapshot(self._lvl_slot), self._dstate(),
-                *self._guard_batch_args())
-        self._build("decode", args)
-        try:
-            out = self._decode(*args)
-            self.caches, toks = out[:2]
-            if guard_on:
-                gdead = self._note_guard(
-                    out[2], out[3],
-                    [(s, s) for s in range(self.max_slots) if act_host[s]])
-            self.last_tok = toks
-        except Exception:                  # noqa: BLE001
+        failed = False
+        with self._launch("decode", decode=n_dec,
+                          idle=self.max_slots - n_dec):
+            self._build("decode", args)
+            try:
+                out = self._decode(*args)
+                self.caches, toks = out[:2]
+                if guard_on:
+                    gdead = self._note_guard(
+                        out[2], out[3],
+                        [(s, s) for s in range(self.max_slots)
+                         if act_host[s]])
+                self.last_tok = toks
+            except Exception:              # noqa: BLE001
+                failed = True
+        if failed:
             toks, probed = self._isolate_decode(act_host, temps, step_key,
                                                tok_idx)
             for s, e in probed:
@@ -1378,77 +1447,91 @@ class Engine:
         dispatch. Token streams (and the PRNG draw order) are identical
         to the per-call path. Returns False to route the iteration to
         the per-call body instead: permanently if the step raises (the
-        fallback recovers per-slot failure isolation), or just for this
+        fallback recovers per-slot failure isolation; the first error is
+        kept in ``fused_step_error`` and every fallback counts in
+        ``counters.fused_fallbacks``), or just for this
         iteration when no slot is prefilling (pure decode is already a
         single ``_decode`` launch)."""
-        n_slots = self.max_slots
-        chunk_toks = np.zeros((n_slots, 1, self.chunk_size), np.int32)
-        resets = np.zeros(n_slots, bool)
-        valids = np.zeros(n_slots, np.int32)
-        finals = np.zeros(n_slots, bool)
-        prefilling = np.zeros(n_slots, bool)
-        act_after = np.zeros(n_slots, bool)
-        tok_idx = np.zeros(n_slots, np.int32)
-        for s, r in enumerate(self._slots):
-            if r is None:
-                continue
-            if self._decoding[s]:
-                act_after[s] = True
-                tok_idx[s] = self._counts[s]
-                continue
-            prompt = np.asarray(r.prompt, np.int32)
-            off = self._offsets[s]
-            valid = min(self.chunk_size, prompt.shape[0] - off)
-            chunk_toks[s, 0, :valid] = prompt[off:off + valid]
-            resets[s] = off == 0
-            valids[s] = valid
-            # a slot finishing its prompt this iteration joins this
-            # same iteration's decode (matching the per-call scheduler)
-            finals[s] = off + valid >= prompt.shape[0]
-            prefilling[s] = True
-            if finals[s] and r.max_new_tokens > 1:
-                act_after[s] = True
-                tok_idx[s] = 1   # first decode token after the prefill tok
-        if not prefilling.any():
+        if all(r is None or self._decoding[s]
+               for s, r in enumerate(self._slots)):
             # pure-decode iteration: the per-call path is already a
             # single ``_decode`` launch, and it skips ``_step``'s
             # scan-over-slots slice traffic — route it there (this is
             # NOT the failure fallback; the next mixed iteration fuses)
             return False
-        do_decode = bool(act_after.any())
-        temps_now = np.array(
-            [float(r.temperature) if r is not None else 0.0
-             for r in self._slots], np.float32)
-        # one packed (S, 7) transfer instead of seven small ones, and one
-        # jitted key-chain dispatch instead of up to S+1 sequential
-        # splits + a stack — per-iteration host dispatch used to exceed
-        # the cost of a chunk forward (see draw_keys_fn). The key order
-        # (prefilling slots ascending, then the decode) matches the
-        # per-call path, so both consume the same PRNG stream.
-        flags = np.stack(
-            [resets.astype(np.int32), valids,
-             finals.astype(np.int32), prefilling.astype(np.int32),
-             act_after.astype(np.int32), tok_idx,
-             self._lvl_slot.astype(np.int32)], axis=1)
-        key_mask = np.append(prefilling, do_decode)
-        self.key, key_rows = self._draw_keys(self.key,
-                                             jnp.asarray(key_mask))
-        meta_p = [self._req_index[id(self._slots[s])]
-                  if prefilling[s] and finals[s] else None
-                  for s in range(n_slots)]
-        meta_d = [self._req_index[id(self._slots[s])] if act_after[s]
-                  else None for s in range(n_slots)]
-        args = (self.params, self.caches, self.last_tok,
-                jnp.asarray(chunk_toks), jnp.asarray(flags),
-                jnp.asarray(temps_now), key_rows,
-                _host_snapshot(self._rk_slot), self._dstate())
-        self._build("step", args)
-        try:
-            self.launch_count += 1
-            caches, toks, ptoks = self._step(*args)
-        except Exception:                  # noqa: BLE001
-            self._fused_ok = False
-            return False
+        n_slots = self.max_slots
+        with TraceAnnotation("engine.stage"):
+            chunk_toks = np.zeros((n_slots, 1, self.chunk_size), np.int32)
+            resets = np.zeros(n_slots, bool)
+            valids = np.zeros(n_slots, np.int32)
+            finals = np.zeros(n_slots, bool)
+            prefilling = np.zeros(n_slots, bool)
+            act_after = np.zeros(n_slots, bool)
+            tok_idx = np.zeros(n_slots, np.int32)
+            for s, r in enumerate(self._slots):
+                if r is None:
+                    continue
+                if self._decoding[s]:
+                    act_after[s] = True
+                    tok_idx[s] = self._counts[s]
+                    continue
+                prompt = np.asarray(r.prompt, np.int32)
+                off = self._offsets[s]
+                valid = min(self.chunk_size, prompt.shape[0] - off)
+                chunk_toks[s, 0, :valid] = prompt[off:off + valid]
+                resets[s] = off == 0
+                valids[s] = valid
+                # a slot finishing its prompt this iteration joins this
+                # same iteration's decode (matching the per-call scheduler)
+                finals[s] = off + valid >= prompt.shape[0]
+                prefilling[s] = True
+                if finals[s] and r.max_new_tokens > 1:
+                    act_after[s] = True
+                    tok_idx[s] = 1   # first decode token after the prefill
+            do_decode = bool(act_after.any())
+            temps_now = np.array(
+                [float(r.temperature) if r is not None else 0.0
+                 for r in self._slots], np.float32)
+            # one packed (S, 7) transfer instead of seven small ones, and
+            # one jitted key-chain dispatch instead of up to S+1
+            # sequential splits + a stack — per-iteration host dispatch
+            # used to exceed the cost of a chunk forward (see
+            # draw_keys_fn). The key order (prefilling slots ascending,
+            # then the decode) matches the per-call path, so both consume
+            # the same PRNG stream.
+            flags = np.stack(
+                [resets.astype(np.int32), valids,
+                 finals.astype(np.int32), prefilling.astype(np.int32),
+                 act_after.astype(np.int32), tok_idx,
+                 self._lvl_slot.astype(np.int32)], axis=1)
+            key_mask = np.append(prefilling, do_decode)
+            self.key, key_rows = self._draw_keys(self.key,
+                                                 jnp.asarray(key_mask))
+            meta_p = [self._req_index[id(self._slots[s])]
+                      if prefilling[s] and finals[s] else None
+                      for s in range(n_slots)]
+            meta_d = [self._req_index[id(self._slots[s])] if act_after[s]
+                      else None for s in range(n_slots)]
+            args = (self.params, self.caches, self.last_tok,
+                    jnp.asarray(chunk_toks), jnp.asarray(flags),
+                    jnp.asarray(temps_now), key_rows,
+                    _host_snapshot(self._rk_slot), self._dstate())
+        n_dec = int(act_after.sum())
+        n_valid = int(valids.sum())
+        with self._launch(
+                "step", decode=n_dec, idle=n_slots - n_dec if do_decode
+                else 0, prefill=n_valid,
+                pad=int(prefilling.sum()) * self.chunk_size - n_valid):
+            self._build("step", args)
+            try:
+                caches, toks, ptoks = self._step(*args)
+            except Exception as e:         # noqa: BLE001
+                self._fused_ok = False
+                self.counters.fused_fallbacks += 1
+                if self.fused_step_error is None:
+                    self.fused_step_error = "".join(
+                        traceback.format_exception_only(type(e), e)).strip()
+                return False
         self.caches = caches
         self.last_tok = toks
         if any(m is not None for m in meta_p):

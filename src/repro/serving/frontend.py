@@ -58,6 +58,7 @@ import time
 from collections import deque
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 from typing import Any, Callable, Deque, List, Optional
 
 from repro.serving.engine import OUTCOMES, Engine, Request, RequestError
@@ -249,10 +250,11 @@ class Frontend:
         pinned = now is not None
         if now is None:
             now = self.clock()
-        self._enforce_drain(now)
-        self._expire_and_cancel(now)
-        self._step_ladder(now)
-        self._admit(now)
+        with TraceAnnotation("frontend.admit"):
+            self._enforce_drain(now)
+            self._expire_and_cancel(now)
+            self._step_ladder(now)
+            self._admit(now)
         did = self.engine.step(now=now)
         self.engine.drain_pending()
         if getattr(self.engine, "drift", None) is not None:
@@ -261,7 +263,8 @@ class Frontend:
                     now if pinned else self.clock(), ev)
         # re-read the clock for outcome/TTFT stamps unless the caller pinned
         # ``now`` (tests): an engine step can hide seconds of compile/compute
-        self._pump(now if pinned else self.clock())
+        with TraceAnnotation("frontend.pump"):
+            self._pump(now if pinned else self.clock())
         return did
 
     async def run(self, idle_sleep_s: float = 0.002) -> None:

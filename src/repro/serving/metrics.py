@@ -11,7 +11,9 @@ level / vote count a request was admitted at (the paper's accuracy/energy
 knob, DESIGN.md §16) sits beside its queue wait and TTFT, so a bench run
 can show what the degraded admissions bought. Ladder transitions are logged
 separately (``MetricsLog.transitions``) with the queue depth that triggered
-them.
+them. ``ServingCounters`` holds an engine's cumulative work counts
+(iterations, launches, rows computed and padded, drains, fallbacks) and
+the process's JAX trace and compile counts.
 
 Kept dependency-free (stdlib only): the front-end imports it under asyncio,
 the benches import it for BENCH_*.json summaries.
@@ -71,6 +73,59 @@ class RequestRecord:
             if dt > 0:
                 self.tps = (self.tokens_out - 1) / dt
         return self
+
+
+# process-wide tally of JAX's compile events (``note_compile_event`` is
+# registered once per process as a ``jax.monitoring`` duration listener by
+# ``serving.engine``). On a persistent-cache hit both events still fire: the
+# jaxpr is traced again and the backend-compile event times the retrieval.
+TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_COMPILE_TALLY = {"traces": 0, "compiles": 0}
+
+
+def note_compile_event(event: str, duration_secs: float, **kwargs) -> None:
+    if event == TRACE_EVENT:
+        _COMPILE_TALLY["traces"] += 1
+    elif event == COMPILE_EVENT:
+        _COMPILE_TALLY["compiles"] += 1
+
+
+@dataclasses.dataclass
+class ServingCounters:
+    """Cumulative counts of one engine's work over its lifetime.
+
+    Rows are counted where a program is launched, from the host arrays
+    that staged it: ``decode_rows`` are slots decoded, ``decode_idle_rows``
+    the batch rows of a decode launch whose slot was not decoding,
+    ``prefill_rows`` prompt tokens a chunk (or bucket) carried and
+    ``prefill_pad_rows`` its padding. ``traces`` and ``compiles`` are
+    process-wide: every engine of the process reads the same counts.
+    """
+
+    iterations: int = 0        # scheduler iterations that had work
+    launches: int = 0          # jitted program launches
+    decode_rows: int = 0
+    decode_idle_rows: int = 0
+    prefill_rows: int = 0
+    prefill_pad_rows: int = 0
+    drains: int = 0            # device->host token drains
+    tokens_drained: int = 0
+    fused_fallbacks: int = 0   # fused iterations that raised
+
+    @property
+    def traces(self) -> int:
+        return _COMPILE_TALLY["traces"]
+
+    @property
+    def compiles(self) -> int:
+        return _COMPILE_TALLY["compiles"]
+
+    def snapshot(self) -> Dict[str, int]:
+        """Every count, process-wide ones included, as a plain dict."""
+        out = dataclasses.asdict(self)
+        out.update(_COMPILE_TALLY)
+        return out
 
 
 @dataclasses.dataclass

@@ -27,9 +27,10 @@ migrated greedy request continues token-for-token with no re-emitted
 prefix, even when the old replica died mid-decode or mid-chunked-prefill
 (tests/test_router.py). Whole-replica failures are detected two ways:
 ``step()``/``drain_pending()`` raising (device loss — ``Engine.kill()``)
-marks the replica dead immediately; a replica whose ``iter_count`` stalls
-``wedge_patience`` ticks while it has work is a wedged launch queue
-(``Engine.wedge()`` — the call "succeeds" but nothing advances).
+marks the replica dead immediately; a replica whose
+``counters.iterations`` stalls ``wedge_patience`` ticks while it has work
+is a wedged launch queue (``Engine.wedge()`` — the call "succeeds" but
+nothing advances).
 
 Deterministic fault injection rides ``core.faults.ReplicaFaultSpec``: the
 router applies kill/wedge at its own step counter, and ``build_pool``
@@ -142,7 +143,7 @@ class ReplicaRouter:
         self._track_of: Dict[int, _Track] = {}
         self._rstate = [_ReplicaState() for _ in self.engines]
         for st, e in zip(self._rstate, self.engines):
-            st.last_iter = e.iter_count
+            st.last_iter = e.counters.iterations
             st.hard = int(e.guard_hard_counts.sum())
             st.watchdog = e.watchdog_trips
             st.calib = e.calibrations
@@ -440,14 +441,14 @@ class ReplicaRouter:
                 continue
             busy = any(t.replica == i and not t.terminal and t.ereq is not None
                        for t in self._tracks)
-            if busy and e.iter_count == st.last_iter:
+            if busy and e.counters.iterations == st.last_iter:
                 st.stall_ticks += 1
                 if st.stall_ticks >= hp.wedge_patience:
                     self._mark_dead(i, f"wedged: no progress in "
                                        f"{st.stall_ticks} ticks")
             else:
                 st.stall_ticks = 0
-            st.last_iter = e.iter_count
+            st.last_iter = e.counters.iterations
 
     def _update_health(self) -> None:
         hp = self.health

@@ -179,10 +179,10 @@ def test_fused_step_matches_per_call_and_halves_launches(dense_setup):
     b = legacy.generate(_requests(cfg, lens, 6))
     assert a == b, (a, b)
     assert fused._fused_ok, "fused engine silently fell back to per-call"
-    assert fused.iter_count == legacy.iter_count
-    assert fused.launch_count == fused.iter_count  # ONE launch per iteration
-    assert 2 * fused.launch_count <= legacy.launch_count, (
-        fused.launch_count, legacy.launch_count)
+    fc, lc = fused.counters, legacy.counters
+    assert fc.iterations == lc.iterations
+    assert fc.launches == fc.iterations  # ONE launch per iteration
+    assert 2 * fc.launches <= lc.launches, (fc.launches, lc.launches)
 
 
 def test_fused_step_int8_and_sim(dense_setup):
