@@ -53,17 +53,19 @@ def _operands(max_len: int, live: int):
     key = jax.random.PRNGKey(max_len + live)
     kq, kk, kv = jax.random.split(key, 3)
     q = jax.random.normal(kq, (B, H, D), jnp.float32)
-    k = jax.random.normal(kk, (B, max_len, KV, D), jnp.float32)
-    v = jax.random.normal(kv, (B, max_len, KV, D), jnp.float32)
+    k = jax.random.normal(kk, (B, max_len, KV * D), jnp.float32)
+    v = jax.random.normal(kv, (B, max_len, KV * D), jnp.float32)
     lens = jnp.full((B,), live, jnp.int32)
     return q, k, v, lens
 
 
 def _einsum_step(q, k, v, lens):
     """The engine's einsum decode-attention step (post cache write):
-    dense scores over the whole cache, masked to the live prefix."""
+    dense scores over the whole (B, T, KV·D) cache, masked to the live
+    prefix."""
     t = k.shape[1]
-    return _sdpa(q[:, None], k, v, _cached_mask(lens - 1, 1, t))[:, 0]
+    return _sdpa(q[:, None], k.reshape(B, t, KV, D), v.reshape(B, t, KV, D),
+                 _cached_mask(lens - 1, 1, t))[:, 0]
 
 
 def _xla_cost(fn, *args) -> dict:

@@ -152,8 +152,9 @@ def kernel_phase(seed: int) -> None:
     b, t, h, kv, hd = 8, 2048, cfg.n_heads, cfg.n_kv_heads, cfg.hd
     ks = jax.random.split(key, 6)
     q = jax.random.normal(ks[0], (b, h, hd), jnp.bfloat16)
-    kc = jax.random.normal(ks[1], (b, t, kv, hd), jnp.bfloat16)
-    vc = jax.random.normal(ks[2], (b, t, kv, hd), jnp.bfloat16)
+    # the slot cache's lane-dense (B, T, KV·D) layout
+    kc = jax.random.normal(ks[1], (b, t, kv * hd), jnp.bfloat16)
+    vc = jax.random.normal(ks[2], (b, t, kv * hd), jnp.bfloat16)
     lens = jnp.array([1, 17, 128, 129, 700, 1024, 2047, 2048], jnp.int32)
     # bf16 output of a softmax-weighted mean of unit-scale values: a few
     # bf16 ulps of |o| <= 4 (the kernel rounds p to bf16 for the PV dot)
@@ -167,8 +168,8 @@ def kernel_phase(seed: int) -> None:
         f"compile_s={secs:.2f}")
     check(ncc > 0 and diff <= bound, "decode_attention bf16")
 
-    kq = jax.random.randint(ks[3], (b, t, kv, hd), -127, 128).astype(jnp.int8)
-    vq = jax.random.randint(ks[4], (b, t, kv, hd), -127, 128).astype(jnp.int8)
+    kq = jax.random.randint(ks[3], (b, t, kv * hd), -127, 128).astype(jnp.int8)
+    vq = jax.random.randint(ks[4], (b, t, kv * hd), -127, 128).astype(jnp.int8)
     sc = jnp.full((b, t, kv, 1), 1.0 / 127.0, jnp.float32)
     comp, ncc, secs = compile_and_count(
         lambda q, k, v, l, s1, s2: decode_attention(q, k, v, l, ks=s1, vs=s2),

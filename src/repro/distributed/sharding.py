@@ -114,12 +114,17 @@ class ShardingRules:
     PRIORITY = {"seq": 9, "qseq": 8, "frames": 9}
 
     def _resolve(self, table: Dict[str, AxisVal], names, shape) -> P:
+        # a name given as (name, unit) is a dim merged from ``name`` and a
+        # minor dim of ``unit`` (e.g. the (KV·D) cache lanes): it shards in
+        # whole units, so divisibility is checked on dim // unit
+        names = [n if isinstance(n, tuple) else (n, 1) for n in names]
         order = sorted(range(len(shape)),
-                       key=lambda i: self.PRIORITY.get(names[i] or "", 1))
+                       key=lambda i: self.PRIORITY.get(names[i][0] or "", 1))
         spec = [None] * len(shape)
         used = set()
         for i in order:
-            name, dim = names[i], shape[i]
+            name, unit = names[i]
+            dim = shape[i] // unit
             ax = table.get(name)
             if ax is None:
                 continue

@@ -1,11 +1,12 @@
 """Ragged, length-aware GQA decode-attention Pallas TPU kernel.
 
 One query token per sequence against the stacked slot cache (serving
-engine decode, DESIGN.md §10/§11). The dense einsum path computes scores
-over the *entire* ``(B, max_len)`` cache every step and masks the dead
-tail away — O(max_len) FLOPs and HBM traffic per token even when a slot
-holds a 3-token prompt. This kernel makes decode cost scale with the live
-context instead:
+engine decode, DESIGN.md §10/§11), stored lane-dense as ``(B, T, KV·D)``:
+KV head ``h`` is lanes ``[h·D, (h+1)·D)`` of each key's row. The dense
+einsum path computes scores over the *entire* ``(B, max_len)`` cache
+every step and masks the dead tail away — O(max_len) FLOPs and HBM
+traffic per token even when a slot holds a 3-token prompt. This kernel
+makes decode cost scale with the live context instead:
 
   * grid ``(B, kv_blocks)`` with the per-sequence key counts ``lens: (B,)``
     scalar-prefetched (SMEM): KV blocks at or past ``ceil(lens[b]/block_k)``
@@ -16,9 +17,13 @@ context instead:
     scratch across the ``kv_blocks`` sweep (``arbitrary`` semantics), the
     output is normalised and written once at the final block.
   * GQA head grouping happens in-kernel: the ``(H, D)`` query block is
-    sliced per KV head into ``(G, D)`` groups so every score/value product
+    sliced per KV head into ``(G, D)`` groups and the ``(block_k, KV·D)``
+    K/V block into its heads' lane ranges, so every score/value product
     is a dense ``(G, D) x (D, block_k)`` MXU dot — no host-side head
-    replication of the cache.
+    replication of the cache. The block is read as stored: a minor dim
+    of KV·D fills the TPU's 128-lane tiles, where a minor (KV, D) pair
+    (2 x 64 at qwen2-0.5b) would not and XLA would keep the cache
+    sequence-minor, relaid out around every kernel call.
   * int8 KV stays int8 in HBM: ``ks``/``vs`` per-key scales ride the same
     block pipeline and dequantisation happens on the VMEM-resident block
     right before the dot (the einsum fallback used to materialise a full
@@ -48,7 +53,7 @@ NEG_INF = -1e30
 
 def _pick_block_k(t: int, block_k: int) -> int:
     """Largest divisor of T that is <= block_k: never pad the cache (a pad
-    would copy the whole (B, T, KV, D) cache every decode step — the exact
+    would copy the whole (B, T, KV·D) cache every decode step — the exact
     traffic this kernel removes), so block_k must divide T. A plain
     gcd(T, block_k) would collapse to 1-2 for any odd-ish T (e.g. T=258 ->
     2); scanning down from min(block_k, T) keeps blocks MXU-sized for any
@@ -60,7 +65,7 @@ def _pick_block_k(t: int, block_k: int) -> int:
 
 
 def _kernel(lens_ref, *refs, scale: float, block_k: int, kv_heads: int,
-            group: int, n_kb: int, int8: bool):
+            group: int, d: int, n_kb: int, int8: bool):
     if int8:
         q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = refs
     else:
@@ -83,8 +88,8 @@ def _kernel(lens_ref, *refs, scale: float, block_k: int, kv_heads: int,
         valid = kj < n_live
         for h in range(kv_heads):
             q = q_ref[0, h * group:(h + 1) * group, :]       # (G, D)
-            k = k_ref[0, :, h, :]                            # (bk, D)
-            v = v_ref[0, :, h, :]
+            k = k_ref[0, :, h * d:(h + 1) * d]               # (bk, D)
+            v = v_ref[0, :, h * d:(h + 1) * d]
             if int8:
                 k = k.astype(jnp.float32) * ks_ref[0, :, h, :]
                 v = v.astype(jnp.float32) * vs_ref[0, :, h, :]
@@ -121,8 +126,9 @@ def decode_attention(
 
     Args:
       q:    (B, H, D) query for the one new token per sequence.
-      k, v: (B, T, KV, D) stacked slot cache (f32/bf16, or int8 with
-            ``ks``/``vs``). ``H % KV == 0``; group size ``G = H // KV``.
+      k, v: (B, T, KV·D) stacked slot cache, KV head ``h`` in lanes
+            ``[h·D, (h+1)·D)`` (f32/bf16, or int8 with ``ks``/``vs``).
+            ``H % KV == 0``; group size ``G = H // KV``.
       lens: (B,) int32 — valid keys per row *including* the current token
             (i.e. ``cache_len + 1`` after the decode-step cache write).
             Keys at positions >= lens[b] are never read; lens[b] == 0
@@ -138,7 +144,10 @@ def decode_attention(
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     b, h, d = q.shape
-    _, t, kv_heads, _ = k.shape
+    _, t, width = k.shape
+    if width % d:
+        raise ValueError(f"cache width {width} not a multiple of D={d}")
+    kv_heads = width // d
     if h % kv_heads:
         raise ValueError(f"H={h} not a multiple of KV={kv_heads}")
     group = h // kv_heads
@@ -152,21 +161,24 @@ def decode_attention(
         # clamp dead-tail blocks onto the last live block: the repeated
         # block index elides the DMA, making traffic O(lens) not O(T)
         last = jnp.maximum((lens_pref[bi] - 1) // bk, 0)
-        return (bi, jnp.minimum(kb, last), 0, 0)
+        return (bi, jnp.minimum(kb, last), 0)
+
+    def scale_map(bi, kb, lens_pref):
+        return kv_map(bi, kb, lens_pref) + (0,)
 
     def row_map(bi, kb, lens_pref):
         return (bi, 0, 0)
 
     in_specs = [
-        pl.BlockSpec((1, h, d), row_map),            # q
-        pl.BlockSpec((1, bk, kv_heads, d), kv_map),  # k
-        pl.BlockSpec((1, bk, kv_heads, d), kv_map),  # v
+        pl.BlockSpec((1, h, d), row_map),        # q
+        pl.BlockSpec((1, bk, width), kv_map),    # k
+        pl.BlockSpec((1, bk, width), kv_map),    # v
     ]
     operands = [q, k, v]
     if int8:
         in_specs += [
-            pl.BlockSpec((1, bk, kv_heads, 1), kv_map),  # ks
-            pl.BlockSpec((1, bk, kv_heads, 1), kv_map),  # vs
+            pl.BlockSpec((1, bk, kv_heads, 1), scale_map),  # ks
+            pl.BlockSpec((1, bk, kv_heads, 1), scale_map),  # vs
         ]
         operands += [ks, vs]
 
@@ -183,7 +195,7 @@ def decode_attention(
     )
     return pl.pallas_call(
         functools.partial(_kernel, scale=scale, block_k=bk,
-                          kv_heads=kv_heads, group=group, n_kb=n_kb,
+                          kv_heads=kv_heads, group=group, d=d, n_kb=n_kb,
                           int8=int8),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),

@@ -37,9 +37,10 @@ Two kernels live here:
     (training/cross-attention shapes; heads pre-folded into rows).
   * ``flash_gqa_attention`` — the GQA-native prefill kernel (DESIGN.md
     §13): queries stay ``(B, S, H, D)`` and K/V stream straight from the
-    ``(B, T, KV, D)`` slot cache. Head grouping happens in-kernel (the
-    ``(block_q, G, D)`` query block collapses to a ``(block_q·G, D)`` MXU
-    operand per KV head, exactly as ``decode_attention`` does for S=1) and
+    lane-dense ``(B, T, KV·D)`` slot cache. Head grouping happens in-kernel
+    (the ``(block_q, G, D)`` query block collapses to a ``(block_q·G, D)``
+    MXU operand per KV head, read against that head's lane range of the
+    K/V block, exactly as ``decode_attention`` does for S=1) and
     an int8 cache is dequantised on the VMEM-resident block — the G-fold
     ``jnp.repeat`` + up-front dequant copies the old prefill wrapper paid
     per chunk are gone. ``flash_gqa_modeled_cost`` records the eliminated
@@ -235,7 +236,7 @@ def _gqa_blocks(s: int, t: int, block_q: int, block_k: int):
 
 def _gqa_kernel(start_ref, *refs, scale: float, int8: bool, count: bool,
                 block_q: int, block_k: int, n_k: int, group: int,
-                kv_heads: int, s_valid: int):
+                kv_heads: int, d: int, s_valid: int):
     if int8:
         q_ref, k_ref, v_ref, ks_ref, vs_ref = refs[:5]
         rest = refs[5:]
@@ -281,8 +282,8 @@ def _gqa_kernel(start_ref, *refs, scale: float, int8: bool, count: bool,
         mask = (kj <= qi + start_b) & (kj < start_b + s_valid)
         for h in range(kv_heads):
             q = q_ref[0, h]                            # (bq*G, D)
-            k = k_ref[0, :, h, :]                      # (bk, D)
-            v = v_ref[0, :, h, :]
+            k = k_ref[0, :, h * d:(h + 1) * d]         # (bk, D)
+            v = v_ref[0, :, h * d:(h + 1) * d]
             if int8:
                 k = k.astype(jnp.float32) * ks_ref[0, :, h, :]
                 v = v.astype(jnp.float32) * vs_ref[0, :, h, :]
@@ -326,9 +327,10 @@ def flash_gqa_attention(
 
     Args:
       q:    (B, S, H, D) queries for the S freshly written tokens per row.
-      k, v: (B, T, KV, D) stacked slot cache (f32/bf16, or int8 with
-            ``ks``/``vs``). ``H % KV == 0``; group size ``G = H // KV``.
-            Streamed in cache layout — never head-replicated, never padded
+      k, v: (B, T, KV·D) stacked slot cache, KV head ``h`` in lanes
+            ``[h·D, (h+1)·D)`` (f32/bf16, or int8 with ``ks``/``vs``).
+            ``H % KV == 0``; group size ``G = H // KV``. Streamed in cache
+            layout — never head-replicated, never padded
             (``block_k`` is shrunk to a divisor of T; padding would copy
             the whole cache per chunk).
       start: (B,) int32 per-row absolute offsets (``_cached_mask``
@@ -348,7 +350,10 @@ def flash_gqa_attention(
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     b, s, h, d = q.shape
-    _, t, kv_heads, _ = k.shape
+    _, t, width = k.shape
+    if width % d:
+        raise ValueError(f"cache width {width} not a multiple of D={d}")
+    kv_heads = width // d
     if h % kv_heads:
         raise ValueError(f"H={h} not a multiple of KV={kv_heads}")
     if (ks is None) != (vs is None):
@@ -376,18 +381,21 @@ def flash_gqa_attention(
         # clamp pruned blocks onto the causal-frontier block: the repeated
         # block index elides the DMA (same trick as the MHA kernel)
         last = (st[bi] + jnp.minimum((qi + 1) * bq, s) - 1) // bk
-        return (bi, jnp.minimum(kb, last), 0, 0)
+        return (bi, jnp.minimum(kb, last), 0)
+
+    def scale_map(bi, qi, kb, st):
+        return kv_map(bi, qi, kb, st) + (0,)
 
     in_specs = [
         pl.BlockSpec((1, kv_heads, bq * group, d), q_map),
-        pl.BlockSpec((1, bk, kv_heads, d), kv_map),
-        pl.BlockSpec((1, bk, kv_heads, d), kv_map),
+        pl.BlockSpec((1, bk, width), kv_map),
+        pl.BlockSpec((1, bk, width), kv_map),
     ]
     operands = [qp, k, v]
     if int8:
         in_specs += [
-            pl.BlockSpec((1, bk, kv_heads, 1), kv_map),
-            pl.BlockSpec((1, bk, kv_heads, 1), kv_map),
+            pl.BlockSpec((1, bk, kv_heads, 1), scale_map),
+            pl.BlockSpec((1, bk, kv_heads, 1), scale_map),
         ]
         operands += [ks, vs]
 
@@ -413,7 +421,7 @@ def flash_gqa_attention(
     outs = pl.pallas_call(
         functools.partial(_gqa_kernel, scale=scale, int8=int8,
                           count=return_block_counts, block_q=bq, block_k=bk,
-                          n_k=n_k, group=group, kv_heads=kv_heads,
+                          n_k=n_k, group=group, kv_heads=kv_heads, d=d,
                           s_valid=s),
         grid_spec=grid_spec,
         out_shape=out_shapes,

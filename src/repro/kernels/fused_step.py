@@ -236,8 +236,8 @@ def _kernel(lens_ref, lmax_ref, *refs, b: int, d: int, h: int, kv: int, hd: int,
             valid = kj < n_live
             cur = (kj == n_live - 1)[:, None]                   # (bk, 1)
             for hk in range(kv):
-                kblk = kc_ref[bi, :, hk, :]
-                vblk = vc_ref[bi, :, hk, :]
+                kblk = kc_ref[bi, :, hk * hd:(hk + 1) * hd]
+                vblk = vc_ref[bi, :, hk * hd:(hk + 1) * hd]
                 if int8:
                     kblk = kblk.astype(jnp.float32) * ks_ref[bi, :, hk, :]
                     vblk = vblk.astype(jnp.float32) * vs_ref[bi, :, hk, :]
@@ -277,7 +277,8 @@ def _kernel(lens_ref, lmax_ref, *refs, b: int, d: int, h: int, kv: int, hd: int,
 def fused_dense_layer(ctx, p, x, cache):
     """One dense transformer layer's decode step as a single Pallas program.
 
-    x: (B, 1, d); cache: the layer's slot cache ({k, v[, ks, vs], len}).
+    x: (B, 1, d); cache: the layer's slot cache ({k, v[, ks, vs], len},
+    K/V lane-dense ``(B, T, KV·hd)``).
     Returns (x_out (B, 1, d), new_cache) with the same cache-write semantics
     as the unfused ``transformer._dense_block`` (``row_update`` at the old
     length, ``len + 1``). Eligibility is the caller's job
@@ -336,17 +337,19 @@ def fused_dense_layer(ctx, p, x, cache):
 
     def kv_map(i, lens_pref, lmax_pref):
         last = jnp.maximum((lmax_pref[0] - 1) // bk, 0)
-        return (0, jnp.minimum(i, last), 0, 0)
+        return (0, jnp.minimum(i, last), 0)
+
+    def scale_map(i, lens_pref, lmax_pref):
+        return kv_map(i, lens_pref, lmax_pref) + (0,)
 
     in_specs = [pl.BlockSpec(op.shape, const) for op in operands[:3]]
     in_specs += [pl.BlockSpec(wv.shape, const) for wv in operands[3:10]]
     if qkv_bias:
         in_specs += [pl.BlockSpec((1, bb.shape[1]), const)
                      for bb in operands[10:13]]
-    in_specs += [pl.BlockSpec((b, bk, kv, hd),
-                              kv_map)] * 2
+    in_specs += [pl.BlockSpec((b, bk, kv * hd), kv_map)] * 2
     if int8:
-        in_specs += [pl.BlockSpec((b, bk, kv, 1), kv_map)] * 2
+        in_specs += [pl.BlockSpec((b, bk, kv, 1), scale_map)] * 2
     if sim:
         in_specs += [pl.BlockSpec(memory_space=pltpu.SMEM)] * 2
 
@@ -391,8 +394,8 @@ def fused_dense_layer(ctx, p, x, cache):
     if int8:
         x_new, kq, vq, kscale, vscale = outs
         new_cache = {
-            "k": row_update(cache["k"], kq[:, None], start),
-            "v": row_update(cache["v"], vq[:, None], start),
+            "k": row_update(cache["k"], kq.reshape(b, 1, kv * hd), start),
+            "v": row_update(cache["v"], vq.reshape(b, 1, kv * hd), start),
             "ks": row_update(cache["ks"], kscale[:, None], start),
             "vs": row_update(cache["vs"], vscale[:, None], start),
             "len": start + 1,
@@ -400,8 +403,8 @@ def fused_dense_layer(ctx, p, x, cache):
     else:
         x_new, k_cur, v_cur = outs
         new_cache = {
-            "k": row_update(cache["k"], k_cur[:, None], start),
-            "v": row_update(cache["v"], v_cur[:, None], start),
+            "k": row_update(cache["k"], k_cur.reshape(b, 1, kv * hd), start),
+            "v": row_update(cache["v"], v_cur.reshape(b, 1, kv * hd), start),
             "len": start + 1,
         }
     return x_new[:, None], new_cache

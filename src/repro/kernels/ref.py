@@ -277,16 +277,17 @@ def flash_attention_ref(q, k, v, causal: bool = True, start=None):
 def decode_attention_ref(q, k, v, lens, ks=None, vs=None):
     """Ragged single-token GQA decode oracle for the Pallas decode kernel.
 
-    q: (B, H, D); k, v: (B, T, KV, D); lens: (B,) valid-key counts
-    (including the current token's freshly written key). ``ks``/``vs``
-    (B, T, KV, 1) dequantise an int8 cache. Rows with lens == 0 return
-    exactly zero (matching the kernel's empty-accumulator output).
+    q: (B, H, D); k, v: (B, T, KV·D) lane-dense slot cache; lens: (B,)
+    valid-key counts (including the current token's freshly written key).
+    ``ks``/``vs`` (B, T, KV, 1) dequantise an int8 cache. Rows with
+    lens == 0 return exactly zero (matching the kernel's
+    empty-accumulator output).
     """
     b, h, d = q.shape
-    t, kv_heads = k.shape[1], k.shape[2]
+    t, kv_heads = k.shape[1], k.shape[2] // d
     g = h // kv_heads
-    kf = k.astype(jnp.float32)
-    vf = v.astype(jnp.float32)
+    kf = k.reshape(b, t, kv_heads, d).astype(jnp.float32)
+    vf = v.reshape(b, t, kv_heads, d).astype(jnp.float32)
     if ks is not None:
         kf = kf * ks
         vf = vf * vs
@@ -304,18 +305,18 @@ def decode_attention_ref(q, k, v, lens, ks=None, vs=None):
 def flash_gqa_ref(q, k, v, start=None, ks=None, vs=None):
     """GQA-native flash-prefill oracle (``kernels.flash_gqa_attention``).
 
-    q: (B, S, H, D); k, v: (B, T, KV, D) slot cache, optionally int8 with
-    ``ks``/``vs`` (B, T, KV, 1) scales. ``start: (B,)`` gives the
+    q: (B, S, H, D); k, v: (B, T, KV·D) lane-dense slot cache, optionally
+    int8 with ``ks``/``vs`` (B, T, KV, 1) scales. ``start: (B,)`` gives the
     ``_cached_mask`` semantics — query i of row b sits at absolute
     position start[b]+i and may attend key j iff j <= start[b]+i (causal)
     and j < start[b]+S (freshly written prefix; recycled slots keep stale
     keys beyond the row's length and must never expose them).
     """
     b, s, h, d = q.shape
-    t, kv_heads = k.shape[1], k.shape[2]
+    t, kv_heads = k.shape[1], k.shape[2] // d
     g = h // kv_heads
-    kf = k.astype(jnp.float32)
-    vf = v.astype(jnp.float32)
+    kf = k.reshape(b, t, kv_heads, d).astype(jnp.float32)
+    vf = v.reshape(b, t, kv_heads, d).astype(jnp.float32)
     if ks is not None:
         kf = kf * ks
         vf = vf * vs

@@ -95,9 +95,10 @@ def collective_bytes(hlo_text: str) -> Dict[str, float]:
 # --------------------------------------------------------------------------
 
 
-def _gqa_cache_axes():
-    return {"k": ("layers", "batch", "seq", "kv_heads", "head_dim"),
-            "v": ("layers", "batch", "seq", "kv_heads", "head_dim"),
+def _gqa_cache_axes(cfg):
+    lanes = ("kv_heads", cfg.hd)     # (KV·D) minor dim, whole heads
+    return {"k": ("layers", "batch", "seq", lanes),
+            "v": ("layers", "batch", "seq", lanes),
             "ks": ("layers", "batch", "seq", "kv_heads", None),
             "vs": ("layers", "batch", "seq", "kv_heads", None),
             "len": ("layers", "batch")}
@@ -106,13 +107,13 @@ def _gqa_cache_axes():
 def cache_axes(cfg) -> Any:
     fam = cfg.family
     if fam in ("dense", "vlm"):
-        return _gqa_cache_axes()
+        return _gqa_cache_axes(cfg)
     if fam == "moe":
         if cfg.mla is not None:
             return {"ckv": ("layers", "batch", "seq", None),
                     "krope": ("layers", "batch", "seq", None),
                     "len": ("layers", "batch")}
-        return _gqa_cache_axes()
+        return _gqa_cache_axes(cfg)
     if fam == "ssm":
         return {"conv": ("layers", "batch", None, "mlp"),
                 "state": ("layers", "batch", "heads", None, None)}
@@ -120,11 +121,11 @@ def cache_axes(cfg) -> Any:
         return {
             "mamba": {"conv": ("layers", "layers", "batch", None, "mlp"),
                       "state": ("layers", "layers", "batch", "heads", None, None)},
-            "attn": _gqa_cache_axes(),
+            "attn": _gqa_cache_axes(cfg),
         }
     if fam == "encdec":
         return {
-            "self": _gqa_cache_axes(),
+            "self": _gqa_cache_axes(cfg),
             "cross": {"k": ("layers", "batch", "frames", "kv_heads", "head_dim"),
                       "v": ("layers", "batch", "frames", "kv_heads", "head_dim")},
         }

@@ -6,9 +6,11 @@ All paths support three phases:
   * decode   — one query token against the cache (functional update)
 
 KV caches are plain pytrees so they shard/checkpoint like params. GQA cache:
-{"k": (B, S, KV, D), "v": ..., "len": (B,)}; MLA caches the *compressed* c_kv
-(B, S, kv_lora) + shared k_rope (B, S, rope_hd) — the arch's serving-memory
-win — and up-projects per step.
+{"k": (B, S, KV·D), "v": ..., "len": (B,)} — lane-dense, KV head ``h`` in
+lanes ``[h·D, (h+1)·D)`` of each position's row (DESIGN.md §10), the one
+layout every writer and both attention kernels share; MLA caches the
+*compressed* c_kv (B, S, kv_lora) + shared k_rope (B, S, rope_hd) — the
+arch's serving-memory win — and up-projects per step.
 
 ``len`` is *per sequence*: every cached row advances independently, which is
 what lets the serving engine fuse ragged continuous-batching slots into one
@@ -58,18 +60,24 @@ def init_gqa(key, cfg: ModelConfig, dtype=jnp.float32):
 
 
 def init_gqa_cache(cfg: ModelConfig, batch: int, max_len: int, dtype) -> Dict[str, Any]:
+    """K/V stored ``(batch, max_len, KV·D)``: the heads merged into one
+    minor dim, which fills the TPU's (8, 128) tiles (a minor ``(KV, D)``
+    pair such as 2 x 64 does not, and XLA then keeps the cache
+    sequence-minor and relayouts it around every kernel call). int8
+    caches keep their per-(position, head) scales ``(batch, max_len, KV,
+    1)``."""
     kv, hd = cfg.n_kv_heads, cfg.hd
     if cfg.kv_cache_int8:
         return {
-            "k": jnp.zeros((batch, max_len, kv, hd), jnp.int8),
-            "v": jnp.zeros((batch, max_len, kv, hd), jnp.int8),
+            "k": jnp.zeros((batch, max_len, kv * hd), jnp.int8),
+            "v": jnp.zeros((batch, max_len, kv * hd), jnp.int8),
             "ks": jnp.zeros((batch, max_len, kv, 1), jnp.float32),
             "vs": jnp.zeros((batch, max_len, kv, 1), jnp.float32),
             "len": jnp.zeros((batch,), jnp.int32),
         }
     return {
-        "k": jnp.zeros((batch, max_len, kv, hd), dtype),
-        "v": jnp.zeros((batch, max_len, kv, hd), dtype),
+        "k": jnp.zeros((batch, max_len, kv * hd), dtype),
+        "v": jnp.zeros((batch, max_len, kv * hd), dtype),
         "len": jnp.zeros((batch,), jnp.int32),
     }
 
@@ -150,7 +158,7 @@ def _flash_prefill(q, k_c, v_c, start, ks=None, vs=None) -> jnp.ndarray:
     """Chunked/bucketed prefill through the GQA-native flash kernel
     (attn_impl="kernel", DESIGN.md §13).
 
-    q: (B,S,H,D); k_c, v_c: (B,T,KV,D) slot cache streamed *as stored* —
+    q: (B,S,H,D); k_c, v_c: (B,T,KV·D) slot cache streamed *as stored* —
     head grouping happens in-kernel (the G-fold ``jnp.repeat`` copy the
     old MHA-shaped wrapper paid per prefill is gone) and an int8 cache
     (``ks``/``vs`` scales) dequantises on the VMEM-resident block, so the
@@ -217,18 +225,19 @@ def gqa_attention(
         if int8_cache:
             kq, ks_ = _kv_quant(k)
             vq, vs_ = _kv_quant(v)
-            ck = row_update(cache["k"], kq, start)
-            cv = row_update(cache["v"], vq, start)
+            ck = row_update(cache["k"], kq.reshape(b, s, kv * hd), start)
+            cv = row_update(cache["v"], vq.reshape(b, s, kv * hd), start)
             cks = row_update(cache["ks"], ks_, start)
             cvs = row_update(cache["vs"], vs_, start)
             new_cache = {"k": ck, "v": cv, "ks": cks, "vs": cvs, "len": start + s}
         else:
-            ck = row_update(cache["k"], k, start)
-            cv = row_update(cache["v"], v, start)
+            ck = row_update(cache["k"], k.reshape(b, s, kv * hd), start)
+            cv = row_update(cache["v"], v.reshape(b, s, kv * hd), start)
             new_cache = {"k": ck, "v": cv, "len": start + s}
         t = ck.shape[1]
-        ck_s = shard(ck, "batch", "seq", "kv_heads", "head_dim")
-        cv_s = shard(cv, "batch", "seq", "kv_heads", "head_dim")
+        # the merged minor dim shards in whole KV heads, as (KV, D) did
+        ck_s = shard(ck, "batch", "seq", ("kv_heads", hd))
+        cv_s = shard(cv, "batch", "seq", ("kv_heads", hd))
         if impl == "kernel" and s == 1:
             # length-aware Pallas decode: O(len[b]) KV blocks per row, int8
             # dequantised in-kernel (the cache never round-trips through a
@@ -249,10 +258,12 @@ def gqa_attention(
         elif int8_cache:
             # einsum fallback: scales fold into logits/probs — no f32
             # dequantised copy of the whole (B, T, KV, D) cache per step
-            out = _sdpa_int8(q, ck_s, cks, cv_s, cvs,
+            out = _sdpa_int8(q, ck_s.reshape(b, t, kv, hd), cks,
+                             cv_s.reshape(b, t, kv, hd), cvs,
                              _cached_mask(start, s, t))
         else:
-            out = _sdpa(q, ck_s, cv_s, _cached_mask(start, s, t))
+            out = _sdpa(q, ck_s.reshape(b, t, kv, hd),
+                        cv_s.reshape(b, t, kv, hd), _cached_mask(start, s, t))
 
     out = out.reshape(b, s, h * hd)
     return dense(ctx, p["o"], out, "attn_out"), new_cache

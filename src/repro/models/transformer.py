@@ -378,8 +378,15 @@ def _scan_blocks(ctx: Ctx, blocks: Params, block_fn, x, positions, caches):
     guard = ctx.guard is not None
     b = x.shape[0]
 
-    def body(h, xs):
-        layer_p, layer_cache, idx = xs
+    def body(carry, xs):
+        # the stacked caches ride in the carry, each layer's slice read and
+        # written back at its index: as scan xs/ys they would be restacked
+        # into a second whole-cache buffer, then copied into the donated one
+        h, caches = carry
+        layer_p, idx = xs
+        layer_cache = None if caches is None else jax.tree.map(
+            lambda c: jax.lax.dynamic_index_in_dim(c, idx, keepdims=False),
+            caches)
         lctx = dataclasses.replace(ctx, key=jax.random.fold_in(base_key, idx), counter=0)
         if guard:
             # fresh scratch lists per layer; guarded_dense appends (B,)
@@ -388,23 +395,26 @@ def _scan_blocks(ctx: Ctx, blocks: Params, block_fn, x, positions, caches):
             if ctx.pin_layers is not None:
                 lctx.pin_rows = jnp.take(ctx.pin_layers, idx, axis=1)
         h, new_cache = block_fn(lctx, layer_p, h, positions, layer_cache)
+        if caches is not None:
+            caches = jax.tree.map(
+                lambda c, u: jax.lax.dynamic_update_index_in_dim(c, u, idx, 0),
+                caches, new_cache)
         if guard:
             zero = jnp.zeros((b,), jnp.int32)
             trips = sum(lctx.trip_log, zero) if lctx.trip_log else zero
             hard = sum(lctx.hard_log, zero) if lctx.hard_log else zero
-            return h, (new_cache, trips, hard)
-        return h, new_cache
+            return (h, caches), (trips, hard)
+        return (h, caches), None
 
     if cfg.remat:
         body = jax.checkpoint(body)
-    x, ys = scan_or_loop(cfg, body, x, (blocks, caches, jnp.arange(n)), n)
+    (x, new_caches), ys = scan_or_loop(cfg, body, (x, caches),
+                                       (blocks, jnp.arange(n)), n)
     if guard:
-        new_caches, trips, hard = ys
         # side-channel outputs: read off the Ctx by the engine closures at
         # trace time (the Ctx is a fresh python object per traced call)
-        ctx.guard_trips, ctx.guard_hard = trips, hard
-        return x, new_caches
-    return x, ys
+        ctx.guard_trips, ctx.guard_hard = ys
+    return x, new_caches
 
 
 def forward(params: Params, batch: Dict[str, Any], cfg: ModelConfig,

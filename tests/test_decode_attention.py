@@ -30,17 +30,19 @@ LEN_PATTERNS = [
 
 
 def _qkv(key, int8=False):
+    """q (B, H, D) and a lane-dense (B, T, KV·D) cache (int8 + (B, T, KV, 1)
+    scales when ``int8``)."""
     kq, kk, kv = jax.random.split(key, 3)
     q = jax.random.normal(kq, (B, H, D))
     k = jax.random.normal(kk, (B, T, KV, D))
     v = jax.random.normal(kv, (B, T, KV, D))
     if not int8:
-        return q, k, v, None, None
+        return q, k.reshape(B, T, KV * D), v.reshape(B, T, KV * D), None, None
     ks = jnp.maximum(jnp.max(jnp.abs(k), axis=-1, keepdims=True) / 127.0, 1e-8)
     vs = jnp.maximum(jnp.max(jnp.abs(v), axis=-1, keepdims=True) / 127.0, 1e-8)
     kq8 = jnp.clip(jnp.round(k / ks), -127, 127).astype(jnp.int8)
     vq8 = jnp.clip(jnp.round(v / vs), -127, 127).astype(jnp.int8)
-    return q, kq8, vq8, ks, vs
+    return (q, kq8.reshape(B, T, KV * D), vq8.reshape(B, T, KV * D), ks, vs)
 
 
 @pytest.mark.parametrize("lens", LEN_PATTERNS)
@@ -170,6 +172,7 @@ def test_int8_fallback_matches_dequant_first():
     q, k8, v8, ks, vs = _qkv(key, int8=True)
     lens = jnp.asarray([1, 5, 37, 96], jnp.int32)
     mask = attn._cached_mask(lens - 1, 1, T)
+    k8, v8 = k8.reshape(B, T, KV, D), v8.reshape(B, T, KV, D)
     out = attn._sdpa_int8(q[:, None], k8, ks, v8, vs, mask)
     kf = (k8.astype(jnp.float32) * ks)
     vf = (v8.astype(jnp.float32) * vs)

@@ -456,17 +456,19 @@ GQA_SHAPES = [
 
 
 def _gqa_operands(key, b, s, t, h, kv, d, int8=False):
+    """q (b, s, h, d) and a lane-dense (b, t, kv·d) slot cache (int8 +
+    (b, t, kv, 1) scales when ``int8``)."""
     kq, kk, kv_ = jax.random.split(key, 3)
     q = jax.random.normal(kq, (b, s, h, d))
     k = jax.random.normal(kk, (b, t, kv, d))
     v = jax.random.normal(kv_, (b, t, kv, d))
     if not int8:
-        return q, k, v, None, None
+        return q, k.reshape(b, t, kv * d), v.reshape(b, t, kv * d), None, None
     ks = jnp.maximum(jnp.max(jnp.abs(k), axis=-1, keepdims=True) / 127.0, 1e-8)
     vs = jnp.maximum(jnp.max(jnp.abs(v), axis=-1, keepdims=True) / 127.0, 1e-8)
     k8 = jnp.clip(jnp.round(k / ks), -127, 127).astype(jnp.int8)
     v8 = jnp.clip(jnp.round(v / vs), -127, 127).astype(jnp.int8)
-    return q, k8, v8, ks, vs
+    return q, k8.reshape(b, t, kv * d), v8.reshape(b, t, kv * d), ks, vs
 
 
 @pytest.mark.parametrize("b,s,t,h,kv,d,starts", GQA_SHAPES)
@@ -520,8 +522,8 @@ def test_flash_gqa_matches_replicated_mha_path():
     y = flash_gqa_attention(q, k, v, start=st, block_q=bq, block_k=bk,
                             interpret=True)
     # the old wrapper, verbatim: G-fold repeat + (B, H) row fold
-    kx = jnp.repeat(k, g, axis=2)
-    vx = jnp.repeat(v, g, axis=2)
+    kx = jnp.repeat(k.reshape(b, t, kv, d), g, axis=2)
+    vx = jnp.repeat(v.reshape(b, t, kv, d), g, axis=2)
     qf = q.transpose(0, 2, 1, 3).reshape(b * h, s, d)
     kf = kx.transpose(0, 2, 1, 3).reshape(b * h, t, d)
     vf = vx.transpose(0, 2, 1, 3).reshape(b * h, t, d)

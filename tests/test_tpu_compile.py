@@ -80,8 +80,8 @@ def test_decode_attention_compiles(one_chip, int8):
     from repro.kernels.decode_attention import decode_attention
 
     kv_dt = jnp.int8 if int8 else jnp.bfloat16
-    shapes = [((B, H, HD), jnp.bfloat16), ((B, T, KV, HD), kv_dt),
-              ((B, T, KV, HD), kv_dt), ((B,), jnp.int32)]
+    shapes = [((B, H, HD), jnp.bfloat16), ((B, T, KV * HD), kv_dt),
+              ((B, T, KV * HD), kv_dt), ((B,), jnp.int32)]
     if int8:
         shapes += [((B, T, KV, 1), jnp.float32)] * 2
     _compile(one_chip,
@@ -96,8 +96,8 @@ def test_flash_gqa_prefill_compiles(one_chip, int8):
     from repro.kernels.flash_attention import flash_gqa_attention
 
     kv_dt = jnp.int8 if int8 else jnp.bfloat16
-    shapes = [((1, CHUNK, H, HD), jnp.bfloat16), ((1, T, KV, HD), kv_dt),
-              ((1, T, KV, HD), kv_dt), ((1,), jnp.int32)]
+    shapes = [((1, CHUNK, H, HD), jnp.bfloat16), ((1, T, KV * HD), kv_dt),
+              ((1, T, KV * HD), kv_dt), ((1,), jnp.int32)]
     if int8:
         shapes += [((1, T, KV, 1), jnp.float32)] * 2
     _compile(one_chip,
