@@ -25,6 +25,8 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
+from bench.family import model_config
+
 BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parent
 clock = time.perf_counter
@@ -80,7 +82,8 @@ def load_cell(name: str, bench: Optional[dict] = None) -> Cell:
 
 def dims(config: dict) -> dict:
     """The sizes the cost functions take, by their short names, and how
-    the block's linears run: int8 through the macro, or bf16."""
+    the block's linears run: int8 through the macro, or bf16. Only for a
+    dense configuration, whose published keys name these sizes."""
     sim = config["serving"]["cim_mode"] == "sim"
     return {"linear": "int8" if sim else "bf16",
             "n_layers": config["num_hidden_layers"],
@@ -90,32 +93,6 @@ def dims(config: dict) -> dict:
             "head_dim": config["head_dim"],
             "d_ff": config["intermediate_size"],
             "vocab_size": config["vocab_size"]}
-
-
-def model_config(config: dict):
-    """The program's ``ModelConfig`` for a configuration file."""
-    from repro.configs.base import CIMModelConfig, ModelConfig
-
-    s = config["serving"]
-    return ModelConfig(
-        name=config["name"], family="dense",
-        n_layers=config["num_hidden_layers"],
-        d_model=config["hidden_size"],
-        n_heads=config["num_attention_heads"],
-        n_kv_heads=config["num_key_value_heads"],
-        head_dim=config["head_dim"],
-        d_ff=config["intermediate_size"],
-        vocab_size=config["vocab_size"],
-        qkv_bias=config["attention_bias"],
-        rope_theta=config["rope_theta"],
-        norm_eps=config["rms_norm_eps"],
-        tie_embeddings=config["tie_word_embeddings"],
-        dtype=config["torch_dtype"],
-        attn_impl=s["attn_impl"],
-        kv_cache_int8=s["kv_cache_dtype"] == "int8",
-        cim=CIMModelConfig(mode=s["cim_mode"], policy=s["cim_policy"],
-                           act_clip_sigmas=s["act_clip_sigmas"],
-                           use_kernel=s["cim_use_kernel"]))
 
 
 def seed32(seed: int) -> int:
@@ -488,7 +465,9 @@ def run(cell: Cell, seed: int, seconds: float, trace_dir: Optional[str],
         reading = Reading(summary=summary, ticks=ticks,
                           recs=[r for r in win.recs
                                 if tw[0] <= r.submitted < tw[1]],
-                          dims=dims(config), shape=shape,
+                          dims=(dims(config) if mcfg.family == "dense"
+                                else None),
+                          config=config, shape=shape,
                           peaks=peaks.peaks_for(dev.device_kind),
                           flops=flops)
         for m in cell.per_layer:
@@ -554,12 +533,15 @@ def watch_fused_step(engine) -> dict:
 @dataclasses.dataclass
 class Reading:
     """What a per-layer metric reads: the reduced trace, the scheduler's
-    work per traced tick, the requests submitted while tracing, and the
-    tables to compare with."""
+    work per traced tick, the requests submitted while tracing, the
+    configuration's sizes, and the tables to compare with. ``dims`` is
+    ``None`` for a family other than dense; a reader for such a family
+    computes its costs from ``config``."""
     summary: object
     ticks: List[Tick]
     recs: List[Rec]
-    dims: dict
+    dims: Optional[dict]     # the dense sizes ``bench/flops.py`` takes
+    config: dict             # the configuration file, for other families
     shape: dict
     peaks: dict
     flops: object

@@ -7,6 +7,7 @@ import re
 import shutil
 import subprocess
 import sys
+from functools import reduce
 from pathlib import Path
 
 import pytest
@@ -15,7 +16,7 @@ ROOT = Path(__file__).resolve().parents[2]
 BENCH = ROOT / "bench"
 sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 
-from bench import harness, loadgen  # noqa: E402
+from bench import family, harness, loadgen  # noqa: E402
 
 SPEC = json.loads((ROOT / "BENCHMARK.json").read_text())
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
@@ -61,38 +62,62 @@ def test_every_cell_loads(cell):
         c.shape["max_len"]
 
 
-@pytest.mark.parametrize("path", sorted((BENCH / "configs").glob("*.json")),
-                         ids=lambda p: p.stem)
+# the benchmark's configurations, and test fixtures of other families
+CONFIG_FILES = sorted((BENCH / "configs").glob("*.json")) + sorted(
+    (BENCH / "tests" / "data").glob("*.json"))
+
+
+@pytest.mark.parametrize("path", CONFIG_FILES, ids=lambda p: p.stem)
 def test_every_config_builds(path):
     config = json.loads(path.read_text())
     assert config["name"] == path.stem
     mcfg = harness.model_config(config)
-    assert mcfg.hd == config["head_dim"]
-    assert (BENCH / "references" / f"{config['reference']}.py").is_file()
+    for field, key in family.published(config).items():
+        assert reduce(getattr, field.split("."), mcfg) == config[key], field
+    if path.parent == BENCH / "configs":
+        assert (BENCH / "references" / f"{config['reference']}.py").is_file()
     for entry in SPEC["configs"]:
         if entry["name"] == path.stem:
             assert entry["file"] == f"bench/configs/{path.name}"
             assert sorted(entry["reduced"]) == sorted(config["reduced"])
 
 
-@pytest.mark.parametrize("path", sorted((BENCH / "configs").glob("*.json")),
-                         ids=lambda p: p.stem)
+@pytest.mark.parametrize("path", CONFIG_FILES, ids=lambda p: p.stem)
 def test_weight_layout_is_the_programs(path):
     """The tree ``bench/weights.py`` draws has the paths, shapes and dtypes
-    of the program's own ``init`` for the configuration."""
+    of the program's own ``init`` for the configuration: every leaf has a
+    rule to draw it."""
     import jax
 
     from bench import weights
     from repro.models.model import build
 
     config = json.loads(path.read_text())
-    mine = weights.layout(config)
+    drawn = jax.eval_shape(lambda: weights.make(config, 1))
     prog = jax.eval_shape(
         lambda k: build(harness.model_config(config)).init(k)[0],
         jax.random.PRNGKey(0))
     flat = lambda t: {jax.tree_util.keystr(p): (s.shape, s.dtype)
                       for p, s in jax.tree_util.tree_flatten_with_path(t)[0]}
-    assert flat(mine) == flat(prog)
+    assert flat(drawn) == flat(prog)
+
+
+def test_a_leaf_with_no_draw_rule_raises():
+    """A tree with a leaf ``bench/weights.py`` has no rule for (here an
+    SSM's ``A_log``) is refused, not drawn by a guess."""
+    from bench import weights
+
+    config = json.loads((BENCH / "tests/data/moe-mla-tiny.json").read_text())
+    config["program"] = {"family": "ssm", "n_layers": 2, "d_model": 64,
+                         "n_heads": 2, "n_kv_heads": 2, "d_ff": 128,
+                         "vocab_size": 64, "dtype": "float32",
+                         "ssm": {"d_state": 16, "headdim": 32, "chunk": 32}}
+    config["published"] = {"dtype": "torch_dtype"}
+    config["unpublished"] = ["n_layers", "d_model", "n_heads", "n_kv_heads",
+                             "d_ff", "vocab_size", "ssm.d_state",
+                             "ssm.headdim", "ssm.chunk"]
+    with pytest.raises(ValueError, match="no rule to draw weight .*A_log"):
+        weights.make(config, 1)
 
 
 @pytest.mark.parametrize("path", sorted((BENCH / "metrics").glob("*.py")),
@@ -103,7 +128,7 @@ def test_every_metric_reader_loads_and_reads_nothing_from_nothing(path):
         summary=_EmptySummary(), ticks=[], recs=[],
         dims={"n_layers": 1, "d_model": 8, "n_heads": 2, "n_kv_heads": 1,
               "head_dim": 4, "d_ff": 16, "vocab_size": 32},
-        shape={"max_slots": 2, "chunk_size": 4}, peaks={}, flops=None)
+        config={}, shape={"max_slots": 2, "chunk_size": 4}, peaks={}, flops=None)
     assert mod.read(empty) is None
 
 

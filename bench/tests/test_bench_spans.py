@@ -28,7 +28,7 @@ def _reader(name):
 
 def _reading(summary, **extra):
     r = harness.Reading(summary=summary, ticks=[], recs=[], dims={},
-                        shape={}, peaks={}, flops=None)
+                        config={}, shape={}, peaks={}, flops=None)
     for k, v in extra.items():
         setattr(r, k, v)
     return r

@@ -1,10 +1,10 @@
 """Random weights for a configuration, drawn on the device from the seed.
 
-The tree has the layout the system under test takes for a dense GQA
-model (``layout``; a test checks it against the program's own ``init``);
-every value is drawn here, in one jitted call, in the dtype the model is
-served in. The plain reference redraws the same tree from the same seed,
-so it uses nothing the program computed.
+The tree has the layout of the program's own ``init`` for the
+configuration, of any family (``layout``: paths, shapes and dtypes, no
+values); every value is drawn here, in one jitted call, in the dtype the
+model is served in. The plain reference redraws the same tree from the
+same seed, so it uses nothing the program computed.
 """
 
 from __future__ import annotations
@@ -14,36 +14,27 @@ import zlib
 import jax
 import jax.numpy as jnp
 
+from bench.family import model_config
+
 
 def layout(config: dict) -> dict:
-    """ShapeDtypeStruct tree of a dense GQA configuration's parameters:
-    per-layer leaves stacked on a leading layer axis."""
-    n, d = config["num_hidden_layers"], config["hidden_size"]
-    q = config["num_attention_heads"] * config["head_dim"]
-    kv = config["num_key_value_heads"] * config["head_dim"]
-    f = config["intermediate_size"]
-    dt = jnp.dtype(config["torch_dtype"])
+    """ShapeDtypeStruct tree of the configuration's parameters, as the
+    program's ``init`` builds it: per-layer leaves stacked on a leading
+    layer axis. Every leaf is in the file's ``torch_dtype`` or the one its
+    ``leaf_dtypes`` names for it; the program does not choose the dtype
+    that both sides are drawn in."""
+    from repro.models.model import build
 
-    def leaf(*shape):
-        return jax.ShapeDtypeStruct(shape, dt)
-
-    def lin(k, m, bias=False):
-        p = {"w": leaf(n, k, m)}
-        if bias:
-            p["b"] = leaf(n, m)
-        return p
-
-    bias = config["attention_bias"]
-    return {
-        "blocks": {
-            "attn": {"q": lin(d, q, bias), "k": lin(d, kv, bias),
-                     "v": lin(d, kv, bias), "o": lin(q, d)},
-            "mlp": {"gate": lin(d, f), "up": lin(d, f), "down": lin(f, d)},
-            "n1": {"g": leaf(n, d)}, "n2": {"g": leaf(n, d)},
-        },
-        "embed": {"e": leaf(config["vocab_size"], d)},
-        "final_norm": {"g": leaf(d)},
-    }
+    init = build(model_config(config)).init
+    tree = jax.eval_shape(lambda k: init(k)[0], jax.random.PRNGKey(0))
+    stated = config.get("leaf_dtypes", {})
+    for p, s in jax.tree_util.tree_flatten_with_path(tree)[0]:
+        path = jax.tree_util.keystr(p)
+        want = jnp.dtype(stated.get(path, config["torch_dtype"]))
+        if s.dtype != want:
+            raise ValueError(f"the program's init makes {path} {s.dtype}; "
+                             f"the configuration states {want}")
+    return tree
 
 
 def _stream(path: str) -> int:
@@ -53,7 +44,8 @@ def _stream(path: str) -> int:
 def _draw(key, path: str, shape, dtype):
     leaf = path.rsplit("[", 1)[-1].strip("]'\"")
     z = jax.random.normal(key, shape, jnp.float32)
-    if leaf == "w":                       # (..., d_in, d_out) linear
+    if leaf in ("w", "w_gate", "w_up", "w_down"):
+        # (..., d_in, d_out) linear; the experts' are stacked per expert
         v = z / jnp.sqrt(jnp.float32(shape[-2]))
     elif leaf == "b":
         v = 0.1 * z
