@@ -27,10 +27,22 @@ class CIMModelConfig:
 
 @dataclasses.dataclass(frozen=True)
 class MoEConfig:
-    n_experts: int = 0
+    n_experts: int = 0           # the router's width: every expert of the layer
     top_k: int = 2
     n_shared: int = 0            # always-on shared experts (deepseek-v2: 2)
     capacity_factor: float = 1.25
+    # the experts this device holds, [held_first, held_first + n_held) of
+    # the router's n_experts (0: all of them). Under expert parallelism a
+    # layer computes its held experts' part of the routed sum; the other
+    # devices' parts are not added here (models/moe.py)
+    n_held: int = 0
+    held_first: int = 0
+    norm_topk: bool = True       # renormalise the top-k gates to sum to 1
+    routed_scale: float = 1.0    # the routed sum's factor (deepseek-v2)
+
+    @property
+    def held(self) -> int:
+        return self.n_held or self.n_experts
 
 
 @dataclasses.dataclass(frozen=True)
@@ -45,11 +57,26 @@ class SSMConfig:
 
 @dataclasses.dataclass(frozen=True)
 class MLAConfig:
-    q_lora: int = 1536
+    q_lora: Optional[int] = 1536     # None: one query projection, no latent
     kv_lora: int = 512
     rope_head_dim: int = 64
     nope_head_dim: int = 128
     v_head_dim: int = 128
+
+
+@dataclasses.dataclass(frozen=True)
+class RopeScaling:
+    """YaRN rope scaling (arXiv:2309.00071), fields named as the published
+    ``rope_scaling`` keys. Sets the MLA rope frequencies and softmax scale
+    (``layers.rope_freqs``, ``attention.mla_softmax_scale``)."""
+
+    type: str = "yarn"
+    factor: float = 1.0
+    original_max_position_embeddings: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -72,6 +99,13 @@ class ModelConfig:
     moe: Optional[MoEConfig] = None
     ssm: Optional[SSMConfig] = None
     mla: Optional[MLAConfig] = None
+    rope_scaling: Optional[RopeScaling] = None
+
+    # moe: the first n_dense_layers blocks have a dense SwiGLU FFN of width
+    # dense_d_ff in place of the expert layer (deepseek-v2:
+    # first_k_dense_replace); n_layers counts them
+    n_dense_layers: int = 0
+    dense_d_ff: int = 0
 
     # hybrid (zamba2): repeating super-block of (attn_period-1) mamba layers
     # + 1 *shared-weight* attention layer.
@@ -144,16 +178,18 @@ class ModelConfig:
             m = self.moe
             if self.mla is not None:
                 a = self.mla
+                qh = self.n_heads * (a.nope_head_dim + a.rope_head_dim)
                 qkv = (
-                    d * a.q_lora
-                    + a.q_lora * self.n_heads * (a.nope_head_dim + a.rope_head_dim)
+                    (d * qh if a.q_lora is None else d * a.q_lora + a.q_lora * qh)
                     + d * (a.kv_lora + a.rope_head_dim)
                     + a.kv_lora * self.n_heads * (a.nope_head_dim + a.v_head_dim)
                     + self.n_heads * a.v_head_dim * d
                 )
             else:
                 qkv = d * hd * (self.n_heads + 2 * self.n_kv_heads) + self.n_heads * hd * d
-            per_layer = qkv + 3 * d * f * (m.n_experts + m.n_shared) + d * m.n_experts
+            per_layer = qkv + 3 * d * f * (m.held + m.n_shared) + d * m.n_experts
+            lead = self.n_dense_layers * (qkv + 3 * d * self.dense_d_ff - per_layer)
+            return emb + self.n_layers * per_layer + lead
         elif self.family == "ssm":
             s = self.ssm
             di = s.expand * d
@@ -187,6 +223,9 @@ class ModelConfig:
             n_patches=min(self.n_patches, 8) if self.n_patches else 0,
             max_seq_len=128,
             dtype="float32",
+            # 128 positions lie far inside the context YaRN stretches from
+            # (original_max_position_embeddings): plain rope
+            rope_scaling=None,
         )
         if self.moe is not None:
             small = dataclasses.replace(

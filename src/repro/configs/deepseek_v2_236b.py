@@ -1,9 +1,11 @@
 """deepseek-v2-236b [moe] — MLA (kv_lora=512) + 2 shared + 160 routed top-6.
 
 arXiv:2405.04434 (hf-verified). d_ff=1536 is the *per-expert* FFN width.
+The MLA latents (q and kv) are RMS-normalised (``attention.init_mla``);
+rope is YaRN-scaled as published.
 """
 
-from repro.configs.base import MLAConfig, ModelConfig, MoEConfig
+from repro.configs.base import MLAConfig, ModelConfig, MoEConfig, RopeScaling
 
 CONFIG = ModelConfig(
     name="deepseek-v2-236b",
@@ -18,4 +20,8 @@ CONFIG = ModelConfig(
     moe=MoEConfig(n_experts=160, top_k=6, n_shared=2),
     mla=MLAConfig(q_lora=1536, kv_lora=512, rope_head_dim=64, nope_head_dim=128,
                   v_head_dim=128),
+    rope_scaling=RopeScaling(type="yarn", factor=40,
+                             original_max_position_embeddings=4096,
+                             beta_fast=32, beta_slow=1, mscale=0.707,
+                             mscale_all_dim=0.707),
 )

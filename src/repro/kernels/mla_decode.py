@@ -99,9 +99,9 @@ def mla_decode_attention(
       krope:  (B, T, R) shared rope-channel key cache.
       lens:   (B,) int32 valid cached positions including the current token;
               ``lens[b] == 0`` yields a zero output row.
-      scale:  static softmax scale, ``1/sqrt(nope_hd + rope_hd)`` (the
-              caller knows the pre-absorption head dims; the kernel cannot
-              recover them from L).
+      scale:  static softmax scale (``attention.mla_softmax_scale``: the
+              caller knows the pre-absorption head dims and the rope
+              scaling; the kernel cannot recover them from L).
       block_k: latent-cache block size; shrunk to a divisor of T.
       interpret: force Pallas interpret mode; default auto (True off-TPU).
 
@@ -148,4 +148,5 @@ def mla_decode_attention(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="mla_decode",
     )(lens, q_lat, q_rope, ckv, krope)

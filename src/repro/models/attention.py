@@ -39,10 +39,13 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.models.layers import Ctx, Params, _init_dense, apply_rope, dense
+from repro.models.layers import (Ctx, Params, _init_dense, apply_rope, dense,
+                                 rmsnorm, yarn_mscale)
 from repro.distributed.sharding import shard
 from repro.kernels.decode_attention import decode_attention
 from repro.kernels.flash_attention import flash_gqa_attention
+from repro.kernels.mla_decode import mla_decode_attention
+from repro.kernels.mla_prefill import mla_prefill_attention
 
 NEG_INF = -1e30
 
@@ -306,21 +309,30 @@ def cross_attention(ctx: Ctx, p: Params, x: jnp.ndarray,
 def init_mla(key, cfg: ModelConfig, dtype=jnp.float32):
     a = cfg.mla
     d, h = cfg.d_model, cfg.n_heads
+    qh = h * (a.nope_head_dim + a.rope_head_dim)
     ks = jax.random.split(key, 6)
-    # q: d -> q_lora -> h*(nope+rope)
-    pdq, adq = _init_dense(ks[0], d, a.q_lora, ("embed", "state"), dtype=dtype)
-    puq, auq = _init_dense(ks[1], a.q_lora, h * (a.nope_head_dim + a.rope_head_dim),
-                           ("state", "heads"), dtype=dtype)
-    # kv: d -> kv_lora (+ shared rope dims)
-    pdkv, adkv = _init_dense(ks[2], d, a.kv_lora + a.rope_head_dim, ("embed", "state"), dtype=dtype)
+    p, ax = {}, {}
+    if a.q_lora is None:
+        # q: d -> h*(nope+rope) in one projection
+        p["q"], ax["q"] = _init_dense(ks[0], d, qh, ("embed", "heads"), dtype=dtype)
+    else:
+        # q: d -> q_lora (RMS-normalised) -> h*(nope+rope)
+        p["dq"], ax["dq"] = _init_dense(ks[0], d, a.q_lora, ("embed", "state"), dtype=dtype)
+        p["q_norm"], ax["q_norm"] = {"g": jnp.ones((a.q_lora,), dtype)}, {"g": ("state",)}
+        p["uq"], ax["uq"] = _init_dense(ks[1], a.q_lora, qh, ("state", "heads"), dtype=dtype)
+    # kv: d -> kv_lora (+ shared rope dims); the latent is RMS-normalised
+    # before it is cached or up-projected
+    p["dkv"], ax["dkv"] = _init_dense(ks[2], d, a.kv_lora + a.rope_head_dim,
+                                      ("embed", "state"), dtype=dtype)
+    p["kv_norm"], ax["kv_norm"] = {"g": jnp.ones((a.kv_lora,), dtype)}, {"g": ("state",)}
     # up: kv_lora -> h*(nope) for K and h*(v_head) for V
-    puk, auk = _init_dense(ks[3], a.kv_lora, h * a.nope_head_dim, ("state", "heads"), dtype=dtype)
-    puv, auv = _init_dense(ks[4], a.kv_lora, h * a.v_head_dim, ("state", "heads"), dtype=dtype)
-    po, ao = _init_dense(ks[5], h * a.v_head_dim, d, ("heads", "embed"), dtype=dtype)
-    return (
-        {"dq": pdq, "uq": puq, "dkv": pdkv, "uk": puk, "uv": puv, "o": po},
-        {"dq": adq, "uq": auq, "dkv": adkv, "uk": auk, "uv": auv, "o": ao},
-    )
+    p["uk"], ax["uk"] = _init_dense(ks[3], a.kv_lora, h * a.nope_head_dim,
+                                    ("state", "heads"), dtype=dtype)
+    p["uv"], ax["uv"] = _init_dense(ks[4], a.kv_lora, h * a.v_head_dim,
+                                    ("state", "heads"), dtype=dtype)
+    p["o"], ax["o"] = _init_dense(ks[5], h * a.v_head_dim, d, ("heads", "embed"),
+                                  dtype=dtype)
+    return p, ax
 
 
 def init_mla_cache(cfg: ModelConfig, batch: int, max_len: int, dtype) -> Dict[str, Any]:
@@ -332,6 +344,17 @@ def init_mla_cache(cfg: ModelConfig, batch: int, max_len: int, dtype) -> Dict[st
     }
 
 
+def mla_softmax_scale(cfg: ModelConfig) -> float:
+    """``(nope + rope)^-1/2``, times YaRN's ``mscale(factor,
+    mscale_all_dim)^2`` under rope scaling (DeepSeek-V2's attention)."""
+    a = cfg.mla
+    scale = (a.nope_head_dim + a.rope_head_dim) ** -0.5
+    sc = cfg.rope_scaling
+    if sc is not None and sc.mscale_all_dim:
+        scale *= yarn_mscale(sc.factor, sc.mscale_all_dim) ** 2
+    return scale
+
+
 def mla_attention(
     ctx: Ctx,
     p: Params,
@@ -339,20 +362,33 @@ def mla_attention(
     positions: jnp.ndarray,
     cache: Optional[Dict[str, Any]] = None,
 ) -> Tuple[jnp.ndarray, Optional[Dict[str, Any]]]:
-    """Multi-head Latent Attention with compressed-KV cache (deepseek-v2)."""
+    """Multi-head Latent Attention with compressed-KV cache (deepseek-v2).
+
+    With ``attn_impl="kernel"`` cached attention runs *absorbed*
+    (DeepSeek-V2 §2.1.2): W_uk folds into the query and W_uv into the
+    output, so scores and values are taken straight from the latent cache
+    by the length-aware kernels — ``mla_decode`` for one query per row,
+    ``mla_prefill`` for a chunk. The einsum path up-projects the cache.
+    """
     cfg = ctx.cfg
     a = cfg.mla
     b, s, _ = x.shape
     h = cfg.n_heads
+    eps, theta, rs = cfg.norm_eps, cfg.rope_theta, cfg.rope_scaling
 
-    cq = dense(ctx, p["dq"], x, "attn_qkv")
-    q = dense(ctx, p["uq"], cq, "attn_qkv").reshape(b, s, h, a.nope_head_dim + a.rope_head_dim)
+    if a.q_lora is None:
+        q = dense(ctx, p["q"], x, "attn_qkv")
+    else:
+        cq = rmsnorm(p["q_norm"], dense(ctx, p["dq"], x, "attn_qkv"), eps)
+        q = dense(ctx, p["uq"], cq, "attn_qkv")
+    q = q.reshape(b, s, h, a.nope_head_dim + a.rope_head_dim)
     q_nope, q_rope = jnp.split(q, [a.nope_head_dim], axis=-1)
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
+    q_rope = apply_rope(q_rope, positions, theta, rs)
 
     dkv = dense(ctx, p["dkv"], x, "attn_qkv")
     ckv, krope = jnp.split(dkv, [a.kv_lora], axis=-1)
-    krope = apply_rope(krope[:, :, None, :], positions, cfg.rope_theta)[:, :, 0, :]
+    ckv = rmsnorm(p["kv_norm"], ckv, eps)
+    krope = apply_rope(krope[:, :, None, :], positions, theta, rs)[:, :, 0, :]
 
     if cache is not None:
         start = cache["len"]                     # (B,) per-sequence lengths
@@ -364,32 +400,30 @@ def mla_attention(
         start = jnp.zeros((b,), jnp.int32)
         ckv_all, krope_all, new_cache, t = ckv, krope, None, s
 
-    scale = 1.0 / jnp.sqrt(a.nope_head_dim + a.rope_head_dim).astype(jnp.float32)
-    causal = _cached_mask(start, s, t)           # (B, 1, s, t)
-
-    if s == 1 and cache is not None:
-        # *absorbed* decode (DeepSeek-V2 §2.1.2): fold W_uk into the query and
-        # W_uv into the output so attention runs directly in the compressed
-        # latent space — O(t * kv_lora) per head instead of up-projecting the
-        # whole cache per step (which would be ~100x more FLOPs at 32k ctx).
+    scale = mla_softmax_scale(cfg)
+    if cache is not None and (s == 1 or cfg.attn_impl == "kernel"):
+        # absorbed: O(t * kv_lora) per head, never up-projecting the cache
+        # (which would be ~100x more FLOPs per decode step at 32k ctx)
         wuk = p["uk"]["w"].astype(x.dtype).reshape(a.kv_lora, h, a.nope_head_dim)
-        q_lat = jnp.einsum("bshd,lhd->bshl", q_nope, wuk)          # (b,1,h,lora)
         wuv = p["uv"]["w"].astype(x.dtype).reshape(a.kv_lora, h, a.v_head_dim)
-        if cfg.attn_impl == "kernel":
-            # length-aware latent-cache decode kernel: O(lens) cache traffic
-            # + online softmax instead of the full-(B, t) masked einsum
-            from repro.kernels.mla_decode import mla_decode_attention
-
-            out_lat = mla_decode_attention(
-                q_lat[:, 0], q_rope[:, 0], ckv_all, krope_all, start + 1,
-                scale=float(1.0 / (a.nope_head_dim + a.rope_head_dim) ** 0.5),
-            )[:, None]                                             # (b,1,h,lora)
+        q_lat = jnp.einsum("bshd,lhd->bshl", q_nope, wuk)          # (b,s,h,lora)
+        if cfg.attn_impl == "kernel" and s == 1:
+            with jax.named_scope("mla.decode"):
+                # 512-key blocks: a latent row is one lane-dense 1 KB
+                # read, so few large blocks keep the grid short
+                out_lat = mla_decode_attention(
+                    q_lat[:, 0], q_rope[:, 0], ckv_all, krope_all, start + 1,
+                    scale=scale, block_k=512)[:, None]             # (b,1,h,lora)
+        elif cfg.attn_impl == "kernel":
+            with jax.named_scope("mla.prefill"):
+                out_lat = mla_prefill_attention(
+                    q_lat, q_rope, ckv_all, krope_all, start, scale=scale)
         else:
             logits = (
                 jnp.einsum("bshl,btl->bhst", q_lat, ckv_all)
                 + jnp.einsum("bshd,btd->bhst", q_rope, krope_all)
             ).astype(jnp.float32) * scale
-            logits = jnp.where(causal, logits, NEG_INF)
+            logits = jnp.where(_cached_mask(start, s, t), logits, NEG_INF)
             probs = jax.nn.softmax(logits, axis=-1).astype(x.dtype)
             out_lat = jnp.einsum("bhst,btl->bshl", probs, ckv_all)  # (b,1,h,lora)
         out = jnp.einsum("bshl,lhv->bshv", out_lat, wuv)
@@ -403,7 +437,7 @@ def mla_attention(
             jnp.einsum("bshd,bthd->bhst", q_nope, k_nope)
             + jnp.einsum("bshd,btd->bhst", q_rope, krope_all)
         ).astype(jnp.float32) * scale
-        logits = jnp.where(causal, logits, NEG_INF)
+        logits = jnp.where(_cached_mask(start, s, t), logits, NEG_INF)
         probs = jax.nn.softmax(logits, axis=-1).astype(v.dtype)
         out = jnp.einsum("bhst,bthd->bshd", probs, v)
 

@@ -11,10 +11,12 @@ framework feature.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.core import quant
@@ -74,6 +76,11 @@ class Ctx:
     # mode adds the per-row analytically-equivalent extra output noise of the
     # reduced vote count (core.cim.vote_drop_extra_std_int, DESIGN.md §16)
     degrade_rows: Optional[jnp.ndarray] = None  # (B,) int32 ladder level/row
+    moe_log: Optional[list] = None      # per-layer scratch: held_experts
+    # appends (routed rows per held expert, rows its grouped matmul
+    # computed, held experts with rows); transformer._scan_blocks sums it
+    # over layers into moe_rows
+    moe_rows: Optional[Tuple[jnp.ndarray, ...]] = None
 
     @classmethod
     def make(cls, cfg: ModelConfig, key: Optional[jax.Array] = None,
@@ -249,16 +256,51 @@ def layernorm(p: Params, x: jnp.ndarray, eps: float = 1e-5) -> jnp.ndarray:
 
 # ------------------------------------------------------------------ RoPE
 
-def rope_freqs(head_dim: int, theta: float) -> jnp.ndarray:
-    return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention factor ``0.1·mscale·ln(factor) + 1`` (1 unscaled)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
 
 
-def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> jnp.ndarray:
-    """x: (B, S, H, D); positions: (B, S) or (S,)."""
+def _yarn_ramp(head_dim: int, theta: float, sc) -> np.ndarray:
+    """Per frequency pair, 1 where YaRN keeps the original frequency
+    (fast rotations) falling to 0 where it interpolates (slow ones)."""
+    def corr(rot):
+        return (head_dim * math.log(sc.original_max_position_embeddings
+                                    / (rot * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(corr(sc.beta_fast)), 0)
+    high = min(math.ceil(corr(sc.beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    i = np.arange(head_dim // 2, dtype=np.float32)
+    return 1.0 - np.clip((i - low) / (high - low), 0.0, 1.0)
+
+
+def rope_freqs(head_dim: int, theta: float, scaling=None) -> jnp.ndarray:
+    """Inverse frequencies of the ``head_dim // 2`` rotary pairs; with a
+    ``RopeScaling`` the YaRN blend of the original and the
+    ``factor``-interpolated frequencies."""
+    freqs = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
+    if scaling is None:
+        return freqs
+    keep = jnp.asarray(_yarn_ramp(head_dim, theta, scaling))
+    return freqs / scaling.factor * (1.0 - keep) + freqs * keep
+
+
+def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float,
+               scaling=None) -> jnp.ndarray:
+    """x: (B, S, H, D); positions: (B, S) or (S,). ``scaling``: an optional
+    ``RopeScaling`` (YaRN frequencies and cos/sin factor)."""
     d = x.shape[-1]
-    freqs = rope_freqs(d, theta)                      # (D/2,)
+    freqs = rope_freqs(d, theta, scaling)             # (D/2,)
     ang = positions[..., None].astype(jnp.float32) * freqs  # (B, S, D/2)
     cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if scaling is not None:
+        m = (yarn_mscale(scaling.factor, scaling.mscale)
+             / yarn_mscale(scaling.factor, scaling.mscale_all_dim))
+        if m != 1.0:
+            cos, sin = cos * m, sin * m
     if cos.ndim == 2:                                  # (S, D/2) -> broadcast B
         cos, sin = cos[None], sin[None]
     cos, sin = cos[:, :, None, :], sin[:, :, None, :]  # (B, S, 1, D/2)

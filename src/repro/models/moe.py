@@ -30,14 +30,16 @@ def init_moe(key, cfg: ModelConfig, dtype=jnp.float32):
     m = cfg.moe
     d, f = cfg.d_model, cfg.d_ff
     kr, ke, ks = jax.random.split(key, 3)
+    # the router scores every expert; the banks hold only this device's
     pr, ar = _init_dense(kr, d, m.n_experts, ("embed", None), dtype=jnp.float32)
     lim = 1.0 / jnp.sqrt(d)
     kw1, kw2, kw3 = jax.random.split(ke, 3)
+    e = m.held
     p = {
         "router": pr,
-        "w_gate": jax.random.uniform(kw1, (m.n_experts, d, f), dtype, -lim, lim),
-        "w_up": jax.random.uniform(kw2, (m.n_experts, d, f), dtype, -lim, lim),
-        "w_down": jax.random.uniform(kw3, (m.n_experts, f, d), dtype, -lim, lim),
+        "w_gate": jax.random.uniform(kw1, (e, d, f), dtype, -lim, lim),
+        "w_up": jax.random.uniform(kw2, (e, d, f), dtype, -lim, lim),
+        "w_down": jax.random.uniform(kw3, (e, f, d), dtype, -lim, lim),
     }
     a = {
         "router": ar,
@@ -83,16 +85,31 @@ def _dp_degree() -> int:
     return _dp_axes()[2]
 
 
+def _gates(m, probs: jnp.ndarray):
+    """Top-k gates and experts of router probabilities (..., E): the
+    gates renormalised to sum to 1 where ``norm_topk``, then scaled by
+    ``routed_scale``."""
+    gate_vals, expert_idx = jax.lax.top_k(probs, m.top_k)
+    if m.norm_topk:
+        gate_vals = gate_vals / (jnp.sum(gate_vals, axis=-1, keepdims=True)
+                                 + 1e-9)
+    if m.routed_scale != 1.0:
+        gate_vals = gate_vals * m.routed_scale
+    return gate_vals, expert_idx
+
+
 def moe_block(ctx: Ctx, p: Params, x: jnp.ndarray,
               dropless: bool = False) -> jnp.ndarray:
     """x: (B, S, d) -> (B, S, d).
 
-    ``dropless=True`` sizes the capacity buffer at the worst case
-    (``tl * top_k``) so routing never drops a token: serving paths use it so
-    a token's output cannot depend on how many other tokens share its
+    ``dropless=True`` (serving) keeps every token's every routed expert,
+    so a token's output cannot depend on how many other tokens share its
     fixed-shape program (chunked prefill must be token-for-token equal to
-    whole-prompt prefill, DESIGN.md §15); training keeps the classic
-    capacity-factor buffer.
+    whole-prompt prefill, DESIGN.md §15). With digital experts it runs
+    ``held_experts``: a grouped matmul over the rows routed to this
+    device's experts. Otherwise (and in training, which keeps the classic
+    capacity-factor buffer) the capacity path below serves it, its buffer
+    sized at the worst case ``tl * top_k`` when dropless.
 
     Dispatch is *local per DP shard*: tokens are reshaped to a leading
     (dp_degree,)-group dim that aligns 1:1 with the DP mesh axes, and the
@@ -107,6 +124,15 @@ def moe_block(ctx: Ctx, p: Params, x: jnp.ndarray,
     m = cfg.moe
     b, s, d = x.shape
     t = b * s
+    if dropless and ctx.spec_for("moe_expert") is None:
+        y = held_experts(ctx, p, x.reshape(t, d)).reshape(b, s, d)
+        if m.n_shared:
+            y = y + swiglu(ctx, p["shared"], x)
+        return y
+    if m.held != m.n_experts:
+        raise ValueError(
+            f"a layer holding {m.held} of {m.n_experts} experts serves "
+            "through held_experts alone (dropless, digital experts)")
     groups = _dp_degree()
     # grouped/shard_map dispatch pays off at training/prefill token counts;
     # at decode-size batches it forces XLA to gather FSDP expert weights
@@ -122,8 +148,7 @@ def moe_block(ctx: Ctx, p: Params, x: jnp.ndarray,
     # router (digital, f32)
     logits = dense(ctx, p["router"], xg.astype(jnp.float32), "router")
     probs = jax.nn.softmax(logits, axis=-1)
-    gate_vals, expert_idx = jax.lax.top_k(probs, m.top_k)      # (G, tl, k)
-    gate_vals = gate_vals / (jnp.sum(gate_vals, axis=-1, keepdims=True) + 1e-9)
+    gate_vals, expert_idx = _gates(m, probs)                    # (G, tl, k)
 
     if dropless:
         capacity = tl * m.top_k
@@ -189,6 +214,88 @@ def moe_block(ctx: Ctx, p: Params, x: jnp.ndarray,
 
     if m.n_shared:
         y = y + swiglu(ctx, p["shared"], x.reshape(b, s, d)).reshape(b, s, d)
+    return y
+
+
+def _tile(dim: int) -> int:
+    """A grouped-matmul tile along a weight dimension: the whole dimension
+    up to 1536, else its largest multiple-of-128 divisor up to 512."""
+    if dim <= 1536:
+        return dim
+    return max(t for t in range(128, 513, 128) if dim % t == 0)
+
+
+def expert_tile_rows(t: int, m) -> int:
+    """Rows per tile of the grouped matmul for ``t`` routed tokens: about
+    four times the rows an expert expects (``t·top_k/n_experts``), a power
+    of two in [16, 128]. Larger tiles hold a group in fewer tiles, so
+    fewer tiles reread its weights; smaller ones compute fewer pad rows."""
+    tm = 16
+    while tm < 128 and tm < 4 * t * m.top_k / m.n_experts:
+        tm *= 2
+    return tm
+
+
+def _gmm(x, w, sizes, tm):
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+    k, n = w.shape[1:]
+    return gmm(x, w.astype(x.dtype), sizes, preferred_element_type=jnp.float32,
+               tiling=(tm, _tile(k), _tile(n)),
+               interpret=jax.default_backend() != "tpu")
+
+
+def held_experts(ctx: Ctx, p: Params, x: jnp.ndarray) -> jnp.ndarray:
+    """The held experts' part of the routed sum for tokens x: (T, d).
+
+    The router scores all ``n_experts`` and keeps each token's top-k, as
+    published; only assignments to the held experts
+    ``[held_first, held_first + held)`` are computed here, and what the
+    other devices' experts add is not (expert parallelism without its
+    exchange). Held assignments are sorted by expert into one row buffer
+    and run through a grouped matmul (megablox ``gmm``), which visits only
+    the row tiles that hold routed rows: the work follows the rows
+    actually routed here plus each group's tile padding, whatever the
+    buffer's static size (``T·min(top_k, held)`` rows, the most that can
+    be routed here). Appends ``(routed rows per held expert, rows the
+    matmul computed, held experts that had rows)`` to ``ctx.moe_log``
+    where the caller collects them.
+    """
+    m = ctx.cfg.moe
+    t, d = x.shape
+    h, k = m.held, m.top_k
+    with jax.named_scope("moe.route"):
+        # full float32: a near-tie of the k-th and (k+1)-th expert must
+        # not turn on the matmul's bf16 passes
+        with jax.default_matmul_precision("highest"):
+            logits = dense(ctx, p["router"], x.astype(jnp.float32), "router")
+        gate_vals, expert_idx = _gates(m, jax.nn.softmax(logits, axis=-1))
+        rel = expert_idx.reshape(-1) - m.held_first        # (T·k,)
+        held = (rel >= 0) & (rel < h)
+        group = jnp.where(held, rel, h)
+        order = jnp.argsort(group, stable=True)
+        sizes = jnp.bincount(group, length=h + 1)[:h].astype(jnp.int32)
+        tm = expert_tile_rows(t, m)
+        rows = -(-t * min(k, h) // tm) * tm
+        order = jnp.pad(order, (0, max(0, rows - t * k)))[:rows]
+        src = order // k                                   # token of each row
+        total = jnp.sum(sizes)
+        live = jnp.arange(rows) < total
+        gate = jnp.where(live, gate_vals.reshape(-1)[order], 0.0)
+    with jax.named_scope("moe.experts"):
+        xs = x[src]
+        g = _gmm(xs, p["w_gate"], sizes, tm)
+        u = _gmm(xs, p["w_up"], sizes, tm)
+        hid = (jax.nn.silu(g) * u).astype(x.dtype)
+        out = _gmm(hid, p["w_down"], sizes, tm)
+        # rows past the routed ones are never written by the matmul
+        out = jnp.where(live[:, None], out, 0.0) * gate[:, None]
+        y = jnp.zeros((t, d), jnp.float32).at[src].add(out).astype(x.dtype)
+    if ctx.moe_log is not None:
+        ends = jnp.cumsum(sizes)
+        tiles = jnp.where(sizes > 0, -(-ends // tm) - (ends - sizes) // tm, 0)
+        ctx.moe_log.append((sizes, jnp.sum(tiles) * tm,
+                            jnp.sum(sizes > 0).astype(jnp.int32)))
     return y
 
 

@@ -152,13 +152,39 @@ def _init_moe_block(key, cfg: ModelConfig):
             {"attn": aa, "moe": am, "n1": an1, "n2": an2})
 
 
-def _moe_block(ctx: Ctx, p: Params, x, positions, cache):
+def _init_lead_block(key, cfg: ModelConfig):
+    """A leading dense block of a ``moe`` model: its attention, and a dense
+    SwiGLU of width ``dense_d_ff`` in place of the expert layer."""
+    dt = _dtype(cfg)
+    k1, k2 = jax.random.split(key)
+    if cfg.mla is not None:
+        pa, aa = attn.init_mla(k1, cfg, dt)
+    else:
+        pa, aa = attn.init_gqa(k1, cfg, dt)
+    pm, am = init_swiglu(k2, cfg.d_model, cfg.dense_d_ff, dt)
+    pn1, an1 = init_rmsnorm(cfg.d_model, dt)
+    pn2, an2 = init_rmsnorm(cfg.d_model, dt)
+    return ({"attn": pa, "mlp": pm, "n1": pn1, "n2": pn2},
+            {"attn": aa, "mlp": am, "n1": an1, "n2": an2})
+
+
+def _moe_attention(ctx: Ctx, p: Params, x, positions, cache):
     xn = rmsnorm(p["n1"], x, ctx.cfg.norm_eps)
     if ctx.cfg.mla is not None:
         h, new_cache = attn.mla_attention(ctx, p["attn"], xn, positions, cache)
     else:
         h, new_cache = attn.gqa_attention(ctx, p["attn"], xn, positions, cache)
-    x = x + h
+    return x + h, new_cache
+
+
+def _lead_block(ctx: Ctx, p: Params, x, positions, cache):
+    x, new_cache = _moe_attention(ctx, p, x, positions, cache)
+    x = x + swiglu(ctx, p["mlp"], rmsnorm(p["n2"], x, ctx.cfg.norm_eps))
+    return shard(x, "batch", "seq", "embed"), new_cache
+
+
+def _moe_block(ctx: Ctx, p: Params, x, positions, cache):
+    x, new_cache = _moe_attention(ctx, p, x, positions, cache)
     # serving (cached) forwards route dropless so a token's experts cannot
     # depend on how many tokens share the fixed-shape program — chunked
     # prefill stays token-for-token equal to whole-prompt prefill
@@ -205,11 +231,19 @@ def init_params(cfg: ModelConfig, key) -> Tuple[Params, Params]:
         p["embed"], a["embed"] = init_embedding(keys[0], cfg.vocab_size, cfg.d_model, dt)
     p["final_norm"], a["final_norm"] = init_rmsnorm(cfg.d_model, dt)
 
+    k_head, k_lead = jax.random.split(keys[3])
+    if not cfg.tie_embeddings and cfg.vocab_size:
+        p["head"], a["head"] = init_embedding(k_head, cfg.vocab_size,
+                                              cfg.d_model, dt)
     fam = cfg.family
     if fam in _BLOCKS:
         init_one = _BLOCKS[fam][0]
-        p["blocks"], a["blocks"] = _stack_init(lambda k: init_one(k, cfg),
-                                               cfg.n_layers, keys[1])
+        p["blocks"], a["blocks"] = _stack_init(
+            lambda k: init_one(k, cfg), cfg.n_layers - cfg.n_dense_layers,
+            keys[1])
+        if cfg.n_dense_layers:
+            p["lead_blocks"], a["lead_blocks"] = _stack_init(
+                lambda k: _init_lead_block(k, cfg), cfg.n_dense_layers, k_lead)
     elif fam == "hybrid":
         n_super = cfg.n_layers // cfg.attn_period
         n_mamba = cfg.attn_period - 1
@@ -265,9 +299,15 @@ def init_caches(cfg: ModelConfig, batch: int, max_len: int) -> Any:
     if fam in ("dense", "vlm"):
         return stack(lambda: attn.init_gqa_cache(cfg, batch, max_len, dt), cfg.n_layers)
     if fam == "moe":
-        if cfg.mla is not None:
-            return stack(lambda: attn.init_mla_cache(cfg, batch, max_len, dt), cfg.n_layers)
-        return stack(lambda: attn.init_gqa_cache(cfg, batch, max_len, dt), cfg.n_layers)
+        init = attn.init_mla_cache if cfg.mla is not None else attn.init_gqa_cache
+        n_lead = cfg.n_dense_layers
+        body = stack(lambda: init(cfg, batch, max_len, dt), cfg.n_layers - n_lead)
+        if not n_lead:
+            return body
+        # one stacked cache per layer group: a step scans each in place,
+        # never splitting or restacking one stack of all the layers
+        return {"lead": stack(lambda: init(cfg, batch, max_len, dt), n_lead),
+                "blocks": body}
     if fam == "ssm":
         return stack(lambda: ssm_mod.init_ssm_cache(cfg, batch, dt), cfg.n_layers)
     if fam == "hybrid":
@@ -371,11 +411,15 @@ def _embed_input(cfg: ModelConfig, params: Params, batch: Dict[str, Any]):
     return shard(x, "batch", "seq", "embed")
 
 
-def _scan_blocks(ctx: Ctx, blocks: Params, block_fn, x, positions, caches):
+def _scan_blocks(ctx: Ctx, blocks: Params, block_fn, x, positions, caches,
+                 first: int = 0):
+    """Scan ``block_fn`` over stacked ``blocks``, the first of them layer
+    ``first`` of the model (its key and its rows in the guard counts)."""
     cfg = ctx.cfg
     n = jax.tree.leaves(blocks)[0].shape[0]
     base_key = ctx.key if ctx.key is not None else jax.random.PRNGKey(0)
     guard = ctx.guard is not None
+    moe_log = ctx.moe_log is not None
     b = x.shape[0]
 
     def body(carry, xs):
@@ -387,33 +431,49 @@ def _scan_blocks(ctx: Ctx, blocks: Params, block_fn, x, positions, caches):
         layer_cache = None if caches is None else jax.tree.map(
             lambda c: jax.lax.dynamic_index_in_dim(c, idx, keepdims=False),
             caches)
-        lctx = dataclasses.replace(ctx, key=jax.random.fold_in(base_key, idx), counter=0)
+        lctx = dataclasses.replace(
+            ctx, key=jax.random.fold_in(base_key, first + idx), counter=0)
         if guard:
             # fresh scratch lists per layer; guarded_dense appends (B,)
             # trip/hard counts which we drain into the scan ys -> (L, B)
             lctx.trip_log, lctx.hard_log = [], []
             if ctx.pin_layers is not None:
-                lctx.pin_rows = jnp.take(ctx.pin_layers, idx, axis=1)
+                lctx.pin_rows = jnp.take(ctx.pin_layers, first + idx, axis=1)
+        if moe_log:
+            lctx.moe_log = []
         h, new_cache = block_fn(lctx, layer_p, h, positions, layer_cache)
         if caches is not None:
             caches = jax.tree.map(
                 lambda c, u: jax.lax.dynamic_update_index_in_dim(c, u, idx, 0),
                 caches, new_cache)
+        ys = {}
         if guard:
             zero = jnp.zeros((b,), jnp.int32)
-            trips = sum(lctx.trip_log, zero) if lctx.trip_log else zero
-            hard = sum(lctx.hard_log, zero) if lctx.hard_log else zero
-            return (h, caches), (trips, hard)
-        return (h, caches), None
+            ys["trips"] = sum(lctx.trip_log, zero) if lctx.trip_log else zero
+            ys["hard"] = sum(lctx.hard_log, zero) if lctx.hard_log else zero
+        if moe_log and lctx.moe_log:
+            ys["moe"] = jax.tree.map(lambda *v: sum(v), *lctx.moe_log)
+        return (h, caches), ys
 
     if cfg.remat:
         body = jax.checkpoint(body)
     (x, new_caches), ys = scan_or_loop(cfg, body, (x, caches),
                                        (blocks, jnp.arange(n)), n)
+    # side-channel outputs: read off the Ctx by the engine closures at
+    # trace time (the Ctx is a fresh python object per traced call); a
+    # later layer group (first > 0) adds to what the first one left
     if guard:
-        # side-channel outputs: read off the Ctx by the engine closures at
-        # trace time (the Ctx is a fresh python object per traced call)
-        ctx.guard_trips, ctx.guard_hard = ys
+        if first:
+            ys["trips"] = jnp.concatenate([ctx.guard_trips, ys["trips"]])
+            ys["hard"] = jnp.concatenate([ctx.guard_hard, ys["hard"]])
+        ctx.guard_trips, ctx.guard_hard = ys["trips"], ys["hard"]
+    if not first:
+        ctx.moe_rows = None
+    if "moe" in ys:
+        rows = jax.tree.map(lambda v: jnp.sum(v, axis=0), ys["moe"])
+        if ctx.moe_rows is not None:
+            rows = jax.tree.map(jnp.add, ctx.moe_rows, rows)
+        ctx.moe_rows = rows
     return x, new_caches
 
 
@@ -434,11 +494,27 @@ def forward(params: Params, batch: Dict[str, Any], cfg: ModelConfig,
     else:
         positions = _cache_positions(cfg, caches, b, s)
         cache_arg = caches
-    x, new_caches = _scan_blocks(ctx, params["blocks"], _BLOCKS[cfg.family][1],
-                                 x, positions, cache_arg)
+    block_fn = _BLOCKS[cfg.family][1]
+    if cfg.n_dense_layers:
+        # the leading dense blocks, then the rest, each over its own cache
+        lead_c, body_c = ((None, None) if caches is None
+                          else (caches["lead"], caches["blocks"]))
+        x, lead = _scan_blocks(ctx, params["lead_blocks"], _lead_block, x,
+                               positions, lead_c)
+        x, body = _scan_blocks(ctx, params["blocks"], block_fn, x, positions,
+                               body_c, first=cfg.n_dense_layers)
+        new_caches = None if caches is None else {"lead": lead, "blocks": body}
+    else:
+        x, new_caches = _scan_blocks(ctx, params["blocks"], block_fn, x,
+                                     positions, cache_arg)
     x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    logits = unembed(ctx, params["embed"], x)
+    logits = unembed(ctx, _head(params), x)
     return shard(logits, "batch", "seq", "vocab"), new_caches
+
+
+def _head(params: Params) -> Params:
+    """The logits head: its own matrix, or the embedding where tied."""
+    return params.get("head", params["embed"])
 
 
 def _cache_len(cfg: ModelConfig, caches) -> jnp.ndarray:
@@ -450,6 +526,8 @@ def _cache_len(cfg: ModelConfig, caches) -> jnp.ndarray:
         return caches["attn"]["len"][0]
     if cfg.family == "encdec":
         return caches["self"]["len"][0]
+    if cfg.n_dense_layers:
+        return caches["blocks"]["len"][0]
     return caches["len"][0]
 
 
@@ -497,7 +575,7 @@ def _hybrid_forward(params, batch, cfg, ctx, caches=None):
           jnp.arange(n_super))
     x, new_caches = scan_or_loop(cfg, body, x, xs, n_super)
     x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    logits = unembed(ctx, params["embed"], x)
+    logits = unembed(ctx, _head(params), x)
     return shard(logits, "batch", "seq", "vocab"), new_caches
 
 
@@ -573,7 +651,7 @@ def _encdec_forward(params, batch, cfg, ctx, caches=None):
         new_self, new_cross = ys
         new_caches = {"self": new_self, "cross": new_cross}
     x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    logits = unembed(ctx, params["embed"], x)
+    logits = unembed(ctx, _head(params), x)
     return shard(logits, "batch", "seq", "vocab"), new_caches
 
 

@@ -57,7 +57,15 @@ iteration, holding ``engine.fill`` (slot admission), ``engine.stage``
 (host arrays, transfers, key draws) and one ``engine.launch.<program>``
 per jitted call, which carries the rows it computes (``rows``,
 ``pad_rows``, ``decode_rows``, ``prefill_rows``); ``engine.drain`` around
-the blocking device->host token copy. With no profiler running a span
+the blocking device->host token copy. A model whose expert layers run the
+grouped matmul (``models.moe.held_experts``) also returns, from every
+launch, the rows routed to each held expert and the rows the matmul
+computed, summed over its layers; the drain fetches them with the tokens
+and puts them on an ``engine.drain.experts`` span inside ``engine.drain``
+(stats ``routed_rows``, ``expert_pad_rows``, ``expert_groups`` — the
+(layer, held expert) pairs that had rows, whose weights the matmul read —
+``expert_rows``, one count per held expert comma-joined, and
+``launches``). With no profiler running a span
 costs about a microsecond and touches nothing on the device.
 ``Engine.counters`` (``serving.metrics.ServingCounters``) keeps the same
 counts, cumulative over the engine's lifetime.
@@ -510,10 +518,19 @@ class Engine:
                         if self.ladder is not None else ())
 
         drift_spec = self.drift
+        # expert layers on the grouped-matmul path report their rows
+        # (held_experts) from every program
+        moe_stats = (cfg.moe is not None and
+                     Ctx.make(cfg, mode=mode).spec_for("moe_expert") is None)
+        self._moe_stats = moe_stats
+        no_rows = ((jnp.zeros((cfg.moe.held,), jnp.int32), jnp.int32(0),
+                    jnp.int32(0)) if moe_stats else ())
 
         def make_ctx(kctx, pin, frow, lvl=None, dstate=None):
             ctx = Ctx.make(cfg, kctx, mode=mode, deployed=deployed,
                            guard=gspec, fault=fspec)
+            if moe_stats:
+                ctx.moe_log = []
             ctx.pin_layers = pin
             ctx.fault_rows = frow
             if ladder_votes and lvl is not None:
@@ -551,6 +568,8 @@ class Engine:
             out = (caches, last_tok.at[slot].set(tok), tok)
             if guard_on:
                 out = out + (ctx.guard_trips, ctx.guard_hard)   # (L, 1) each
+            if moe_stats:
+                out = out + (ctx.moe_rows,)
             return out
 
         def chunk_slot_core(params, slot_cache, prev_tok, tokens, reset,
@@ -611,6 +630,8 @@ class Engine:
             out = (caches, last_tok, tok)
             if guard_on:
                 out = out + (ctx.guard_trips, ctx.guard_hard)
+            if moe_stats:
+                out = out + (ctx.moe_rows,)
             return out
 
         def decode_core(params, caches, last_tok, active, temps, key,
@@ -633,9 +654,12 @@ class Engine:
             new_caches, toks, ctx = decode_core(
                 params, caches, last_tok, active, temps, key, rkeys,
                 tok_idx, lvls, dstate, pin, frow)
+            out = (new_caches, toks)
             if guard_on:
-                return new_caches, toks, ctx.guard_trips, ctx.guard_hard
-            return new_caches, toks
+                out = out + (ctx.guard_trips, ctx.guard_hard)
+            if moe_stats:
+                out = out + (ctx.moe_rows,)
+            return out
 
         n_slots = max_slots
 
@@ -698,21 +722,21 @@ class Engine:
 
                 def adv(ops):
                     sl, prev = ops
-                    sl, keep, tok, _ = chunk_slot_core(
+                    sl, keep, tok, ctx = chunk_slot_core(
                         params, sl, prev, toks_s, reset, valid, final,
                         temp, key, rkey, f[6], dstate)
-                    return sl, keep, tok
+                    return sl, keep, tok, ctx.moe_rows if moe_stats else ()
 
                 def skip(ops):
                     sl, prev = ops
-                    return sl, prev, jnp.int32(0)
+                    return sl, prev, jnp.int32(0), no_rows
 
-                sl, keep, tok = jax.lax.cond(pre, adv, skip,
-                                             (sl, last_tok[s]))
+                sl, keep, tok, rows = jax.lax.cond(pre, adv, skip,
+                                                   (sl, last_tok[s]))
                 return (tf.put_slot(caches, sl, s),
-                        last_tok.at[s].set(keep)), tok
+                        last_tok.at[s].set(keep)), (tok, rows)
 
-            (caches, last_tok), ptoks = jax.lax.scan(
+            (caches, last_tok), (ptoks, rows) = jax.lax.scan(
                 body, (caches, last_tok),
                 (jnp.arange(n_slots, dtype=jnp.int32), chunk_toks, flags,
                  temps, keys[:n_slots], rkeys))
@@ -721,14 +745,19 @@ class Engine:
 
             def dec(ops):
                 caches, last_tok = ops
-                caches, last_tok, _ = decode_core(
+                caches, last_tok, ctx = decode_core(
                     params, caches, last_tok, active, temps, keys[n_slots],
                     rkeys, flags[:, 5], flags[:, 6], dstate)
-                return caches, last_tok
+                return caches, last_tok, ctx.moe_rows if moe_stats else ()
 
-            caches, last_tok = jax.lax.cond(
-                jnp.any(active), dec, lambda ops: ops, (caches, last_tok))
-            return caches, last_tok, ptoks
+            caches, last_tok, dec_rows = jax.lax.cond(
+                jnp.any(active), dec, lambda ops: ops + (no_rows,),
+                (caches, last_tok))
+            if not moe_stats:
+                return caches, last_tok, ptoks
+            rows = jax.tree.map(lambda c, d: jnp.sum(c, axis=0) + d, rows,
+                                dec_rows)
+            return caches, last_tok, ptoks, rows
 
         # donate only the cache: last_tok/toks arrays stay referenced by the
         # pending-drain token log until device_get, so they must not alias
@@ -795,6 +824,8 @@ class Engine:
         # emitted tokens stay on device until drained:
         # ("p", scalar_dev_tok, req_idx) | ("d", (B,) dev_toks, per-slot idx)
         self._pend: List[Tuple[str, Any, Any]] = []
+        # each launch's expert rows, on device until drained with the tokens
+        self._pend_moe: List[Any] = []
         # host-side degradation state, per (slot, layer); reset on recycle
         self._pinned = np.zeros((S, self.cfg.n_layers), bool)
         for s in self.pin_slots:
@@ -953,6 +984,7 @@ class Engine:
         """
         self.dead = reason
         self._pend.clear()
+        self._pend_moe.clear()
 
     def wedge(self) -> None:
         """Simulate a wedged launch queue: steps no-op without erroring."""
@@ -966,11 +998,14 @@ class Engine:
         if self.dead is not None:
             raise RuntimeError(
                 f"replica {self.replica or '?'} dead: {self.dead}")
-        if not self._pend:
+        if not self._pend and not self._pend_moe:
             return
         n = 0
         with TraceAnnotation("engine.drain"):
-            vals = jax.device_get([e[1] for e in self._pend])
+            vals, moe = jax.device_get(([e[1] for e in self._pend],
+                                        self._pend_moe))
+            if moe:
+                self._note_expert_rows(moe)
             for (kind, _, meta), v in zip(self._pend, vals):
                 if kind == "p":
                     self._reqs[meta].out_tokens.append(int(v))
@@ -983,6 +1018,25 @@ class Engine:
             self._pend.clear()
         self.counters.drains += 1
         self.counters.tokens_drained += n
+
+    def _note_expert_rows(self, moe: List[Any]) -> None:
+        """Count the drained launches' expert rows (``held_experts``) and
+        mark them on the drain's trace."""
+        per_expert = np.sum([np.asarray(r) for r, _, _ in moe], axis=0)
+        computed = int(sum(int(c) for _, c, _ in moe))
+        groups = int(sum(int(g) for _, _, g in moe))
+        routed = int(per_expert.sum())
+        c = self.counters
+        c.expert_rows = (per_expert + (c.expert_rows or 0)).tolist()
+        c.expert_routed_rows += routed
+        c.expert_pad_rows += computed - routed
+        c.expert_groups += groups
+        with TraceAnnotation("engine.drain.experts", routed_rows=routed,
+                             expert_pad_rows=computed - routed,
+                             expert_groups=groups,
+                             expert_rows=",".join(map(str, per_expert)),
+                             launches=len(moe)):
+            self._pend_moe.clear()
 
     def generate(self, requests: List[Request]) -> List[Any]:
         """Run all requests to completion; returns generated token lists.
@@ -1201,6 +1255,11 @@ class Engine:
                                rows=decode + prefill, pad_rows=idle + pad,
                                decode_rows=decode, prefill_rows=prefill)
 
+    def _note_moe(self, out: tuple) -> None:
+        """Keep a launch's expert rows (its last output) for the drain."""
+        if self._moe_stats:
+            self._pend_moe.append(out[-1])
+
     def _fill_slots(self) -> None:
         with TraceAnnotation("engine.fill"):
             for s in range(self.max_slots):
@@ -1250,6 +1309,7 @@ class Engine:
                     slot=s))
                 return
         self.caches, self.last_tok, tok = out[:3]
+        self._note_moe(out)
         self._slots[s] = None
         if self.guard is not None:
             dead = self._note_guard(out[3], out[4], [(s, 0)])
@@ -1301,6 +1361,7 @@ class Engine:
                     finished = True        # slot freed -> refill
                     continue
             self.caches, self.last_tok, tok = out[:3]
+            self._note_moe(out)
             if guard_on:
                 dead = self._note_guard(out[3], out[4], [(s, 0)])
                 if dead:
@@ -1362,6 +1423,7 @@ class Engine:
                         _host_snapshot(self._lvl_slot), self._dstate(),
                         *self._guard_batch_args())
                 self.caches, toks = out[:2]
+                self._note_moe(out)
                 if guard_on:
                     self._note_guard(out[2], out[3], [(s, s)])
             except Exception as e:         # noqa: BLE001
@@ -1402,6 +1464,7 @@ class Engine:
             try:
                 out = self._decode(*args)
                 self.caches, toks = out[:2]
+                self._note_moe(out)
                 if guard_on:
                     gdead = self._note_guard(
                         out[2], out[3],
@@ -1524,7 +1587,7 @@ class Engine:
                 pad=int(prefilling.sum()) * self.chunk_size - n_valid):
             self._build("step", args)
             try:
-                caches, toks, ptoks = self._step(*args)
+                out = self._step(*args)
             except Exception as e:         # noqa: BLE001
                 self._fused_ok = False
                 self.counters.fused_fallbacks += 1
@@ -1532,7 +1595,8 @@ class Engine:
                     self.fused_step_error = "".join(
                         traceback.format_exception_only(type(e), e)).strip()
                 return False
-        self.caches = caches
+        self.caches, toks, ptoks = out[:3]
+        self._note_moe(out)
         self.last_tok = toks
         if any(m is not None for m in meta_p):
             self._pend.append(("d", ptoks, meta_p))

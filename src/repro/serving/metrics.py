@@ -112,6 +112,15 @@ class ServingCounters:
     drains: int = 0            # device->host token drains
     tokens_drained: int = 0
     fused_fallbacks: int = 0   # fused iterations that raised
+    # grouped-matmul expert layers (models.moe.held_experts), summed over
+    # layers and counted at the drain: rows routed to the held experts (in
+    # all, and to each: ``expert_rows``), the rows the matmul computed
+    # beyond them (tile padding), and the (layer, held expert) pairs that
+    # had rows (whose weights the matmul read)
+    expert_routed_rows: int = 0
+    expert_pad_rows: int = 0
+    expert_groups: int = 0
+    expert_rows: List[int] = dataclasses.field(default_factory=list)
 
     @property
     def traces(self) -> int:
@@ -122,8 +131,10 @@ class ServingCounters:
         return _COMPILE_TALLY["compiles"]
 
     def snapshot(self) -> Dict[str, int]:
-        """Every count, process-wide ones included, as a plain dict."""
+        """Every scalar count, process-wide ones included, as a plain dict
+        (``expert_rows``, one count per held expert, is read as is)."""
         out = dataclasses.asdict(self)
+        del out["expert_rows"]
         out.update(_COMPILE_TALLY)
         return out
 
