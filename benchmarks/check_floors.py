@@ -183,7 +183,7 @@ def check_megakernel() -> None:
     args = (jax.random.normal(ks[0], (b, h, lat)),
             jax.random.normal(ks[1], (b, h, rhd)),
             jax.random.normal(ks[2], (b, t, lat)),
-            jax.random.normal(ks[3], (b, t, rhd)),
+            jax.random.normal(ks[3], (b, rhd, t)),
             jnp.array([24, 7], jnp.int32), 1.0 / (lat + rhd) ** 0.5)
     mla_err = float(jnp.max(jnp.abs(
         mla_decode_attention(*args, block_k=8)

@@ -33,6 +33,12 @@ makes decode cost scale with the live context instead:
 ``cache_len + 1`` — the query's own key is written before attention).
 ``lens[b] == 0`` rows (never-touched slots) produce exactly zero output.
 
+The cache operands may be the whole layer stack ``(L, R, T, KV·D)`` the
+serving engine keeps: a scalar-prefetched ``layer`` and per-query ``rows``
+pick the layer and the cache row each query reads, inside the BlockSpec
+index maps, so no slice of the stack is materialised (DESIGN.md §10).
+A plain ``(B, T, KV·D)`` cache is the one-layer stack read in row order.
+
 Validated against ``ref.decode_attention_ref`` and the einsum path in
 interpret mode (tests/test_decode_attention.py); CPU callers get
 ``interpret=True`` automatically.
@@ -64,8 +70,25 @@ def _pick_block_k(t: int, block_k: int) -> int:
     return bk
 
 
-def _kernel(lens_ref, *refs, scale: float, block_k: int, kv_heads: int,
-            group: int, d: int, n_kb: int, int8: bool):
+def stack_operands(b: int, caches, layer, rows):
+    """The cache operands as layer stacks, and the (layer, rows) that
+    address them: a plain ``(B, T, ...)`` cache becomes a one-layer stack
+    (a free reshape) read at layer 0, rows ``0..B-1``. Shared by the four
+    attention kernels."""
+    if caches[0].ndim == 3:
+        caches = tuple(None if c is None else c[None] for c in caches)
+        layer = 0
+    elif layer is None:
+        raise ValueError("a stacked (L, R, T, W) cache needs its layer")
+    layer = jnp.reshape(jnp.asarray(layer, jnp.int32), (1,))
+    rows = (jnp.arange(b, dtype=jnp.int32) if rows is None
+            else jnp.asarray(rows, jnp.int32).reshape(b))
+    return caches, layer, rows
+
+
+def _kernel(lens_ref, layer_ref, rows_ref, *refs, scale: float,
+            block_k: int, kv_heads: int, group: int, d: int, n_kb: int,
+            int8: bool):
     if int8:
         q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = refs
     else:
@@ -119,6 +142,8 @@ def decode_attention(
     lens: jnp.ndarray,
     ks: jnp.ndarray | None = None,
     vs: jnp.ndarray | None = None,
+    layer: jnp.ndarray | int | None = None,
+    rows: jnp.ndarray | None = None,
     block_k: int = 128,
     interpret: bool | None = None,
 ) -> jnp.ndarray:
@@ -126,14 +151,18 @@ def decode_attention(
 
     Args:
       q:    (B, H, D) query for the one new token per sequence.
-      k, v: (B, T, KV·D) stacked slot cache, KV head ``h`` in lanes
-            ``[h·D, (h+1)·D)`` (f32/bf16, or int8 with ``ks``/``vs``).
-            ``H % KV == 0``; group size ``G = H // KV``.
+      k, v: (B, T, KV·D) slot cache, or the layer stack (L, R, T, KV·D),
+            KV head ``h`` in lanes ``[h·D, (h+1)·D)`` (f32/bf16, or int8
+            with ``ks``/``vs``). ``H % KV == 0``; group size
+            ``G = H // KV``.
       lens: (B,) int32 — valid keys per row *including* the current token
             (i.e. ``cache_len + 1`` after the decode-step cache write).
             Keys at positions >= lens[b] are never read; lens[b] == 0
             yields a zero output row.
-      ks, vs: (B, T, KV, 1) f32 per-key dequant scales (int8 cache only).
+      ks, vs: (B, T, KV, 1) or (L, R, T, KV, 1) f32 per-key dequant
+            scales (int8 cache only).
+      layer: the layer of a stacked cache (a traced scalar).
+      rows:  (B,) int32 cache row of each query; default ``0..B-1``.
       block_k: KV block size; shrunk to a divisor of T (never pads the
             cache).
       interpret: force Pallas interpret mode; default auto (True off-TPU).
@@ -144,7 +173,9 @@ def decode_attention(
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     b, h, d = q.shape
-    _, t, width = k.shape
+    (k, v, ks, vs), layer, rows = stack_operands(b, (k, v, ks, vs), layer,
+                                                 rows)
+    t, width = k.shape[2:]
     if width % d:
         raise ValueError(f"cache width {width} not a multiple of D={d}")
     kv_heads = width // d
@@ -157,33 +188,33 @@ def decode_attention(
     n_kb = t // bk
     lens = lens.astype(jnp.int32)
 
-    def kv_map(bi, kb, lens_pref):
+    def kv_map(bi, kb, lens_pref, layer_pref, rows_pref):
         # clamp dead-tail blocks onto the last live block: the repeated
         # block index elides the DMA, making traffic O(lens) not O(T)
         last = jnp.maximum((lens_pref[bi] - 1) // bk, 0)
-        return (bi, jnp.minimum(kb, last), 0)
+        return (layer_pref[0], rows_pref[bi], jnp.minimum(kb, last), 0)
 
-    def scale_map(bi, kb, lens_pref):
-        return kv_map(bi, kb, lens_pref) + (0,)
+    def scale_map(*idx):
+        return kv_map(*idx) + (0,)
 
-    def row_map(bi, kb, lens_pref):
+    def row_map(bi, kb, *_):
         return (bi, 0, 0)
 
     in_specs = [
-        pl.BlockSpec((1, h, d), row_map),        # q
-        pl.BlockSpec((1, bk, width), kv_map),    # k
-        pl.BlockSpec((1, bk, width), kv_map),    # v
+        pl.BlockSpec((1, h, d), row_map),              # q
+        pl.BlockSpec((None, 1, bk, width), kv_map),    # k
+        pl.BlockSpec((None, 1, bk, width), kv_map),    # v
     ]
     operands = [q, k, v]
     if int8:
         in_specs += [
-            pl.BlockSpec((1, bk, kv_heads, 1), scale_map),  # ks
-            pl.BlockSpec((1, bk, kv_heads, 1), scale_map),  # vs
+            pl.BlockSpec((None, 1, bk, kv_heads, 1), scale_map),  # ks
+            pl.BlockSpec((None, 1, bk, kv_heads, 1), scale_map),  # vs
         ]
         operands += [ks, vs]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=3,
         grid=(b, n_kb),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, h, d), row_map),
@@ -203,4 +234,4 @@ def decode_attention(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(lens, *operands)
+    )(lens, layer, rows, *operands)

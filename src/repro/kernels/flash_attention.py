@@ -43,8 +43,11 @@ Two kernels live here:
     K/V block, exactly as ``decode_attention`` does for S=1) and
     an int8 cache is dequantised on the VMEM-resident block — the G-fold
     ``jnp.repeat`` + up-front dequant copies the old prefill wrapper paid
-    per chunk are gone. ``flash_gqa_modeled_cost`` records the eliminated
-    KV-stream bytes.
+    per chunk are gone. The cache may be the whole layer stack
+    ``(L, R, T, KV·D)``, read at a scalar-prefetched layer and per-row
+    cache rows (the slot a chunk fills) inside the index maps, so no
+    slice of the stack is materialised. ``flash_gqa_modeled_cost``
+    records the eliminated KV-stream bytes.
 """
 
 from __future__ import annotations
@@ -56,7 +59,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.decode_attention import _pick_block_k
+from repro.kernels.decode_attention import _pick_block_k, stack_operands
 
 NEG_INF = -1e30
 
@@ -234,9 +237,9 @@ def _gqa_blocks(s: int, t: int, block_q: int, block_k: int):
     return bq, _pick_block_k(t, block_k)
 
 
-def _gqa_kernel(start_ref, *refs, scale: float, int8: bool, count: bool,
-                block_q: int, block_k: int, n_k: int, group: int,
-                kv_heads: int, d: int, s_valid: int):
+def _gqa_kernel(start_ref, layer_ref, rows_ref, *refs, scale: float,
+                int8: bool, count: bool, block_q: int, block_k: int,
+                n_k: int, group: int, kv_heads: int, d: int, s_valid: int):
     if int8:
         q_ref, k_ref, v_ref, ks_ref, vs_ref = refs[:5]
         rest = refs[5:]
@@ -318,6 +321,8 @@ def flash_gqa_attention(
     start: jnp.ndarray | None = None,
     ks: jnp.ndarray | None = None,
     vs: jnp.ndarray | None = None,
+    layer: jnp.ndarray | int | None = None,
+    rows: jnp.ndarray | None = None,
     block_q: int = 128,
     block_k: int = 128,
     interpret: bool | None = None,
@@ -327,8 +332,9 @@ def flash_gqa_attention(
 
     Args:
       q:    (B, S, H, D) queries for the S freshly written tokens per row.
-      k, v: (B, T, KV·D) stacked slot cache, KV head ``h`` in lanes
-            ``[h·D, (h+1)·D)`` (f32/bf16, or int8 with ``ks``/``vs``).
+      k, v: (B, T, KV·D) slot cache, or the layer stack (L, R, T, KV·D),
+            KV head ``h`` in lanes ``[h·D, (h+1)·D)`` (f32/bf16, or int8
+            with ``ks``/``vs``).
             ``H % KV == 0``; group size ``G = H // KV``. Streamed in cache
             layout — never head-replicated, never padded
             (``block_k`` is shrunk to a divisor of T; padding would copy
@@ -336,8 +342,11 @@ def flash_gqa_attention(
       start: (B,) int32 per-row absolute offsets (``_cached_mask``
             semantics): query i of row b sits at position start[b]+i,
             attends keys j <= start[b]+i and j < start[b]+S. None = zeros.
-      ks, vs: (B, T, KV, 1) f32 per-key dequant scales (int8 cache only) —
-            dequantisation happens on the VMEM-resident block in-kernel.
+      ks, vs: (B, T, KV, 1) or (L, R, T, KV, 1) f32 per-key dequant
+            scales (int8 cache only) — dequantisation happens on the
+            VMEM-resident block in-kernel.
+      layer: the layer of a stacked cache (a traced scalar).
+      rows: (B,) int32 cache row of each query row; default ``0..B-1``.
       block_q, block_k: tile sizes; block_q pads the (small) q operand,
             block_k shrinks to a divisor of T.
       interpret: force Pallas interpret mode; default auto (True off-TPU).
@@ -350,7 +359,9 @@ def flash_gqa_attention(
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     b, s, h, d = q.shape
-    _, t, width = k.shape
+    (k, v, ks, vs), layer, rows = stack_operands(b, (k, v, ks, vs), layer,
+                                                 rows)
+    t, width = k.shape[2:]
     if width % d:
         raise ValueError(f"cache width {width} not a multiple of D={d}")
     kv_heads = width // d
@@ -374,28 +385,28 @@ def flash_gqa_attention(
     start_arr = (jnp.zeros((b,), jnp.int32) if start is None
                  else start.astype(jnp.int32))
 
-    def q_map(bi, qi, kb, st):
+    def q_map(bi, qi, kb, *_):
         return (bi, 0, qi, 0)
 
-    def kv_map(bi, qi, kb, st):
+    def kv_map(bi, qi, kb, st, layer_pref, rows_pref):
         # clamp pruned blocks onto the causal-frontier block: the repeated
         # block index elides the DMA (same trick as the MHA kernel)
         last = (st[bi] + jnp.minimum((qi + 1) * bq, s) - 1) // bk
-        return (bi, jnp.minimum(kb, last), 0)
+        return (layer_pref[0], rows_pref[bi], jnp.minimum(kb, last), 0)
 
-    def scale_map(bi, qi, kb, st):
-        return kv_map(bi, qi, kb, st) + (0,)
+    def scale_map(*idx):
+        return kv_map(*idx) + (0,)
 
     in_specs = [
         pl.BlockSpec((1, kv_heads, bq * group, d), q_map),
-        pl.BlockSpec((1, bk, width), kv_map),
-        pl.BlockSpec((1, bk, width), kv_map),
+        pl.BlockSpec((None, 1, bk, width), kv_map),
+        pl.BlockSpec((None, 1, bk, width), kv_map),
     ]
     operands = [qp, k, v]
     if int8:
         in_specs += [
-            pl.BlockSpec((1, bk, kv_heads, 1), scale_map),
-            pl.BlockSpec((1, bk, kv_heads, 1), scale_map),
+            pl.BlockSpec((None, 1, bk, kv_heads, 1), scale_map),
+            pl.BlockSpec((None, 1, bk, kv_heads, 1), scale_map),
         ]
         operands += [ks, vs]
 
@@ -404,10 +415,10 @@ def flash_gqa_attention(
     if return_block_counts:
         out_shapes.append(jax.ShapeDtypeStruct((b, kv_heads, n_q), jnp.int32))
         out_specs.append(pl.BlockSpec((1, kv_heads, 1),
-                                      lambda bi, qi, kb, st: (bi, 0, qi)))
+                                      lambda bi, qi, kb, *_: (bi, 0, qi)))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=3,
         grid=(b, n_q, n_k),
         in_specs=in_specs,
         out_specs=out_specs,
@@ -429,7 +440,7 @@ def flash_gqa_attention(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(start_arr, *operands)
+    )(start_arr, layer, rows, *operands)
     out = outs[0].reshape(b, kv_heads, sq, group, d).transpose(0, 2, 1, 3, 4)
     out = out.reshape(b, sq, h, d)[:, :s]
     if return_block_counts:

@@ -15,7 +15,7 @@ step cost. This kernel keeps the whole layer's activations VMEM-resident:
   * prologue (block 0): rmsnorm1, the three QKV projections, rope at
     position ``lens[b]-1``, and the cache-write image of the current
     token's K/V (the int8 path replicates ``attention._kv_quant`` exactly
-    and emits the int8 rows + scales for the caller's ``row_update``).
+    and emits the int8 rows + scales for the caller's cache write).
   * sweep: the length-aware online-softmax attention of
     ``kernels/decode_attention.py`` against the *stale* cache blocks, with
     the current token's K/V substituted in-register at ``lens[b]-1`` —
@@ -280,11 +280,11 @@ def fused_dense_layer(ctx, p, x, cache):
     x: (B, 1, d); cache: the layer's slot cache ({k, v[, ks, vs], len},
     K/V lane-dense ``(B, T, KV·hd)``).
     Returns (x_out (B, 1, d), new_cache) with the same cache-write semantics
-    as the unfused ``transformer._dense_block`` (``row_update`` at the old
+    as the unfused ``transformer._dense_block`` (``write_rows`` at the old
     length, ``len + 1``). Eligibility is the caller's job
     (``transformer._use_fused_layer``).
     """
-    from repro.models.attention import row_update
+    from repro.models.attention import write_rows
 
     cfg = ctx.cfg
     b, s, d = x.shape
@@ -393,18 +393,12 @@ def fused_dense_layer(ctx, p, x, cache):
 
     if int8:
         x_new, kq, vq, kscale, vscale = outs
-        new_cache = {
-            "k": row_update(cache["k"], kq.reshape(b, 1, kv * hd), start),
-            "v": row_update(cache["v"], vq.reshape(b, 1, kv * hd), start),
-            "ks": row_update(cache["ks"], kscale[:, None], start),
-            "vs": row_update(cache["vs"], vscale[:, None], start),
-            "len": start + 1,
-        }
+        rows = {"k": kq.reshape(b, 1, kv * hd), "v": vq.reshape(b, 1, kv * hd),
+                "ks": kscale[:, None], "vs": vscale[:, None]}
     else:
         x_new, k_cur, v_cur = outs
-        new_cache = {
-            "k": row_update(cache["k"], k_cur.reshape(b, 1, kv * hd), start),
-            "v": row_update(cache["v"], v_cur.reshape(b, 1, kv * hd), start),
-            "len": start + 1,
-        }
+        rows = {"k": k_cur.reshape(b, 1, kv * hd),
+                "v": v_cur.reshape(b, 1, kv * hd)}
+    new_cache = {n: write_rows(cache, n, u, start) for n, u in rows.items()}
+    new_cache["len"] = start + 1
     return x_new[:, None], new_cache

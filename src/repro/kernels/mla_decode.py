@@ -22,9 +22,13 @@ step; this kernel is the latent-cache analogue of
     across all heads.
 
 ``lens[b]`` counts valid cached positions *including* the current token;
-``lens[b] == 0`` rows return exactly zero. Validated against
-``ref.mla_decode_attention_ref`` and the einsum branch in interpret mode
-(tests/test_megakernel.py); CPU callers get ``interpret=True`` automatically.
+``lens[b] == 0`` rows return exactly zero. The caches may be the whole
+layer stack ``(L, R, T, W)``, read at a scalar-prefetched ``layer`` and
+per-query ``rows`` as ``kernels/decode_attention.py`` reads its stack;
+the rope keys are cached ``(R, T)`` per row and read as ``(R, bk)`` blocks.
+Validated against ``ref.mla_decode_attention_ref`` and the einsum branch
+in interpret mode (tests/test_megakernel.py); CPU callers get
+``interpret=True`` automatically.
 """
 
 from __future__ import annotations
@@ -36,12 +40,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.decode_attention import NEG_INF, _pick_block_k
+from repro.kernels.decode_attention import (NEG_INF, _pick_block_k,
+                                            stack_operands)
 
 
-def _kernel(lens_ref, ql_ref, qr_ref, ckv_ref, kr_ref, o_ref,
-            m_ref, l_ref, acc_ref, *, scale: float, block_k: int,
-            n_kb: int):
+def _kernel(lens_ref, layer_ref, rows_ref, ql_ref, qr_ref, ckv_ref,
+            kr_ref, o_ref, m_ref, l_ref, acc_ref, *, scale: float,
+            block_k: int, n_kb: int):
     b = pl.program_id(0)
     kb = pl.program_id(1)
 
@@ -60,9 +65,9 @@ def _kernel(lens_ref, ql_ref, qr_ref, ckv_ref, kr_ref, o_ref,
         ql = ql_ref[0].astype(jnp.float32)                    # (H, L)
         qr = qr_ref[0].astype(jnp.float32)                    # (H, R)
         ckv = ckv_ref[0].astype(jnp.float32)                  # (bk, L)
-        kr = kr_ref[0].astype(jnp.float32)                    # (bk, R)
+        kr = kr_ref[0].astype(jnp.float32)                    # (R, bk)
         s = (jnp.dot(ql, ckv.T, preferred_element_type=jnp.float32)
-             + jnp.dot(qr, kr.T, preferred_element_type=jnp.float32)) * scale
+             + jnp.dot(qr, kr, preferred_element_type=jnp.float32)) * scale
         s = jnp.where(valid[None, :], s, NEG_INF)             # (H, bk)
         m_prev = m_ref[0]                                     # (H,)
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
@@ -87,6 +92,8 @@ def mla_decode_attention(
     krope: jnp.ndarray,
     lens: jnp.ndarray,
     scale: float,
+    layer: jnp.ndarray | int | None = None,
+    rows: jnp.ndarray | None = None,
     block_k: int = 128,
     interpret: bool | None = None,
 ) -> jnp.ndarray:
@@ -95,13 +102,17 @@ def mla_decode_attention(
     Args:
       q_lat:  (B, H, L) query with W_uk absorbed (L = kv_lora rank).
       q_rope: (B, H, R) rope-channel query (R = rope head dim).
-      ckv:    (B, T, L) compressed KV latent cache (scores *and* values).
-      krope:  (B, T, R) shared rope-channel key cache.
+      ckv:    (B, T, L) compressed KV latent cache (scores *and* values),
+              or its layer stack (n_layers, R_c, T, L).
+      krope:  (B, R, T) shared rope-channel key cache, positions minor
+              (``attention.init_mla_cache``), or its stack.
       lens:   (B,) int32 valid cached positions including the current token;
               ``lens[b] == 0`` yields a zero output row.
       scale:  static softmax scale (``attention.mla_softmax_scale``: the
               caller knows the pre-absorption head dims and the rope
               scaling; the kernel cannot recover them from L).
+      layer:  the layer of a stacked cache (a traced scalar).
+      rows:   (B,) int32 cache row of each query; default ``0..B-1``.
       block_k: latent-cache block size; shrunk to a divisor of T.
       interpret: force Pallas interpret mode; default auto (True off-TPU).
 
@@ -111,27 +122,33 @@ def mla_decode_attention(
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     b, h, lat = q_lat.shape
-    _, t, _ = ckv.shape
+    (ckv, krope), layer, rows = stack_operands(b, (ckv, krope), layer, rows)
+    t = ckv.shape[2]
     rope_hd = q_rope.shape[-1]
     bk = _pick_block_k(t, block_k)
     n_kb = t // bk
     lens = lens.astype(jnp.int32)
 
-    def kv_map(bi, kb, lens_pref):
+    def kv_map(bi, kb, lens_pref, layer_pref, rows_pref):
         last = jnp.maximum((lens_pref[bi] - 1) // bk, 0)
-        return (bi, jnp.minimum(kb, last), 0)
+        return (layer_pref[0], rows_pref[bi], jnp.minimum(kb, last), 0)
 
-    def row_map(bi, kb, lens_pref):
+    def kr_map(*idx):
+        # the rope keys are cached (R, T) per row, positions minor
+        lay, row, kb, _ = kv_map(*idx)
+        return (lay, row, 0, kb)
+
+    def row_map(bi, kb, *_):
         return (bi, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=3,
         grid=(b, n_kb),
         in_specs=[
-            pl.BlockSpec((1, h, lat), row_map),      # q_lat
-            pl.BlockSpec((1, h, rope_hd), row_map),  # q_rope
-            pl.BlockSpec((1, bk, lat), kv_map),      # ckv
-            pl.BlockSpec((1, bk, rope_hd), kv_map),  # krope
+            pl.BlockSpec((1, h, lat), row_map),              # q_lat
+            pl.BlockSpec((1, h, rope_hd), row_map),          # q_rope
+            pl.BlockSpec((None, 1, bk, lat), kv_map),        # ckv
+            pl.BlockSpec((None, 1, rope_hd, bk), kr_map),    # krope
         ],
         out_specs=pl.BlockSpec((1, h, lat), row_map),
         scratch_shapes=[
@@ -149,4 +166,4 @@ def mla_decode_attention(
         ),
         interpret=interpret,
         name="mla_decode",
-    )(lens, q_lat, q_rope, ckv, krope)
+    )(lens, layer, rows, q_lat, q_rope, ckv, krope)

@@ -20,6 +20,9 @@ up-projected to per-head keys and values:
     up to each row's live length, not to its allocated end.
   * online softmax in VMEM scratch across the key sweep; bf16 operands,
     float32 accumulation.
+  * the caches may be the whole layer stack ``(L, R, T, W)``: the
+    scalar-prefetched ``layer`` and per-row ``rows`` (the slot a chunk
+    fills) address it in the index maps, as in ``mla_decode``.
 
 Validated against the einsum branch in interpret mode
 (tests/test_mla_moe_lite.py); CPU callers get ``interpret=True``.
@@ -34,14 +37,16 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.decode_attention import NEG_INF, _pick_block_k
+from repro.kernels.decode_attention import (NEG_INF, _pick_block_k,
+                                            stack_operands)
 
 ROWS = 512          # query rows (positions x heads) per block
 
 
-def _kernel(start_ref, ql_ref, qr_ref, ckv_ref, kr_ref, o_ref,
-            m_ref, l_ref, acc_ref, *, scale: float, block_q: int,
-            block_k: int, heads: int, n_k: int, s_valid: int):
+def _kernel(start_ref, layer_ref, rows_ref, ql_ref, qr_ref, ckv_ref,
+            kr_ref, o_ref, m_ref, l_ref, acc_ref, *, scale: float,
+            block_q: int, block_k: int, heads: int, n_k: int,
+            s_valid: int):
     b = pl.program_id(0)
     qb = pl.program_id(1)
     kb = pl.program_id(2)
@@ -66,7 +71,7 @@ def _kernel(start_ref, ql_ref, qr_ref, ckv_ref, kr_ref, o_ref,
         mask = (kj <= qi + start_b) & (kj < start_b + s_valid)
         ckv = ckv_ref[0]                                   # (bk, L)
         s = (jnp.dot(ql_ref[0], ckv.T, preferred_element_type=jnp.float32)
-             + jnp.dot(qr_ref[0], kr_ref[0].T,
+             + jnp.dot(qr_ref[0], kr_ref[0],
                        preferred_element_type=jnp.float32)) * scale
         s = jnp.where(mask, s, NEG_INF)                    # (rows, bk)
         m_prev = m_ref[0]
@@ -93,6 +98,8 @@ def mla_prefill_attention(
     krope: jnp.ndarray,
     start: jnp.ndarray,
     scale: float,
+    layer: jnp.ndarray | int | None = None,
+    rows: jnp.ndarray | None = None,
     block_k: int = 256,
     interpret: bool | None = None,
 ) -> jnp.ndarray:
@@ -101,10 +108,14 @@ def mla_prefill_attention(
     Args:
       q_lat:  (B, S, H, L) queries with W_uk absorbed (L = kv_lora rank).
       q_rope: (B, S, H, R) rope-channel queries.
-      ckv:    (B, T, L) latent cache, the chunk's own rows already written.
-      krope:  (B, T, R) shared rope-channel key cache.
+      ckv:    (B, T, L) latent cache, the chunk's own rows already written,
+              or its layer stack (n_layers, R_c, T, L).
+      krope:  (B, R, T) shared rope-channel key cache, positions minor
+              (``attention.init_mla_cache``), or its stack.
       start:  (B,) int32 position of each row's first query.
       scale:  static softmax scale (``attention.mla_softmax_scale``).
+      layer:  the layer of a stacked cache (a traced scalar).
+      rows:   (B,) int32 cache row of each query row; default ``0..B-1``.
       block_k: key block; shrunk to a divisor of T.
       interpret: force Pallas interpret mode; default auto (True off-TPU).
 
@@ -114,7 +125,8 @@ def mla_prefill_attention(
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     b, s, h, lat = q_lat.shape
-    t = ckv.shape[1]
+    (ckv, krope), layer, rows = stack_operands(b, (ckv, krope), layer, rows)
+    t = ckv.shape[2]
     rope_hd = q_rope.shape[-1]
     bq = max(1, min(ROWS // h, s))
     bq = 1 << (bq.bit_length() - 1)            # a power of two <= S
@@ -127,28 +139,33 @@ def mla_prefill_attention(
     qr = jnp.pad(q_rope, pad).reshape(b, sq * h, rope_hd)
     start = start.astype(jnp.int32)
 
-    def q_map(bi, qi, kb, st):
+    def q_map(bi, qi, kb, *_):
         return (bi, qi, 0)
 
-    def kv_map(bi, qi, kb, st):
+    def kv_map(bi, qi, kb, st, layer_pref, rows_pref):
         last = (st[bi] + jnp.minimum((qi + 1) * bq, s) - 1) // bk
-        return (bi, jnp.minimum(kb, last), 0)
+        return (layer_pref[0], rows_pref[bi], jnp.minimum(kb, last), 0)
 
-    rows = bq * h
+    def kr_map(*idx):
+        # the rope keys are cached (R, T) per row, positions minor
+        lay, row, kb, _ = kv_map(*idx)
+        return (lay, row, 0, kb)
+
+    qrows = bq * h
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=3,
         grid=(b, n_q, n_k),
         in_specs=[
-            pl.BlockSpec((1, rows, lat), q_map),
-            pl.BlockSpec((1, rows, rope_hd), q_map),
-            pl.BlockSpec((1, bk, lat), kv_map),
-            pl.BlockSpec((1, bk, rope_hd), kv_map),
+            pl.BlockSpec((1, qrows, lat), q_map),
+            pl.BlockSpec((1, qrows, rope_hd), q_map),
+            pl.BlockSpec((None, 1, bk, lat), kv_map),
+            pl.BlockSpec((None, 1, rope_hd, bk), kr_map),
         ],
-        out_specs=pl.BlockSpec((1, rows, lat), q_map),
+        out_specs=pl.BlockSpec((1, qrows, lat), q_map),
         scratch_shapes=[
-            pltpu.VMEM((1, rows), jnp.float32),     # running max
-            pltpu.VMEM((1, rows), jnp.float32),     # denominator
-            pltpu.VMEM((rows, lat), jnp.float32),   # latent accumulator
+            pltpu.VMEM((1, qrows), jnp.float32),    # running max
+            pltpu.VMEM((1, qrows), jnp.float32),    # denominator
+            pltpu.VMEM((qrows, lat), jnp.float32),  # latent accumulator
         ],
     )
     out = pl.pallas_call(
@@ -161,5 +178,5 @@ def mla_prefill_attention(
         ),
         interpret=interpret,
         name="mla_prefill",
-    )(start, ql, qr, ckv, krope)
+    )(start, layer, rows, ql, qr, ckv, krope)
     return out.reshape(b, sq, h, lat)[:, :s]

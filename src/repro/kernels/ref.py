@@ -483,7 +483,8 @@ def mla_decode_attention_ref(
     folds W_uk into the query and applies W_uv to the returned latent
     context): logits are the sum of the latent and rope channels, masked to
     the first ``lens[b]`` cached positions, and the output is the
-    probability-weighted latent cache — shape (B, H, kv_lora).
+    probability-weighted latent cache — shape (B, H, kv_lora). ``ckv`` is
+    (B, T, kv_lora) and ``krope`` (B, R, T), as cached.
 
     ``lens[b] == 0`` rows return exact zeros (mirrors
     ``decode_attention_ref``).
@@ -491,13 +492,44 @@ def mla_decode_attention_ref(
     b, t, _ = ckv.shape
     logits = (
         jnp.einsum("bhl,btl->bht", q_lat, ckv)
-        + jnp.einsum("bhd,btd->bht", q_rope, krope)
+        + jnp.einsum("bhd,bdt->bht", q_rope, krope)
     ).astype(jnp.float32) * scale
     valid = jnp.arange(t)[None, :] < lens[:, None]
     logits = jnp.where(valid[:, None, :], logits, -1e30)
     probs = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("bht,btl->bhl", probs, ckv.astype(jnp.float32))
     return jnp.where(lens[:, None, None] > 0, out, 0.0)
+
+
+def mla_prefill_attention_ref(
+    q_lat: jnp.ndarray,
+    q_rope: jnp.ndarray,
+    ckv: jnp.ndarray,
+    krope: jnp.ndarray,
+    start: jnp.ndarray,
+    scale: float,
+) -> jnp.ndarray:
+    """Dense oracle for ``kernels.mla_prefill.mla_prefill_attention``.
+
+    A chunk of S absorbed queries per row, ``q_lat`` (B, S, H, kv_lora)
+    and ``q_rope`` (B, S, H, R), against the latent cache: query i of row
+    b sits at position start[b]+i and sees key j iff j <= start[b]+i and
+    j < start[b]+S (``_cached_mask`` semantics); ``krope`` is (B, R, T), as
+    cached. Returns the probability-weighted latent rows, (B, S, H,
+    kv_lora), in float32.
+    """
+    s, t = q_lat.shape[1], ckv.shape[1]
+    f32 = jnp.float32
+    logits = (
+        jnp.einsum("bshl,btl->bhst", q_lat.astype(f32), ckv.astype(f32))
+        + jnp.einsum("bshr,brt->bhst", q_rope.astype(f32),
+                     krope.astype(f32))) * scale
+    qi = start[:, None, None] + jnp.arange(s)[None, :, None]    # (B, S, 1)
+    kj = jnp.arange(t)[None, None, :]
+    mask = (kj <= qi) & (kj < start[:, None, None] + s)
+    logits = jnp.where(mask[:, None], logits, -1e30)
+    probs = jax.nn.softmax(logits, axis=-1)
+    return jnp.einsum("bhst,btl->bshl", probs, ckv.astype(f32))
 
 
 def ssm_decode_step_ref(

@@ -111,7 +111,7 @@ def cache_axes(cfg) -> Any:
     if fam == "moe":
         if cfg.mla is not None:
             return {"ckv": ("layers", "batch", "seq", None),
-                    "krope": ("layers", "batch", "seq", None),
+                    "krope": ("layers", "batch", None, "seq"),
                     "len": ("layers", "batch")}
         return _gqa_cache_axes(cfg)
     if fam == "ssm":

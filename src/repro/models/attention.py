@@ -9,14 +9,24 @@ KV caches are plain pytrees so they shard/checkpoint like params. GQA cache:
 {"k": (B, S, KV·D), "v": ..., "len": (B,)} — lane-dense, KV head ``h`` in
 lanes ``[h·D, (h+1)·D)`` of each position's row (DESIGN.md §10), the one
 layout every writer and both attention kernels share; MLA caches the
-*compressed* c_kv (B, S, kv_lora) + shared k_rope (B, S, rope_hd) — the
-arch's serving-memory win — and up-projects per step.
+*compressed* c_kv (B, S, kv_lora) + shared k_rope (B, rope_hd, S),
+positions minor — the arch's serving-memory win — and up-projects per
+step.
 
 ``len`` is *per sequence*: every cached row advances independently, which is
 what lets the serving engine fuse ragged continuous-batching slots into one
-batch-axis decode program (DESIGN.md §10). Writes are per-row
-``dynamic_update_slice`` (vmapped over batch) and the attention mask combines
+batch-axis decode program (DESIGN.md §10). The attention mask combines
 per-row causality with per-row key validity.
+
+A layer's cache arrives in one of two forms. *Addressed*: the whole layer
+stack ``(L, R, T, W)`` with the layer ``layer`` and the cache row of each
+batch row ``rows`` (None: rows ``0..B-1``), as the stacked-block scan and
+the serving engine hand it over; the new rows are written into the stack
+in place (one ``dynamic_update_slice``, scatter or, for the positions-minor
+rope keys of a decode step, ``write_columns`` kernel) and the kernels read
+their layer and rows through their index maps, so no slice of the stack
+is made. *Plain*: one layer's ``(B, T, W)`` leaves (the hybrid and encdec
+forwards), written as their one-layer stack.
 
 GQA cached attention runs one of two implementations, selected by
 ``cfg.attn_impl`` (DESIGN.md §11):
@@ -42,6 +52,7 @@ from repro.configs.base import ModelConfig
 from repro.models.layers import (Ctx, Params, _init_dense, apply_rope, dense,
                                  rmsnorm, yarn_mscale)
 from repro.distributed.sharding import shard
+from repro.kernels.cache_write import write_columns
 from repro.kernels.decode_attention import decode_attention
 from repro.kernels.flash_attention import flash_gqa_attention
 from repro.kernels.mla_decode import mla_decode_attention
@@ -143,13 +154,61 @@ def _causal_mask(s: int, t: int, offset: int = 0) -> jnp.ndarray:
     return (kj <= qi)[None, None]
 
 
-def row_update(cache_arr: jnp.ndarray, update: jnp.ndarray,
-               starts: jnp.ndarray) -> jnp.ndarray:
-    """Per-row cache write: row b of ``update`` lands at ``starts[b]`` along
-    the sequence axis (axis 1). starts: (B,) int32."""
-    return jax.vmap(
-        lambda c, u, st: jax.lax.dynamic_update_slice_in_dim(c, u, st, axis=0)
-    )(cache_arr, update.astype(cache_arr.dtype), starts)
+def stack_update(stack: jnp.ndarray, update: jnp.ndarray, layer, rows,
+                 starts: jnp.ndarray, seq_axis: int = 2) -> jnp.ndarray:
+    """Write ``update`` (B, ...) into the layer stack (L, R, ...): row b,
+    laid out as one cache row with its S new positions on axis
+    ``seq_axis - 1``, lands in layer ``layer``, cache row ``rows[b]``
+    (None: ``b``), from position ``starts[b]`` of the stack's axis
+    ``seq_axis``. One row is one ``dynamic_update_slice``, several are
+    one scatter; both write in place into a donated or loop-carried
+    stack. One position of several rows of a positions-minor stack (a
+    decode step's rope keys) is one ``write_columns`` kernel instead: XLA
+    would relayout a stack small enough to stage on chip for that
+    scatter. A plain (B, T, ...) cache is written as its one-layer stack
+    ``cache[None]`` at layer 0."""
+    b, s = update.shape[0], update.shape[seq_axis - 1]
+    update = update.astype(stack.dtype)
+    if b > 1 and s == 1 and seq_axis == stack.ndim - 1 == 3:
+        return write_columns(stack, update[..., 0], layer, rows, starts)
+    if b == 1:
+        at = [0] * stack.ndim
+        at[0], at[seq_axis] = layer, starts[0]
+        at[1] = 0 if rows is None else rows[0]
+        return jax.lax.dynamic_update_slice(stack, update[None], at)
+    rows = jnp.arange(b) if rows is None else rows
+    pos = starts[:, None] + jnp.arange(s)[None]
+    # the indexed axes come first in the indexed result, (B, S, ...)
+    idx = (layer, rows[:, None]) + (slice(None),) * (seq_axis - 2) + (pos,)
+    return stack.at[idx].set(jnp.moveaxis(update, seq_axis - 1, 1),
+                             mode="drop", unique_indices=True)
+
+
+def write_rows(cache: Dict[str, Any], leaf: str, update: jnp.ndarray,
+               starts: jnp.ndarray, seq_axis: int = 2) -> jnp.ndarray:
+    """Cache leaf ``leaf`` with ``update`` written at each row's
+    ``starts``: in place into an addressed stack, else into the plain
+    leaf, as its one-layer stack."""
+    if "layer" in cache:
+        return stack_update(cache[leaf], update, cache["layer"],
+                            cache["rows"], starts, seq_axis)
+    return stack_update(cache[leaf][None], update, 0, None, starts,
+                        seq_axis)[0]
+
+
+def _view(cache: Dict[str, Any], leaf: str) -> jnp.ndarray:
+    """The batch's rows of leaf ``leaf`` for the einsum path: the plain
+    leaf, or a read of the addressed stack (never written back)."""
+    x = cache[leaf]
+    if "layer" not in cache:
+        return x
+    x = jax.lax.dynamic_index_in_dim(x, cache["layer"], keepdims=False)
+    return x if cache["rows"] is None else x[cache["rows"]]
+
+
+def _at(cache: Dict[str, Any]) -> Dict[str, Any]:
+    """The kernels' ``layer``/``rows`` arguments for this cache."""
+    return {"layer": cache.get("layer"), "rows": cache.get("rows")}
 
 
 def _pow2_block(n: int, cap: int = 128, lo: int = 8) -> int:
@@ -157,11 +216,13 @@ def _pow2_block(n: int, cap: int = 128, lo: int = 8) -> int:
     return max(lo, min(cap, 1 << (max(n, 1) - 1).bit_length()))
 
 
-def _flash_prefill(q, k_c, v_c, start, ks=None, vs=None) -> jnp.ndarray:
+def _flash_prefill(q, k_c, v_c, start, ks=None, vs=None, layer=None,
+                   rows=None) -> jnp.ndarray:
     """Chunked/bucketed prefill through the GQA-native flash kernel
     (attn_impl="kernel", DESIGN.md §13).
 
-    q: (B,S,H,D); k_c, v_c: (B,T,KV·D) slot cache streamed *as stored* —
+    q: (B,S,H,D); k_c, v_c: (B,T,KV·D) slot cache, or the layer stack
+    addressed by ``layer``/``rows``, streamed *as stored* —
     head grouping happens in-kernel (the G-fold ``jnp.repeat`` copy the
     old MHA-shaped wrapper paid per prefill is gone) and an int8 cache
     (``ks``/``vs`` scales) dequantises on the VMEM-resident block, so the
@@ -170,9 +231,10 @@ def _flash_prefill(q, k_c, v_c, start, ks=None, vs=None) -> jnp.ndarray:
     prompt.
     """
     s = q.shape[1]
-    t = k_c.shape[1]
+    t = k_c.shape[-2]
     return flash_gqa_attention(q, k_c, v_c, start=start.astype(jnp.int32),
-                               ks=ks, vs=vs, block_q=_pow2_block(s),
+                               ks=ks, vs=vs, layer=layer, rows=rows,
+                               block_q=_pow2_block(s),
                                block_k=_pow2_block(t))
 
 
@@ -228,45 +290,50 @@ def gqa_attention(
         if int8_cache:
             kq, ks_ = _kv_quant(k)
             vq, vs_ = _kv_quant(v)
-            ck = row_update(cache["k"], kq.reshape(b, s, kv * hd), start)
-            cv = row_update(cache["v"], vq.reshape(b, s, kv * hd), start)
-            cks = row_update(cache["ks"], ks_, start)
-            cvs = row_update(cache["vs"], vs_, start)
-            new_cache = {"k": ck, "v": cv, "ks": cks, "vs": cvs, "len": start + s}
+            rows_new = {"k": kq.reshape(b, s, kv * hd),
+                        "v": vq.reshape(b, s, kv * hd), "ks": ks_, "vs": vs_}
         else:
-            ck = row_update(cache["k"], k.reshape(b, s, kv * hd), start)
-            cv = row_update(cache["v"], v.reshape(b, s, kv * hd), start)
-            new_cache = {"k": ck, "v": cv, "len": start + s}
-        t = ck.shape[1]
-        # the merged minor dim shards in whole KV heads, as (KV, D) did
-        ck_s = shard(ck, "batch", "seq", ("kv_heads", hd))
-        cv_s = shard(cv, "batch", "seq", ("kv_heads", hd))
+            rows_new = {"k": k.reshape(b, s, kv * hd),
+                        "v": v.reshape(b, s, kv * hd)}
+        new_cache = {n: write_rows(cache, n, u, start)
+                     for n, u in rows_new.items()}
+        written = {**cache, **new_cache}
+        new_cache["len"] = start + s
+        if impl == "kernel":
+            # the kernels read the written stack (or plain leaves) as is
+            ck, cv = written["k"], written["v"]
+            cks, cvs = written.get("ks"), written.get("vs")
+        else:
+            ck, cv = _view(written, "k"), _view(written, "v")
+            if int8_cache:
+                cks, cvs = _view(written, "ks"), _view(written, "vs")
+        # the merged minor dim shards in whole KV heads, as (KV, D) did; a
+        # stack's leading layer dim is not sharded
+        lead = (None,) * (ck.ndim - 3)
+        ck = shard(ck, *lead, "batch", "seq", ("kv_heads", hd))
+        cv = shard(cv, *lead, "batch", "seq", ("kv_heads", hd))
+        t = ck.shape[-2]
         if impl == "kernel" and s == 1:
             # length-aware Pallas decode: O(len[b]) KV blocks per row, int8
             # dequantised in-kernel (the cache never round-trips through a
             # full-precision HBM copy). lens counts the freshly written key.
-            if int8_cache:
-                out = decode_attention(q[:, 0], ck_s, cv_s, start + 1,
-                                       ks=cks, vs=cvs)
-            else:
-                out = decode_attention(q[:, 0], ck_s, cv_s, start + 1)
-            out = out[:, None]
+            out = decode_attention(q[:, 0], ck, cv, start + 1, ks=cks,
+                                   vs=cvs, **_at(cache))[:, None]
         elif impl == "kernel":
             # chunked/bucketed prefill via GQA-native flash (causal block
             # pruning + per-row start offsets); int8 stays int8 in HBM and
             # dequantises in-kernel, exactly as the decode kernel does.
-            out = _flash_prefill(q, ck_s, cv_s, start,
-                                 ks=cks if int8_cache else None,
-                                 vs=cvs if int8_cache else None)
+            out = _flash_prefill(q, ck, cv, start, ks=cks, vs=cvs,
+                                 **_at(cache))
         elif int8_cache:
             # einsum fallback: scales fold into logits/probs — no f32
             # dequantised copy of the whole (B, T, KV, D) cache per step
-            out = _sdpa_int8(q, ck_s.reshape(b, t, kv, hd), cks,
-                             cv_s.reshape(b, t, kv, hd), cvs,
+            out = _sdpa_int8(q, ck.reshape(b, t, kv, hd), cks,
+                             cv.reshape(b, t, kv, hd), cvs,
                              _cached_mask(start, s, t))
         else:
-            out = _sdpa(q, ck_s.reshape(b, t, kv, hd),
-                        cv_s.reshape(b, t, kv, hd), _cached_mask(start, s, t))
+            out = _sdpa(q, ck.reshape(b, t, kv, hd),
+                        cv.reshape(b, t, kv, hd), _cached_mask(start, s, t))
 
     out = out.reshape(b, s, h * hd)
     return dense(ctx, p["o"], out, "attn_out"), new_cache
@@ -339,7 +406,9 @@ def init_mla_cache(cfg: ModelConfig, batch: int, max_len: int, dtype) -> Dict[st
     a = cfg.mla
     return {
         "ckv": jnp.zeros((batch, max_len, a.kv_lora), dtype),
-        "krope": jnp.zeros((batch, max_len, a.rope_head_dim), dtype),
+        # the rope keys are kept (R, T) per row, positions minor: as
+        # (T, R) their 64 lanes would pad to 128
+        "krope": jnp.zeros((batch, a.rope_head_dim, max_len), dtype),
         "len": jnp.zeros((batch,), jnp.int32),
     }
 
@@ -389,16 +458,22 @@ def mla_attention(
     ckv, krope = jnp.split(dkv, [a.kv_lora], axis=-1)
     ckv = rmsnorm(p["kv_norm"], ckv, eps)
     krope = apply_rope(krope[:, :, None, :], positions, theta, rs)[:, :, 0, :]
+    krope_t = jnp.swapaxes(krope, 1, 2)          # (B, R, S), as cached
 
     if cache is not None:
         start = cache["len"]                     # (B,) per-sequence lengths
-        ckv_all = row_update(cache["ckv"], ckv, start)
-        krope_all = row_update(cache["krope"], krope, start)
-        new_cache = {"ckv": ckv_all, "krope": krope_all, "len": start + s}
-        t = ckv_all.shape[1]
+        new_cache = {"ckv": write_rows(cache, "ckv", ckv, start),
+                     "krope": write_rows(cache, "krope", krope_t, start,
+                                     seq_axis=3)}
+        written = {**cache, **new_cache}
+        new_cache["len"] = start + s
+        t = cache["ckv"].shape[-2]
+        if cfg.attn_impl != "kernel":
+            ckv_all, krope_all = (_view(written, "ckv"),
+                                  _view(written, "krope"))
     else:
         start = jnp.zeros((b,), jnp.int32)
-        ckv_all, krope_all, new_cache, t = ckv, krope, None, s
+        ckv_all, krope_all, new_cache, t = ckv, krope_t, None, s
 
     scale = mla_softmax_scale(cfg)
     if cache is not None and (s == 1 or cfg.attn_impl == "kernel"):
@@ -412,16 +487,18 @@ def mla_attention(
                 # 512-key blocks: a latent row is one lane-dense 1 KB
                 # read, so few large blocks keep the grid short
                 out_lat = mla_decode_attention(
-                    q_lat[:, 0], q_rope[:, 0], ckv_all, krope_all, start + 1,
-                    scale=scale, block_k=512)[:, None]             # (b,1,h,lora)
+                    q_lat[:, 0], q_rope[:, 0], written["ckv"],
+                    written["krope"], start + 1, scale=scale, block_k=512,
+                    **_at(cache))[:, None]                 # (b,1,h,lora)
         elif cfg.attn_impl == "kernel":
             with jax.named_scope("mla.prefill"):
                 out_lat = mla_prefill_attention(
-                    q_lat, q_rope, ckv_all, krope_all, start, scale=scale)
+                    q_lat, q_rope, written["ckv"], written["krope"], start,
+                    scale=scale, **_at(cache))
         else:
             logits = (
                 jnp.einsum("bshl,btl->bhst", q_lat, ckv_all)
-                + jnp.einsum("bshd,btd->bhst", q_rope, krope_all)
+                + jnp.einsum("bshd,bdt->bhst", q_rope, krope_all)
             ).astype(jnp.float32) * scale
             logits = jnp.where(_cached_mask(start, s, t), logits, NEG_INF)
             probs = jax.nn.softmax(logits, axis=-1).astype(x.dtype)
@@ -435,7 +512,7 @@ def mla_attention(
         v = shard(v, "batch", "seq", "heads", "head_dim")
         logits = (
             jnp.einsum("bshd,bthd->bhst", q_nope, k_nope)
-            + jnp.einsum("bshd,btd->bhst", q_rope, krope_all)
+            + jnp.einsum("bshd,bdt->bhst", q_rope, krope_all)
         ).astype(jnp.float32) * scale
         logits = jnp.where(_cached_mask(start, s, t), logits, NEG_INF)
         probs = jax.nn.softmax(logits, axis=-1).astype(v.dtype)

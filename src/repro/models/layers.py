@@ -81,6 +81,10 @@ class Ctx:
     # computed, held experts with rows); transformer._scan_blocks sums it
     # over layers into moe_rows
     moe_rows: Optional[Tuple[jnp.ndarray, ...]] = None
+    # set at trace time where a cached forward sliced a layer out of the
+    # stacked cache and wrote it back, instead of addressing the stack in
+    # place by (layer, row) (transformer._scan_blocks)
+    cache_sliced: bool = False
 
     @classmethod
     def make(cls, cfg: ModelConfig, key: Optional[jax.Array] = None,

@@ -304,8 +304,7 @@ def init_caches(cfg: ModelConfig, batch: int, max_len: int) -> Any:
         body = stack(lambda: init(cfg, batch, max_len, dt), cfg.n_layers - n_lead)
         if not n_lead:
             return body
-        # one stacked cache per layer group: a step scans each in place,
-        # never splitting or restacking one stack of all the layers
+        # one stacked cache per layer group, each scanned in place
         return {"lead": stack(lambda: init(cfg, batch, max_len, dt), n_lead),
                 "blocks": body}
     if fam == "ssm":
@@ -345,6 +344,28 @@ def _is_len(path) -> bool:
     return bool(path) and getattr(path[-1], "key", None) == "len"
 
 
+# the leaves of an attention cache group that the attention layers address
+# in place by (layer, row) (models/attention.py)
+_ATTN_LEAVES = frozenset(("k", "v", "ks", "vs", "ckv", "krope"))
+
+
+def _attn_group(caches) -> bool:
+    return (isinstance(caches, dict) and "len" in caches
+            and set(caches) - {"len"} <= _ATTN_LEAVES)
+
+
+def cache_in_place(caches) -> bool:
+    """Whether a forward over this stacked cache addresses it in place: it
+    holds attention leaves alone (the dense, vlm and moe families, a
+    ``moe`` model with leading dense layers as one such group per layer
+    group). Recurrent ``conv``/``state`` leaves and the hybrid and encdec
+    caches are sliced per layer and per slot instead (``take_slot``/
+    ``put_slot``)."""
+    if isinstance(caches, dict) and set(caches) == {"lead", "blocks"}:
+        return all(_attn_group(c) for c in caches.values())
+    return _attn_group(caches)
+
+
 def take_slot(caches, slot) -> Any:
     """Batch-1 slice of one slot row from a stacked slot-cache pytree."""
     return jax.tree_util.tree_map_with_path(
@@ -363,13 +384,18 @@ def put_slot(caches, slot_caches, slot) -> Any:
         caches, slot_caches)
 
 
-def set_cache_lens(caches, value) -> Any:
-    """Overwrite every per-sequence 'len' leaf with ``value`` (broadcast)."""
-    return jax.tree_util.tree_map_with_path(
-        lambda path, leaf: jnp.broadcast_to(
-            jnp.asarray(value, leaf.dtype), leaf.shape)
-        if _is_len(path) else leaf,
-        caches)
+def set_cache_lens(caches, value, rows=None) -> Any:
+    """Overwrite every per-sequence 'len' leaf with ``value`` (broadcast),
+    or, given ``rows`` (R,), only those slot rows of a stacked cache."""
+    def put(path, leaf):
+        if not _is_len(path):
+            return leaf
+        if rows is None:
+            return jnp.broadcast_to(jnp.asarray(value, leaf.dtype),
+                                    leaf.shape)
+        return leaf.at[:, rows].set(jnp.asarray(value, leaf.dtype))
+
+    return jax.tree_util.tree_map_with_path(put, caches)
 
 
 def mask_cache_advance(new_caches, old_caches, active) -> Any:
@@ -377,8 +403,9 @@ def mask_cache_advance(new_caches, old_caches, active) -> Any:
 
     active: (B,) bool. Attention K/V leaves keep the new value — inactive
     rows' writes land in junk space (at their frozen ``len``) that the
-    per-row masks never expose and that prefill fully rewrites on slot
-    recycle. SSM ``conv``/``state`` leaves have no such junk space (every
+    per-row masks never expose: the row's next chunk overwrites it, and a
+    recycled slot restarts at length 0, so rows past its length are never
+    read. SSM ``conv``/``state`` leaves have no such junk space (every
     decode step rolls the window and decays the state in place), so they
     are restored alongside ``len`` — otherwise a slot mid-chunked-prefill
     would have its carried state corrupted by the batch-global decode of
@@ -412,40 +439,68 @@ def _embed_input(cfg: ModelConfig, params: Params, batch: Dict[str, Any]):
 
 
 def _scan_blocks(ctx: Ctx, blocks: Params, block_fn, x, positions, caches,
-                 first: int = 0):
+                 first: int = 0, rows=None):
     """Scan ``block_fn`` over stacked ``blocks``, the first of them layer
-    ``first`` of the model (its key and its rows in the guard counts)."""
+    ``first`` of the model (its key and its rows in the guard counts);
+    ``caches`` is the group's own stack, its layer 0 that first block's.
+
+    ``rows`` (B,) are the cache rows of ``x``'s batch rows (None: the
+    cache's rows in order). An attention cache rides in the carry whole:
+    each block gets the stack with its layer and rows and writes only its
+    new rows, in place (``models/attention.py``). Other caches, and a
+    dense block the f32 megakernel serves, get their layer's slice, which
+    is written back after the block (``ctx.cache_sliced`` records it)."""
     cfg = ctx.cfg
     n = jax.tree.leaves(blocks)[0].shape[0]
     base_key = ctx.key if ctx.key is not None else jax.random.PRNGKey(0)
     guard = ctx.guard is not None
     moe_log = ctx.moe_log is not None
     b = x.shape[0]
+    in_place = (_attn_group(caches) and not (
+        block_fn is _dense_block and _use_fused_layer(ctx, x, caches)))
+    if caches is not None and not in_place:
+        ctx.cache_sliced = True
+
+    def take(c, idx):
+        c = jax.lax.dynamic_index_in_dim(c, idx, keepdims=False)
+        return c if rows is None else c[rows]
+
+    def put(c, u, idx):
+        if rows is None:
+            return jax.lax.dynamic_update_index_in_dim(c, u, idx, 0)
+        return c.at[idx, rows].set(u)
 
     def body(carry, xs):
-        # the stacked caches ride in the carry, each layer's slice read and
-        # written back at its index: as scan xs/ys they would be restacked
-        # into a second whole-cache buffer, then copied into the donated one
+        # the stacked caches ride in the carry: as scan xs/ys they would be
+        # restacked into a second whole-cache buffer, then copied into the
+        # donated one
         h, caches = carry
         layer_p, idx = xs
-        layer_cache = None if caches is None else jax.tree.map(
-            lambda c: jax.lax.dynamic_index_in_dim(c, idx, keepdims=False),
-            caches)
+        layer = first + idx
+        if caches is None:
+            layer_cache = None
+        elif in_place:
+            layer_cache = {**caches, "len": take(caches["len"], idx),
+                           "layer": idx, "rows": rows}
+        else:
+            layer_cache = jax.tree.map(lambda c: take(c, idx), caches)
         lctx = dataclasses.replace(
-            ctx, key=jax.random.fold_in(base_key, first + idx), counter=0)
+            ctx, key=jax.random.fold_in(base_key, layer), counter=0)
         if guard:
             # fresh scratch lists per layer; guarded_dense appends (B,)
             # trip/hard counts which we drain into the scan ys -> (L, B)
             lctx.trip_log, lctx.hard_log = [], []
             if ctx.pin_layers is not None:
-                lctx.pin_rows = jnp.take(ctx.pin_layers, first + idx, axis=1)
+                lctx.pin_rows = jnp.take(ctx.pin_layers, layer, axis=1)
         if moe_log:
             lctx.moe_log = []
         h, new_cache = block_fn(lctx, layer_p, h, positions, layer_cache)
-        if caches is not None:
-            caches = jax.tree.map(
-                lambda c, u: jax.lax.dynamic_update_index_in_dim(c, u, idx, 0),
-                caches, new_cache)
+        if in_place:
+            caches = {**new_cache,
+                      "len": put(caches["len"], new_cache["len"], idx)}
+        elif caches is not None:
+            caches = jax.tree.map(lambda c, u: put(c, u, idx), caches,
+                                  new_cache)
         ys = {}
         if guard:
             zero = jnp.zeros((b,), jnp.int32)
@@ -478,35 +533,41 @@ def _scan_blocks(ctx: Ctx, blocks: Params, block_fn, x, positions, caches,
 
 
 def forward(params: Params, batch: Dict[str, Any], cfg: ModelConfig,
-            ctx: Optional[Ctx] = None, caches=None) -> Tuple[jnp.ndarray, Any]:
-    """Forward to logits. train: caches=None; prefill/decode: caches pytree."""
+            ctx: Optional[Ctx] = None, caches=None,
+            rows=None) -> Tuple[jnp.ndarray, Any]:
+    """Forward to logits. train: caches=None; prefill/decode: caches pytree.
+
+    ``rows`` (B,) int32: the cache rows (slots) of the batch's rows, for a
+    batch narrower than the stacked cache — a prefill chunk of one slot
+    runs on the whole stack with ``rows=[slot]``. None: row b is cache
+    row b."""
     ctx = ctx or Ctx.make(cfg)
-    if cfg.family == "encdec":
-        return _encdec_forward(params, batch, cfg, ctx, caches)
-    if cfg.family == "hybrid":
-        return _hybrid_forward(params, batch, cfg, ctx, caches)
+    if cfg.family in ("encdec", "hybrid"):
+        if rows is not None:
+            raise ValueError(f"family {cfg.family!r} takes its cache one "
+                             f"slot at a time, not by rows")
+        fwd = _encdec_forward if cfg.family == "encdec" else _hybrid_forward
+        return fwd(params, batch, cfg, ctx, caches)
 
     x = _embed_input(cfg, params, batch)
     b, s, _ = x.shape
     if caches is None:
         positions = jnp.broadcast_to(jnp.arange(s)[None], (b, s))
-        cache_arg = None
     else:
-        positions = _cache_positions(cfg, caches, b, s)
-        cache_arg = caches
+        positions = _cache_positions(cfg, caches, b, s, rows)
     block_fn = _BLOCKS[cfg.family][1]
     if cfg.n_dense_layers:
         # the leading dense blocks, then the rest, each over its own cache
         lead_c, body_c = ((None, None) if caches is None
                           else (caches["lead"], caches["blocks"]))
         x, lead = _scan_blocks(ctx, params["lead_blocks"], _lead_block, x,
-                               positions, lead_c)
+                               positions, lead_c, rows=rows)
         x, body = _scan_blocks(ctx, params["blocks"], block_fn, x, positions,
-                               body_c, first=cfg.n_dense_layers)
+                               body_c, first=cfg.n_dense_layers, rows=rows)
         new_caches = None if caches is None else {"lead": lead, "blocks": body}
     else:
         x, new_caches = _scan_blocks(ctx, params["blocks"], block_fn, x,
-                                     positions, cache_arg)
+                                     positions, caches, rows=rows)
     x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
     logits = unembed(ctx, _head(params), x)
     return shard(logits, "batch", "seq", "vocab"), new_caches
@@ -531,9 +592,13 @@ def _cache_len(cfg: ModelConfig, caches) -> jnp.ndarray:
     return caches["len"][0]
 
 
-def _cache_positions(cfg: ModelConfig, caches, b: int, s: int) -> jnp.ndarray:
-    """(B, S) absolute positions for the next ``s`` tokens of every row."""
+def _cache_positions(cfg: ModelConfig, caches, b: int, s: int,
+                     rows=None) -> jnp.ndarray:
+    """(B, S) absolute positions for the next ``s`` tokens of every row
+    (of cache rows ``rows``, where given)."""
     start = _cache_len(cfg, caches)
+    if rows is not None:
+        start = start[rows]
     return jnp.broadcast_to(jnp.arange(s)[None] + start[:, None], (b, s))
 
 

@@ -373,7 +373,7 @@ class Engine:
             chunk_size = DEFAULT_CHUNK_SIZE
         self.chunk_size = int(chunk_size)
         # the cache is over-allocated to the next chunk multiple so a final
-        # padded chunk's row_update can never clamp back onto live keys
+        # padded chunk's cache write can never clamp back onto live keys
         # (chunk writes always start at a multiple of chunk_size)
         self._alloc_len = (-(-max_len // self.chunk_size) * self.chunk_size
                            if self.chunk_size else max_len)
@@ -541,6 +541,43 @@ class Engine:
                 ctx.drift_state = dstate
             return ctx
 
+        # whether every program addresses the stacked attention cache in
+        # place by (layer, row), by program name: noted when the program
+        # is traced, read by each launch's span and counters
+        inplace = self._inplace = {}
+        stacked = tf.cache_in_place(self.caches)
+
+        def slot_forward(params, caches, tokens, slot, reset, n_valid, ctx):
+            """Run ``tokens`` (1, S) through slot ``slot`` of the stacked
+            cache and leave the slot's length at its start + ``n_valid``;
+            ``reset`` first restarts the slot (a new request).
+
+            An attention cache is addressed in place: the forward runs on
+            the whole stack with the slot as its one row, writes only the
+            chunk's own rows, and a restart is length 0 — the previous
+            occupant's rows lie past the length, where the kernels' length
+            masks and ``_cached_mask`` never read. A cache with recurrent
+            ``conv``/``state`` leaves (or the hybrid's) is worked on as the
+            slot's batch-1 slice instead, zero-wiped on a restart: a
+            1-token prompt hits the SSM *decode* branch, which reads that
+            state, and the previous occupant's must not leak in."""
+            if stacked:
+                row = jnp.reshape(slot, (1,)).astype(jnp.int32)
+                start = jnp.where(reset, 0, tf._cache_len(cfg, caches)[row])
+                caches = tf.set_cache_lens(caches, start, rows=row)
+                logits, caches = tf.forward(params, {"tokens": tokens}, cfg,
+                                            ctx, caches, rows=row)
+                return logits, tf.set_cache_lens(caches, start + n_valid,
+                                                 rows=row)
+            ctx.cache_sliced = True
+            sl = jax.tree.map(
+                lambda t: jnp.where(reset, jnp.zeros_like(t), t),
+                tf.take_slot(caches, slot))
+            start = tf._cache_len(cfg, sl)            # (1,) written keys
+            logits, sl = tf.forward(params, {"tokens": tokens}, cfg, ctx, sl)
+            sl = tf.set_cache_lens(sl, start + n_valid)
+            return logits, tf.put_slot(caches, sl, slot)
+
         def prefill_fn(params, caches, last_tok, tokens, true_len, slot,
                        temp, key, rkey, lvl, dstate=None, pin=None,
                        frow=None):
@@ -552,17 +589,12 @@ class Engine:
             ctx = make_ctx(kctx, pin, frow, lvl=jnp.reshape(lvl, (1,)),
                            dstate=dstate)
             ctx.prefill_valid = jnp.reshape(true_len, (1,))
-            # full zero reset, not just len: a 1-token prompt hits the SSM
-            # *decode* branch, which reads conv/state — stale recurrent state
-            # from the slot's previous occupant must not leak in
-            slot_cache = jax.tree.map(jnp.zeros_like, tf.take_slot(caches, slot))
-            logits, slot_cache = tf.forward(params, {"tokens": tokens}, cfg,
-                                            ctx, slot_cache)
+            logits, caches = slot_forward(params, caches, tokens, slot, True,
+                                          true_len, ctx)
+            inplace["prefill"] = stacked and not ctx.cache_sliced
             # last *valid* position of the (possibly right-padded) prompt
             last = jax.lax.dynamic_index_in_dim(logits, true_len - 1, axis=1,
                                                 keepdims=False)    # (1, V)
-            slot_cache = tf.set_cache_lens(slot_cache, true_len)
-            caches = tf.put_slot(caches, slot_cache, slot)
             tok = _sample_tokens(last, jnp.full((1,), temp, jnp.float32),
                                  jax.random.fold_in(rkey, 0)[None])[0]
             out = (caches, last_tok.at[slot].set(tok), tok)
@@ -572,20 +604,17 @@ class Engine:
                 out = out + (ctx.moe_rows,)
             return out
 
-        def chunk_slot_core(params, slot_cache, prev_tok, tokens, reset,
-                            valid, is_final, temp, key, rkey, lvl,
-                            dstate=None, pin=None, frow=None):
-            """Advance ONE slot slice's prefill by one fixed-shape chunk.
+        def chunk_core(params, caches, prev_tok, tokens, reset, valid,
+                       is_final, slot, temp, key, rkey, lvl,
+                       dstate=None, pin=None, frow=None):
+            """Advance slot ``slot``'s prefill by one fixed-shape chunk.
 
             ``tokens``: (1, chunk_size), right-padded; ``valid`` of them are
-            real. ``reset`` zero-wipes the slot row on the first chunk (the
-            recycled-slot hygiene the whole-prompt path does); ``is_final``
+            real. ``reset`` restarts the slot on the first chunk (the
+            recycled-slot hygiene of ``slot_forward``); ``is_final``
             commits the sampled first token as the returned ``keep``. One
             shape -> exactly one compiled trace for every prompt length.
-
-            Operates on the batch-1 slice so the fused ``_step`` can thread
-            it through ``lax.cond``/``lax.scan`` without copying the whole
-            stacked cache per slot.
+            Shared by the per-call ``prefill_chunk`` and the fused ``_step``.
             """
             kctx, _ = jax.random.split(key)
             ctx = make_ctx(kctx, pin, frow, lvl=jnp.reshape(lvl, (1,)),
@@ -593,41 +622,27 @@ class Engine:
             # state-carrying blocks (ssm conv/SSD) must treat the chunk's
             # right-pad as absent, not as zero tokens (models/ssm.py)
             ctx.prefill_valid = jnp.reshape(valid, (1,))
-            slot_cache = jax.tree.map(
-                lambda t: jnp.where(reset, jnp.zeros_like(t), t), slot_cache)
-            start = tf._cache_len(cfg, slot_cache)        # (1,) written keys
-            logits, slot_cache = tf.forward(params, {"tokens": tokens}, cfg,
-                                            ctx, slot_cache)
-            # the forward wrote (and advanced lens by) the full padded
-            # chunk; only `valid` of it is real — the pad keys land beyond
-            # the corrected length and the per-row validity mask never
-            # exposes them (the next chunk overwrites them in place)
-            slot_cache = tf.set_cache_lens(slot_cache, start + valid)
+            # the forward writes the full padded chunk; only `valid` of it
+            # is real — the pad keys land beyond the slot's length and the
+            # per-row validity mask never exposes them (the next chunk
+            # overwrites them in place)
+            logits, caches = slot_forward(params, caches, tokens, slot, reset,
+                                          valid, ctx)
             last = jax.lax.dynamic_index_in_dim(logits, valid - 1, axis=1,
                                                 keepdims=False)   # (1, V)
             tok = _sample_tokens(last, jnp.full((1,), temp, jnp.float32),
                                  jax.random.fold_in(rkey, 0)[None])[0]
             keep = jnp.where(is_final, tok, prev_tok)
-            return slot_cache, keep, tok, ctx
-
-        def chunk_core(params, caches, last_tok, tokens, reset, valid,
-                       is_final, slot, temp, key, rkey, lvl,
-                       dstate=None, pin=None, frow=None):
-            """Whole-cache wrapper over ``chunk_slot_core`` (per-call path)."""
-            slot_cache = tf.take_slot(caches, slot)
-            slot_cache, keep, tok, ctx = chunk_slot_core(
-                params, slot_cache, last_tok[slot], tokens, reset, valid,
-                is_final, temp, key, rkey, lvl, dstate, pin, frow)
-            caches = tf.put_slot(caches, slot_cache, slot)
-            return caches, last_tok.at[slot].set(keep), tok, ctx
+            return caches, keep, tok, ctx
 
         def prefill_chunk_fn(params, caches, last_tok, tokens, reset, valid,
                              is_final, slot, temp, key, rkey, lvl,
                              dstate=None, pin=None, frow=None):
-            caches, last_tok, tok, ctx = chunk_core(
-                params, caches, last_tok, tokens, reset, valid, is_final,
-                slot, temp, key, rkey, lvl, dstate, pin, frow)
-            out = (caches, last_tok, tok)
+            caches, keep, tok, ctx = chunk_core(
+                params, caches, last_tok[slot], tokens, reset, valid,
+                is_final, slot, temp, key, rkey, lvl, dstate, pin, frow)
+            inplace["prefill_chunk"] = stacked and not ctx.cache_sliced
+            out = (caches, last_tok.at[slot].set(keep), tok)
             if guard_on:
                 out = out + (ctx.guard_trips, ctx.guard_hard)
             if moe_stats:
@@ -654,6 +669,7 @@ class Engine:
             new_caches, toks, ctx = decode_core(
                 params, caches, last_tok, active, temps, key, rkeys,
                 tok_idx, lvls, dstate, pin, frow)
+            inplace["decode"] = stacked and not ctx.cache_sliced
             out = (new_caches, toks)
             if guard_on:
                 out = out + (ctx.guard_trips, ctx.guard_hard)
@@ -687,23 +703,23 @@ class Engine:
 
             Collapses the per-iteration dispatch tail — up to ``max_slots``
             ``_prefill_chunk`` launches plus one ``_decode`` launch — into a
-            single launch (DESIGN.md §15). The per-slot chunk advances run
-            as a ``lax.scan`` over slots in slot order (one traced chunk
-            body, not ``max_slots`` unrolled copies — the unrolled version
-            quadrupled the compile and therefore cold TTFT), with the
-            ``lax.cond`` skip threading only the slot's batch-1 cache slice
-            (a cond over the whole stacked cache tree copied it per slot
-            per iteration; a vmap over slots was tried and rejected — it
-            runs the chunk body for EVERY lane, and the discarded lanes'
-            compute cost more than the dispatch it saved). The batch decode
-            then runs under ONE ``lax.cond(do_decode, ...)`` — skipping the
-            whole decode forward on pure-prefill iterations, which the
-            whole-prompt baseline never pays (one traced cond per
-            iteration is fine; it was the per-SLOT conds over the full tree
-            that copied — and a *static* do_decode would split ``_step``
-            into two compiled variants, breaking the 1-trace witness).
-            Sequencing, math and RNG match the legacy per-call path, so the
-            token streams match bit for bit.
+            single launch (DESIGN.md §15). The chunk advances run in a
+            ``lax.fori_loop`` over the prefilling slots alone, in ascending
+            slot order (their indices and count derived on the device from
+            ``flags``), so a slot that is not prefilling costs nothing: one
+            traced chunk body, not ``max_slots`` unrolled copies (the
+            unrolled version quadrupled the compile and therefore cold
+            TTFT), each chunk on the whole stacked cache with its slot as
+            the row (``slot_forward``). A vmap over slots was tried and
+            rejected — it runs the chunk body for EVERY lane, and the
+            discarded lanes' compute cost more than the dispatch it saved.
+            The batch decode then runs under ONE ``lax.cond(do_decode,
+            ...)`` — skipping the whole decode forward on pure-prefill
+            iterations, which the whole-prompt baseline never pays (a
+            *static* do_decode would split ``_step`` into two compiled
+            variants, breaking the 1-trace witness). Sequencing, math and
+            RNG match the legacy per-call path, so the token streams match
+            bit for bit.
 
             chunk_toks: (S, 1, chunk); flags: (S, 7) int32 — columns are
             [reset, valid, final, prefilling, act_after, tok_idx, level],
@@ -713,33 +729,27 @@ class Engine:
             the last row feeds the decode (zeros where unused); rkeys:
             (S, 2) per-request sampling keys.
             """
-            def body(carry, xs):
-                caches, last_tok = carry
-                s, toks_s, f, temp, key, rkey = xs
-                reset, valid, final, pre = (f[0] != 0, f[1], f[2] != 0,
-                                            f[3] != 0)
-                sl = tf.take_slot(caches, s)
+            ctxs = []
+            pre = flags[:, 3] != 0
+            order = jnp.nonzero(pre, size=n_slots)[0].astype(jnp.int32)
 
-                def adv(ops):
-                    sl, prev = ops
-                    sl, keep, tok, ctx = chunk_slot_core(
-                        params, sl, prev, toks_s, reset, valid, final,
-                        temp, key, rkey, f[6], dstate)
-                    return sl, keep, tok, ctx.moe_rows if moe_stats else ()
+            def chunk(i, carry):
+                caches, last_tok, ptoks, rows = carry
+                s = order[i]
+                f = flags[s]
+                caches, keep, tok, ctx = chunk_core(
+                    params, caches, last_tok[s], chunk_toks[s], f[0] != 0,
+                    f[1], f[2] != 0, s, temps[s], keys[s], rkeys[s], f[6],
+                    dstate)
+                ctxs.append(ctx)
+                if moe_stats:
+                    rows = jax.tree.map(jnp.add, rows, ctx.moe_rows)
+                return (caches, last_tok.at[s].set(keep),
+                        ptoks.at[s].set(tok), rows)
 
-                def skip(ops):
-                    sl, prev = ops
-                    return sl, prev, jnp.int32(0), no_rows
-
-                sl, keep, tok, rows = jax.lax.cond(pre, adv, skip,
-                                                   (sl, last_tok[s]))
-                return (tf.put_slot(caches, sl, s),
-                        last_tok.at[s].set(keep)), (tok, rows)
-
-            (caches, last_tok), (ptoks, rows) = jax.lax.scan(
-                body, (caches, last_tok),
-                (jnp.arange(n_slots, dtype=jnp.int32), chunk_toks, flags,
-                 temps, keys[:n_slots], rkeys))
+            caches, last_tok, ptoks, rows = jax.lax.fori_loop(
+                0, jnp.sum(pre.astype(jnp.int32)), chunk,
+                (caches, last_tok, jnp.zeros((n_slots,), jnp.int32), no_rows))
 
             active = flags[:, 4] != 0
 
@@ -748,15 +758,17 @@ class Engine:
                 caches, last_tok, ctx = decode_core(
                     params, caches, last_tok, active, temps, keys[n_slots],
                     rkeys, flags[:, 5], flags[:, 6], dstate)
+                ctxs.append(ctx)
                 return caches, last_tok, ctx.moe_rows if moe_stats else ()
 
             caches, last_tok, dec_rows = jax.lax.cond(
                 jnp.any(active), dec, lambda ops: ops + (no_rows,),
                 (caches, last_tok))
+            inplace["step"] = stacked and not any(c.cache_sliced
+                                                  for c in ctxs)
             if not moe_stats:
                 return caches, last_tok, ptoks
-            rows = jax.tree.map(lambda c, d: jnp.sum(c, axis=0) + d, rows,
-                                dec_rows)
+            rows = jax.tree.map(jnp.add, rows, dec_rows)
             return caches, last_tok, ptoks, rows
 
         # donate only the cache: last_tok/toks arrays stay referenced by the
@@ -810,9 +822,9 @@ class Engine:
     def begin(self) -> None:
         """Reset scheduler state for a fresh session (also called by
         ``__init__`` and ``generate``). The device-side cache is NOT
-        touched: admission hygiene (the prefill zero-reset / chunk reset
-        flag) guarantees a recycled slot is token-clean regardless of what
-        the previous session left in it."""
+        touched: admission hygiene (a new request restarts its slot,
+        ``slot_forward``) guarantees a recycled slot is token-clean
+        regardless of what the previous session left in it."""
         S = self.max_slots
         self._reqs: List[Request] = []
         self._req_index: Dict[int, int] = {}
@@ -876,9 +888,9 @@ class Engine:
         """Withdraw a queued or running request between steps.
 
         A running request's slot is freed host-side only: the next
-        occupant's admission reset (whole-slot zero-wipe on prefill / the
-        chunk ``reset`` flag) makes the recycle token-clean, so no device
-        work is needed — this is the PR 6 slot-recycling machinery doing
+        occupant's admission restart (length 0, or a zero-wipe of
+        recurrent state; ``slot_forward``) makes the recycle token-clean,
+        so no device work is needed — the slot-recycling machinery does
         the cancellation for free. Tokens already emitted stay in
         ``r.out_tokens`` as the partial stream. Returns False if the
         request is unknown or already terminal."""
@@ -1244,16 +1256,23 @@ class Engine:
         """Count one launch of ``program`` and return its span. The rows
         come from the host arrays that staged the launch: slots decoded,
         decode rows of slots not decoding, prompt tokens carried and
-        their padding."""
+        their padding. ``cache_inplace`` is 1 where the program, as
+        traced, addressed every attention cache leaf in place by (layer,
+        row), and 0 where it sliced a layer or a slot out and wrote it
+        back (``transformer.cache_in_place``)."""
         c = self.counters
         c.launches += 1
         c.decode_rows += decode
         c.decode_idle_rows += idle
         c.prefill_rows += prefill
         c.prefill_pad_rows += pad
+        inplace = int(self._inplace.get(program, False))
+        c.cache_inplace_launches += inplace
+        c.cache_slice_launches += 1 - inplace
         return TraceAnnotation(f"engine.launch.{program}",
                                rows=decode + prefill, pad_rows=idle + pad,
-                               decode_rows=decode, prefill_rows=prefill)
+                               decode_rows=decode, prefill_rows=prefill,
+                               cache_inplace=inplace)
 
     def _note_moe(self, out: tuple) -> None:
         """Keep a launch's expert rows (its last output) for the drain."""
@@ -1298,9 +1317,9 @@ class Engine:
                     _host_snapshot(self._rk_slot[s]),
                     np.int32(self._lvl_slot[s]), self._dstate(),
                     *self._guard_args(s))
+        self._build("prefill", args, variant=bucket)
         with self._launch("prefill", prefill=true_len,
                           pad=bucket - true_len):
-            self._build("prefill", args, variant=bucket)
             try:
                 out = self._prefill(*args)
             except Exception as e:     # noqa: BLE001
@@ -1349,9 +1368,9 @@ class Engine:
                         self._next_key(), _host_snapshot(self._rk_slot[s]),
                         np.int32(self._lvl_slot[s]), self._dstate(),
                         *self._guard_args(s))
+            self._build("prefill_chunk", args)
             with self._launch("prefill_chunk", prefill=int(valid),
                               pad=self.chunk_size - int(valid)):
-                self._build("prefill_chunk", args)
                 try:
                     out = self._prefill_chunk(*args)
                 except Exception as e:     # noqa: BLE001
@@ -1458,9 +1477,9 @@ class Engine:
         dead_errs: Dict[int, RequestError] = {}
         gdead: List[int] = []
         failed = False
+        self._build("decode", args)
         with self._launch("decode", decode=n_dec,
                           idle=self.max_slots - n_dec):
-            self._build("decode", args)
             try:
                 out = self._decode(*args)
                 self.caches, toks = out[:2]
@@ -1518,9 +1537,9 @@ class Engine:
         if all(r is None or self._decoding[s]
                for s, r in enumerate(self._slots)):
             # pure-decode iteration: the per-call path is already a
-            # single ``_decode`` launch, and it skips ``_step``'s
-            # scan-over-slots slice traffic — route it there (this is
-            # NOT the failure fallback; the next mixed iteration fuses)
+            # single ``_decode`` launch, with no chunk tokens or flags to
+            # stage — route it there (this is NOT the failure fallback;
+            # the next mixed iteration fuses)
             return False
         n_slots = self.max_slots
         with TraceAnnotation("engine.stage"):
@@ -1581,11 +1600,11 @@ class Engine:
                     _host_snapshot(self._rk_slot), self._dstate())
         n_dec = int(act_after.sum())
         n_valid = int(valids.sum())
+        self._build("step", args)
         with self._launch(
                 "step", decode=n_dec, idle=n_slots - n_dec if do_decode
                 else 0, prefill=n_valid,
                 pad=int(prefilling.sum()) * self.chunk_size - n_valid):
-            self._build("step", args)
             try:
                 out = self._step(*args)
             except Exception as e:         # noqa: BLE001

@@ -121,6 +121,12 @@ class ServingCounters:
     expert_pad_rows: int = 0
     expert_groups: int = 0
     expert_rows: List[int] = dataclasses.field(default_factory=list)
+    # launches whose program addressed every attention cache leaf in place
+    # by (layer, row), and launches whose program sliced a layer or a slot
+    # of the stacked cache out and wrote it back (recurrent and hybrid
+    # caches, the f32 dense megakernel)
+    cache_inplace_launches: int = 0
+    cache_slice_launches: int = 0
 
     @property
     def traces(self) -> int:

@@ -51,7 +51,7 @@ def test_mla_decode_kernel_matches_oracle():
     q_lat = jax.random.normal(ks[0], (b, h, lat), jnp.float32)
     q_rope = jax.random.normal(ks[1], (b, h, rope_hd), jnp.float32)
     ckv = jax.random.normal(ks[2], (b, t, lat), jnp.float32)
-    krope = jax.random.normal(ks[3], (b, t, rope_hd), jnp.float32)
+    krope = jax.random.normal(ks[3], (b, rope_hd, t), jnp.float32)
     lens = jnp.array([24, 5, 0], jnp.int32)
     scale = 1.0 / (lat + rope_hd) ** 0.5
     got = mla_decode_attention(q_lat, q_rope, ckv, krope, lens, scale,

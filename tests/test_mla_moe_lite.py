@@ -207,12 +207,12 @@ def test_mla_prefill_kernel_matches_dense_attention(starts):
     ql = jax.random.normal(ks[0], (b, s, h, lat))
     qr = jax.random.normal(ks[1], (b, s, h, r))
     ckv = jax.random.normal(ks[2], (b, t, lat))
-    kr = jax.random.normal(ks[3], (b, t, r))
+    kr = jax.random.normal(ks[3], (b, r, t))
     start = jnp.array(starts, jnp.int32)
     got = mla_prefill_attention(ql, qr, ckv, kr, start, scale=0.2, block_k=16)
     with jax.default_matmul_precision("highest"):
         sc = (jnp.einsum("bshl,btl->bhst", ql, ckv)
-              + jnp.einsum("bshr,btr->bhst", qr, kr)) * 0.2
+              + jnp.einsum("bshr,brt->bhst", qr, kr)) * 0.2
     qi = start[:, None] + jnp.arange(s)[None]
     kj = jnp.arange(t)
     mask = kj[None, None, :] <= qi[:, :, None]
